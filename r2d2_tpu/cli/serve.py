@@ -31,8 +31,9 @@ import time
 
 
 def main(argv=None) -> int:
-    from r2d2_tpu.utils import pin_platform
+    from r2d2_tpu.utils import enable_compile_cache, pin_platform
     pin_platform()
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--ckpt", default="",
@@ -59,6 +60,8 @@ def main(argv=None) -> int:
     from r2d2_tpu.telemetry.alerts import AlertEngine, default_rules
 
     cfg = parse_overrides(Config(), config_overrides)
+    from r2d2_tpu.utils.platform import announce_runtime
+    announce_runtime(cfg)
     if args.ckpt:
         from r2d2_tpu.runtime.checkpoint import (load_checkpoint_config,
                                                  restore_checkpoint)
@@ -99,7 +102,7 @@ def main(argv=None) -> int:
         stats.trace = ServeTrace()
     telemetry = Telemetry.from_config(cfg, name="serve")
     fleet = None
-    endpoint = None
+    server = None
     transports = []
     if cfg.serve.servers > 1:
         # sharded serving fleet (ISSUE 17): N server loops, one TCP
@@ -127,7 +130,14 @@ def main(argv=None) -> int:
               f"(max {fleet.max_servers}) — spec: "
               + json.dumps(spec), flush=True)
     else:
+        # the server first, the listener second: construction compiles
+        # every micro-batch bucket (tens of seconds cold on the chip), and
+        # a listener that accepts before the loop can answer turns that
+        # wait into client timeouts — "serving on" means it is serving
         endpoint = InprocEndpoint()
+        server = PolicyServer(cfg, net, params, endpoint=endpoint,
+                              stats=stats, telemetry=telemetry,
+                              quant_stats=quant_stats).start()
         transports = [SocketServerTransport(endpoint.submit, cfg.serve.host,
                                             cfg.serve.port)]
         print(f"serving on {transports[0].host}:{transports[0].port} "
@@ -155,12 +165,6 @@ def main(argv=None) -> int:
     proc = proc_header("serve")
     telemetry.start_drain(
         os.path.join(args.save_dir or ".", "spans_serve.jsonl"))
-
-    server = None
-    if fleet is None:
-        server = PolicyServer(cfg, net, params, endpoint=endpoint,
-                              stats=stats, telemetry=telemetry,
-                              quant_stats=quant_stats).start()
 
     def _batches() -> int:
         if server is not None:

@@ -22,9 +22,13 @@ from r2d2_tpu.config import Config, parse_overrides
 from r2d2_tpu.runtime.orchestrator import train
 
 
-def main(argv=None) -> None:
-    from r2d2_tpu.utils import pin_platform
+def main(argv=None):
+    """Run training; returns what ``train()`` returned (the player stacks,
+    or None on the supervised / multihost routes) so a caller such as
+    ``chip_smoke.py`` can check the run instead of trusting the exit."""
+    from r2d2_tpu.utils import enable_compile_cache, pin_platform
     pin_platform()
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     actor_mode, max_steps, max_seconds = None, None, None
     rest = []
@@ -67,8 +71,8 @@ def main(argv=None) -> None:
                         actor_mode=actor_mode or "thread", log_fn=log)
         return
 
-    train(cfg, max_training_steps=max_steps, max_seconds=max_seconds,
-          actor_mode=actor_mode or "process", log_fn=log)
+    return train(cfg, max_training_steps=max_steps, max_seconds=max_seconds,
+                 actor_mode=actor_mode or "process", log_fn=log)
 
 
 if __name__ == "__main__":

@@ -633,6 +633,14 @@ class NetworkApply:
             config, bf16=resolve_pallas_setting(config.bf16, "network.bf16"),
             space_to_depth=resolve_pallas_setting(
                 config.space_to_depth, "network.space_to_depth"))
+        if config.pallas_lstm_interpret and jax.default_backend() == "tpu":
+            # interpret mode is the CPU mesh's way to run the kernel's
+            # semantics; a run that reports itself as TPU must execute the
+            # compiled kernel or nothing
+            raise ValueError(
+                "network.pallas_lstm_interpret is for CPU dry-runs only; "
+                "this process runs on a TPU — unset it (network.pallas_lstm="
+                "'on' then compiles the kernel through Mosaic)")
         if config.space_to_depth:
             _, k0, s0 = config.conv_layers[0]
             if frame_height % 2 or frame_width % 2 or k0 % 2 or s0 % 2:

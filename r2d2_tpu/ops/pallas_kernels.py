@@ -78,7 +78,7 @@ def _stack_kernel(frame_stack: int, out_dtype, out_height: int,
     inv = jnp.float32(1.0 / 255.0)
     for k in range(frame_stack):
         frame = in_ref[0, pl.dslice(t + k, 1)]               # (1, H, W) u8
-        # Mosaic can't lower uint8 -> float32 directly (BENCH_r02 failure);
+        # Mosaic can't lower uint8 -> float32 directly (round-2 bench failure);
         # widen through int32 first, which it can, then convert. The
         # normalization rounds once from f32 into out_dtype — identical to
         # XLA's own cast at the conv boundary under a bf16 policy.
@@ -89,7 +89,7 @@ def _stack_kernel(frame_stack: int, out_dtype, out_height: int,
 
 def _decode_plane(in_ref, t, k, out_height: int, out_width: int):
     """One frame plane, decoded to normalized f32 (H, W) in registers.
-    Mosaic can't cast uint8 -> f32 directly (BENCH_r02): widen via i32."""
+    Mosaic can't cast uint8 -> f32 directly (round 2): widen via i32."""
     from jax.experimental import pallas as pl
 
     frame = in_ref[0, pl.dslice(t + k, 1)]                   # (1, H, W) u8
@@ -230,7 +230,7 @@ def stack_frames_pallas_nhwc(obs: jnp.ndarray, seq_window: int,
 def resolve_pallas_setting(setting, field: str = "pallas setting") -> bool:
     """Resolve a pallas tri-state config knob: "on", "off", or "auto" =
     pallas iff the default backend is TPU (the measured winner there —
-    BENCH_r03 — while Mosaic cannot compile for CPU/GPU backends). Accepts
+    builders, round 3 — while Mosaic cannot compile for CPU/GPU backends). Accepts
     legacy bools (configs serialized before the tri-state existed) and
     their CLI string spellings (--optim.pallas_obs_decode=true coerces to
     the literal string "true")."""
@@ -278,7 +278,7 @@ def gather_rows_reference(ring: jnp.ndarray, block_idx: jnp.ndarray,
                           start: jnp.ndarray, window: int) -> jnp.ndarray:
     """vmapped dynamic-slice twin — correct everywhere, but XLA lowers the
     batched start indices to a generic uint8 gather that measures ~5.5 ms
-    at the production shape on TPU v5e (BENCH_r03 analysis)."""
+    at the production shape on TPU v5e (round-3 analysis)."""
     def one(b, t0):
         return jax.lax.dynamic_slice(
             ring[b], (t0, 0, 0), (window,) + ring.shape[2:])

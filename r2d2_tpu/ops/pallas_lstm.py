@@ -24,12 +24,13 @@ This kernel runs the scan as a single Pallas grid over T:
   iteration (T must divide evenly; T=55 → 1, 5, 11): the in-kernel loop
   amortizes per-iteration grid/DMA bookkeeping at the cost of bigger
   VMEM blocks. The right value is a chip measurement — bench.py sweeps
-  it in the plstm cells. VMEM budget at the reference shape
-  (B=128, H=512, bf16): the backward kernel is the tight side — six
-  (bt, 128, 512..2048) streamed blocks plus the revisited f32 (512,
-  2048) dWh block and Wh^T; bt=11 sits near ~24 MB of live blocks, so a
-  Mosaic VMEM-exceeded failure for the _bt11 cell is a plausible sweep
-  outcome (recorded per-cell by the bench, not a kernel bug).
+  it in the plstm cells. VMEM at the reference shape (B=128, H=512):
+  the backward kernel is the tight side — six (bt, 128, 512..2048)
+  streamed blocks plus the revisited f32 (512, 2048) dWh block and Wh^T,
+  all double-buffered. Only bf16 at bt=1 fits Mosaic's 16 MiB default
+  scoped-VMEM limit on v5e (libtpu 0.0.34 refused f32 at bt=1 and both
+  dtypes at bt=5 with RESOURCE_EXHAUSTED), so each call asks for what
+  its blocks need (``_vmem_params``).
 
 Pre-flight lowering audit (round 5, against the four Mosaic rejection
 classes catalogued in PERF.md): every BlockSpec minor dim is
@@ -160,6 +161,27 @@ def _fwd_kernel_lean(hidden: int, nblocks: int, block_t: int, xpb_ref,
         cfin_ref[:] = c_new.astype(cfin_ref.dtype)
 
 
+# v5e's TensorCore has 128 MiB of VMEM; Mosaic scopes a kernel to 16 MiB
+# unless told otherwise. Leave the rest of the program a quarter of it.
+_VMEM_CEILING = 96 << 20
+
+
+def _vmem_params(specs, arrays, scratch_bytes: int):
+    """CompilerParams sized from the call's own blocks: Pallas double-
+    buffers every in/out block (``specs`` with the dtypes of the matching
+    ``arrays``), the scratches are single, and the unrolled in-kernel step
+    math needs headroom for its f32 temporaries."""
+    import math
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    need = scratch_bytes + 2 * sum(
+        math.prod(spec.block_shape) * jnp.dtype(arr.dtype).itemsize
+        for spec, arr in zip(specs, arrays))
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(need + (16 << 20), _VMEM_CEILING))
+
+
 def _fwd_call(xpb, wh, c0, h0, interpret, block_t, save_residuals=True):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -191,21 +213,25 @@ def _fwd_call(xpb, wh, c0, h0, interpret, block_t, save_residuals=True):
             jax.ShapeDtypeStruct((nsteps, batch, hidden), dtype),
             jax.ShapeDtypeStruct((batch, hidden), dtype),
         ]
+    in_specs = [
+        pl.BlockSpec((bt, batch, gdim), lambda t: (t, 0, 0)),
+        pl.BlockSpec((hidden, gdim), lambda t: (0, 0)),
+        pl.BlockSpec((batch, hidden), lambda t: (0, 0)),
+        pl.BlockSpec((batch, hidden), lambda t: (0, 0)),
+    ]
     return pl.pallas_call(
         kernel,
         grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((bt, batch, gdim), lambda t: (t, 0, 0)),
-            pl.BlockSpec((hidden, gdim), lambda t: (0, 0)),
-            pl.BlockSpec((batch, hidden), lambda t: (0, 0)),
-            pl.BlockSpec((batch, hidden), lambda t: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((batch, hidden), jnp.float32),
             pltpu.VMEM((batch, hidden), jnp.float32),
         ],
+        compiler_params=_vmem_params(
+            in_specs + out_specs, [xpb, wh, c0, h0] + out_shape,
+            scratch_bytes=2 * batch * hidden * 4),
         interpret=interpret,
     )(xpb, wh, c0, h0)
 
@@ -304,39 +330,46 @@ def _bwd_call(wh, c0, h0, hseq, cseq, acts, dhseq, dcfin, dhfin, interpret,
 
     last = nblocks - 1
     prev = lambda i: jnp.maximum(last - 1 - i, 0)
+    operands = (dhseq, acts, cseq, cseq, hseq, wht, c0, h0, dcfin, dhfin)
+    in_specs = [
+        pl.BlockSpec((bt, batch, hidden), rev(lambda i: last - i)),  # dhseq
+        pl.BlockSpec((bt, batch, gdim), rev(lambda i: last - i)),    # acts
+        pl.BlockSpec((bt, batch, hidden), rev(lambda i: last - i)),  # c_t
+        pl.BlockSpec((bt, batch, hidden), rev(prev)),            # c prevblk
+        pl.BlockSpec((bt, batch, hidden), rev(prev)),            # h prevblk
+        pl.BlockSpec((gdim, hidden), lambda i: (0, 0)),              # Wh^T
+        pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # c0
+        pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # h0
+        pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dc_fin
+        pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dh_fin
+    ]
+    out_specs = [
+        pl.BlockSpec((bt, batch, gdim), rev(lambda i: last - i)),    # dxpb
+        pl.BlockSpec((hidden, gdim), lambda i: (0, 0)),              # dWh
+        pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dc0
+        pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dh0
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((nsteps, batch, gdim), dhseq.dtype),
+        jax.ShapeDtypeStruct((hidden, gdim), jnp.float32),
+        jax.ShapeDtypeStruct((batch, hidden), jnp.float32),
+        jax.ShapeDtypeStruct((batch, hidden), jnp.float32),
+    ]
     return pl.pallas_call(
         functools.partial(_bwd_kernel, hidden, nblocks, bt),
         grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((bt, batch, hidden), rev(lambda i: last - i)),  # dhseq
-            pl.BlockSpec((bt, batch, gdim), rev(lambda i: last - i)),    # acts
-            pl.BlockSpec((bt, batch, hidden), rev(lambda i: last - i)),  # c_t
-            pl.BlockSpec((bt, batch, hidden), rev(prev)),            # c prevblk
-            pl.BlockSpec((bt, batch, hidden), rev(prev)),            # h prevblk
-            pl.BlockSpec((gdim, hidden), lambda i: (0, 0)),              # Wh^T
-            pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # c0
-            pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # h0
-            pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dc_fin
-            pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dh_fin
-        ],
-        out_specs=[
-            pl.BlockSpec((bt, batch, gdim), rev(lambda i: last - i)),    # dxpb
-            pl.BlockSpec((hidden, gdim), lambda i: (0, 0)),              # dWh
-            pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dc0
-            pl.BlockSpec((batch, hidden), lambda i: (0, 0)),             # dh0
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nsteps, batch, gdim), dhseq.dtype),
-            jax.ShapeDtypeStruct((hidden, gdim), jnp.float32),
-            jax.ShapeDtypeStruct((batch, hidden), jnp.float32),
-            jax.ShapeDtypeStruct((batch, hidden), jnp.float32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((batch, hidden), jnp.float32),
             pltpu.VMEM((batch, hidden), jnp.float32),
         ],
+        compiler_params=_vmem_params(
+            in_specs + out_specs, list(operands) + out_shape,
+            scratch_bytes=2 * batch * hidden * 4),
         interpret=interpret,
-    )(dhseq, acts, cseq, cseq, hseq, wht, c0, h0, dcfin, dhfin)
+    )(*operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

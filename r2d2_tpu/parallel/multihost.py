@@ -184,7 +184,7 @@ def make_lockstep_ingest(spec: ReplaySpec, mesh, fleet: bool = False):
     """
     import jax
     import jax.numpy as jnp
-    from r2d2_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from r2d2_tpu.parallel.sharded import _shard0, _unshard0
@@ -391,7 +391,7 @@ def make_lockstep_consensus(mesh, fleet: bool = False):
     each rank's first owned row (the only row a host fills). fleet=False
     compiles the exact PR-10 (dp, 4) psum."""
     import jax
-    from r2d2_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from r2d2_tpu.telemetry.fleet import mesh_row_ranks, rank_first_rows

@@ -34,9 +34,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from r2d2_tpu.parallel.compat import shard_map
 
 from r2d2_tpu.models.network import lstm_cell_step
 

@@ -28,9 +28,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from r2d2_tpu.parallel.compat import shard_map
 
 from r2d2_tpu.config import OptimConfig
 from r2d2_tpu.learner.train_step import TrainState, make_loss_fn, make_optimizer
@@ -51,14 +50,21 @@ def _unshard0(tree):
 
 
 def sharded_replay_init(spec: ReplaySpec, mesh: Mesh) -> ReplayState:
-    """Global replay state with leading dp axis, placed shard-per-chip."""
+    """Global replay state with leading dp axis, placed shard-per-chip.
+
+    Built under jit with the dp sharding as its OUTPUT, so each chip
+    allocates its own ring and nothing else. Materializing the dp-wide state
+    on one device and resharding it needs dp x the ring there: 25.6 GiB at
+    dp=4 and the default capacity, which a 16 GB v5e refused (PR 21)."""
     from r2d2_tpu.parallel.mesh import dp_sharding
     dp = mesh.shape["dp"]
-    state = replay_init(spec)
-    state = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (dp,) + x.shape), state)
-    sharding = dp_sharding(mesh)
-    return jax.tree_util.tree_map(lambda x: jax.device_put(x, sharding), state)
+
+    def build():
+        return jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x[None], (dp,) + x.shape),
+            replay_init(spec))
+
+    return jax.jit(build, out_shardings=dp_sharding(mesh))()
 
 
 def make_sharded_replay_add(spec: ReplaySpec, mesh: Mesh):

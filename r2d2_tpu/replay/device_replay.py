@@ -238,7 +238,7 @@ def _gather_windows(spec: ReplaySpec, state: ReplayState,
 
     The obs gather is the dominant cost of sampling (52 MB of uint8 per
     batch); spec.pallas_gather routes it to the scalar-prefetch pallas
-    kernel on TPU (2.6x the XLA gather, BENCH_r03). last_action is 28 KB —
+    kernel on TPU (2.6x the XLA gather, builders, round 3). last_action is 28 KB —
     the vmapped slice is fine everywhere."""
     from r2d2_tpu.ops.pallas_kernels import gather_rows
     obs_len = spec.seq_window + spec.frame_stack - 1

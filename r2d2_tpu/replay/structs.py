@@ -112,7 +112,7 @@ class ReplaySpec:
         the 128-lane tile. Mosaic requires BOTH minor dims of an HBM
         memref slice to be tile-aligned — an H-only pad was rejected on
         v5e ('slice along dimension 3 must be aligned to tiling (128), but
-        is 84', BENCH r4). The decode strips the padding
+        is 84', builders, round 4). The decode strips the padding
         (stack_frames out_width), so the network still sees frame_width.
 
         STORAGE COST: the pad grows the whole obs ring 1.74x in HBM

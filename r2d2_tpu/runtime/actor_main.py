@@ -1,11 +1,11 @@
 """Spawned actor process entry point.
 
 Kept import-light on purpose: with the ``spawn`` start method the child
-re-imports this module before unpickling the target function, and the env
-vars pinning JAX to the host CPU must be set before any jax import — the TPU
+re-imports this module before unpickling the target function. The TPU
 belongs to the learner process alone (the reference gets this isolation for
 free from Ray's per-actor processes + CUDA_VISIBLE_DEVICES,
-/root/reference/config.py:1).
+/root/reference/config.py:1); ``actor_process_main`` pins the child to the
+host CPU before it touches a backend.
 """
 
 import os
@@ -26,12 +26,14 @@ def actor_process_main(cfg_dict: dict, player_idx: int, actor_idx: int,
     # instead of dying loudly on a FileNotFoundError mid-bring-up.
     if stop_event.is_set():
         return
-    # unconditional (not setdefault): an inherited JAX_PLATFORMS=tpu from a
-    # TPU-pinned parent would otherwise have every actor child race to open
-    # the single-process libtpu — the TPU belongs to the learner alone
+    # One process per chip: the TPU belongs to the learner, so the child is
+    # pinned to the CPU unconditionally (not setdefault — an inherited
+    # JAX_PLATFORMS naming the TPU would have every child race the parent
+    # for libtpu). The env var alone arrives too late: under spawn this
+    # child already re-imported the parent's main module, and with it jax,
+    # before this function ran — pin_platform()'s jax.config.update is what
+    # actually keeps the child off the chip.
     os.environ["JAX_PLATFORMS"] = "cpu"
-    # late imports: only after the platform pin; jax.config route as well —
-    # a wedged accelerator plugin can hang discovery despite the env var
     from r2d2_tpu.utils import pin_platform
     pin_platform()
     import jax

@@ -128,6 +128,8 @@ def run_anakin_train(cfg: Config, *, max_training_steps: Optional[int] = None,
             os.path.join(cfg.runtime.save_dir or ".", "spans_player0.jsonl"),
             append=bool(cfg.runtime.resume))
 
+    from r2d2_tpu.utils.platform import announce_runtime
+    announce_runtime(cfg, metrics.logger)
     learner = Learner(cfg, net, 0, metrics=metrics)
     spec = learner.spec
     seg_steps = spec.block_length          # learning steps per lane-block

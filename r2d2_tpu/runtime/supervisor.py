@@ -56,8 +56,9 @@ def _child_entry(cfg_dict: dict, actor_mode: str,
     os.environ[RESTARTS_ENV] = str(restarts)
     from r2d2_tpu.config import Config
     from r2d2_tpu.runtime.orchestrator import train
-    from r2d2_tpu.utils import pin_platform
+    from r2d2_tpu.utils import enable_compile_cache, pin_platform
     pin_platform()
+    enable_compile_cache()
     cfg = Config.from_dict(cfg_dict)
 
     def log_fn(record: dict) -> None:

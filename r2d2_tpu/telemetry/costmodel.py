@@ -20,7 +20,8 @@ machine-readable table for both:
     roofline report (tools/roofline.py) and the periodic record's
     ``costs`` block are built from.
 
-THE while-loop caveat (measured, jax 0.4.37 / XLA HloCostAnalysis): a
+THE while-loop caveat (XLA HloCostAnalysis; first measured under jax
+0.4.37, and the unroll-twin parity test below still holds under 0.9.0): a
 ``while`` body is counted ONCE, not x trip-count — so any ``lax.scan``
 program (the LSTM time scan, the multi-step dispatch scan, the anakin
 acting scan) undercounts its loop body's flops by (T-1)/T. Two uses,
@@ -49,41 +50,43 @@ import sys
 from typing import Any, Dict, Iterable, Optional, Tuple
 
 # ---------------------------------------------------------------------------
-# per-backend peak specs (roofline numerators): dense matmul peak by
-# compute dtype + HBM bandwidth. TPU numbers are the published per-chip
-# figures; the CPU row is a NOMINAL placeholder (flagged) so the report
-# renders on the test backend without pretending to know the host.
+# THE peak table (roofline denominators), keyed by the exact
+# ``jax.devices()[0].device_kind`` string. One row per chip the repo has
+# run on; a device that is not here is an error, not a default.
 # ---------------------------------------------------------------------------
 
-PEAK_SPECS: Tuple[Tuple[str, Dict[str, float]], ...] = (
-    ("v6", dict(flops_bf16=918e12, flops_f32=459e12, hbm_gbps=1640.0)),
-    ("v5p", dict(flops_bf16=459e12, flops_f32=229.5e12, hbm_gbps=2765.0)),
-    ("v5 lite", dict(flops_bf16=197e12, flops_f32=98.5e12, hbm_gbps=819.0)),
-    ("v5e", dict(flops_bf16=197e12, flops_f32=98.5e12, hbm_gbps=819.0)),
-    ("v4", dict(flops_bf16=275e12, flops_f32=137.5e12, hbm_gbps=1228.0)),
-    ("v3", dict(flops_bf16=123e12, flops_f32=61.5e12, hbm_gbps=900.0)),
-    ("v2", dict(flops_bf16=45e12, flops_f32=22.5e12, hbm_gbps=700.0)),
-)
+PEAK_SPECS: Dict[str, Dict[str, float]] = {
+    # TPU v5e, per chip — Google Cloud documentation, "TPU v5e": 197
+    # TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s. f32 taken as
+    # half the bf16 rate. "TPU v5 lite" is what the chip reports
+    # (chip_smoke.py prints it).
+    "TPU v5 lite": dict(flops_bf16=197e12, flops_int8=393e12,
+                        flops_f32=98.5e12, hbm_gbps=819.0),
+}
 
 # nominal 2-core-container numbers, NOT a measurement — %-of-peak rows on
-# the CPU backend are structural smoke, never quoted (nominal=True rides
-# the report so a reader cannot mistake them)
-CPU_FALLBACK = dict(flops_bf16=5e10, flops_f32=5e10, hbm_gbps=10.0,
-                    nominal=True)
+# the CPU backend are structural smoke for the test suite, never quoted
+# (nominal=True rides the report so a reader cannot mistake them)
+CPU_NOMINAL = dict(flops_bf16=5e10, flops_f32=5e10, hbm_gbps=10.0)
 
 
 def peak_spec(device_kind: Optional[str] = None) -> Dict[str, Any]:
     """Peak FLOP/s + HBM bandwidth for a device kind (default: device 0
-    of the current backend). Unknown kinds get the flagged CPU/nominal
-    fallback rather than a silent zero."""
+    of the current backend). The CPU gets the flagged nominal row; any
+    other device missing from ``PEAK_SPECS`` raises."""
     if device_kind is None:
         import jax
         device_kind = jax.devices()[0].device_kind
-    kind = device_kind.lower()
-    for marker, spec in PEAK_SPECS:
-        if marker in kind:
-            return dict(spec, device_kind=device_kind, nominal=False)
-    return dict(CPU_FALLBACK, device_kind=device_kind)
+    if device_kind in PEAK_SPECS:
+        return dict(PEAK_SPECS[device_kind], device_kind=device_kind,
+                    nominal=False)
+    if device_kind.lower() == "cpu":
+        return dict(CPU_NOMINAL, device_kind=device_kind, nominal=True)
+    raise KeyError(
+        f"no peak figures for device_kind {device_kind!r}: add its "
+        "published per-chip peaks, with their source, to "
+        "telemetry/costmodel.py PEAK_SPECS (known: "
+        f"{sorted(PEAK_SPECS)})")
 
 
 # ---------------------------------------------------------------------------

@@ -2300,8 +2300,9 @@ def main(argv=None) -> int:
         # emulated dp-wide lockstep mesh.
         from r2d2_tpu.utils.platform import force_host_device_count
         force_host_device_count(max(args.sharded_dp, 2))
-    from r2d2_tpu.utils import pin_platform
+    from r2d2_tpu.utils import enable_compile_cache, pin_platform
     pin_platform()
+    enable_compile_cache()
     import jax
 
     overrides = {}

@@ -76,11 +76,9 @@ def extract_metrics(obj, prefix: str = "") -> Dict[str, float]:
     """Flatten an artifact to {dotted.path: value} over the watched
     throughput keys. Lists are skipped (the ``*_cells`` arrays are the
     noise the medians exist to absorb), as is anything under a
-    ``config`` block or a stale last-good re-emission (bench.py tags
-    those ``stale: true`` — gating on a number the current tree never
-    produced would misattribute an old regression to this change)."""
+    ``config`` block."""
     out: Dict[str, float] = {}
-    if not isinstance(obj, dict) or obj.get("stale") is True:
+    if not isinstance(obj, dict):
         return out
     for k, v in obj.items():
         path = f"{prefix}.{k}" if prefix else k

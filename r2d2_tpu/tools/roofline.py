@@ -17,10 +17,10 @@ What one run produces (JSON artifact + printed table):
     implied per-iteration latency at the measured step time);
   * the anakin acting program's totals + per-env-step compute.
 
-Peaks come from telemetry/costmodel.PEAK_SPECS (v5e/v5p/v4/v6 bf16+f32
-FLOP/s and HBM GB/s); the CPU backend gets a flagged NOMINAL fallback so
-the report renders on the test backend without pretending to know the
-host (override with --peak-flops / --hbm-gbps). Optionally join a
+Peaks come from telemetry/costmodel.PEAK_SPECS (keyed by device_kind; a
+device missing from it is an error); the CPU backend gets a flagged
+NOMINAL row so the report renders on the test backend without pretending
+to know the host (override with --peak-flops / --hbm-gbps). Optionally join a
 traceparse attribution summary (--trace-summary) to show measured
 device-time shares next to the analytic ones.
 

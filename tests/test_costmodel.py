@@ -236,10 +236,16 @@ def test_analytic_golden_file():
 
 
 def test_peak_spec_table():
+    # "TPU v5 lite" is the device_kind the v5e chip reports (PR 21's
+    # chip_smoke run); its row carries the published peaks
     v5e = costmodel.peak_spec("TPU v5 lite")
-    assert v5e["flops_bf16"] == 197e12 and not v5e["nominal"]
-    unknown = costmodel.peak_spec("weird accelerator")
-    assert unknown["nominal"] is True
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_gbps"] == 819.0
+    assert not v5e["nominal"]
+    # the test backend gets the flagged nominal row, never a real one
+    assert costmodel.peak_spec("cpu")["nominal"] is True
+    # any other device missing from the table is an error, not a default
+    with pytest.raises(KeyError, match="weird accelerator"):
+        costmodel.peak_spec("weird accelerator")
 
 
 # ---------------------------------------------------------------------------

@@ -157,7 +157,7 @@ def _fill_blocks(spec, n, rng, gamma=0.9):
 def test_exact_gather_padded_storage_is_transparent(rng):
     """spec.exact_gather pads the stored frame to the uint8 (32, 128)
     tile (12x12 -> 32x128 here; 84x84 -> 96x128 at reference scale; both
-    minor dims must be tile-aligned for the async-copy DMA — BENCH r4);
+    minor dims must be tile-aligned for the async-copy DMA — builders, round 4);
     the padding must be invisible end-to-end: the same blocks + same
     sample keys yield batches whose unpadded rows and every other field
     are IDENTICAL to the unpadded spec's, and the decoded observation

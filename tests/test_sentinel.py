@@ -644,8 +644,6 @@ def test_regress_extracts_watched_metrics():
     assert not any("seconds" in k for k in m)       # unwatched scalar
     assert not any("cells" in k for k in m)         # lists skipped
     assert not any("config" in k for k in m)        # config skipped
-    # stale last-good re-emissions never become gates
-    assert extract_metrics({"value": 5.0, "stale": True}) == {}
 
 
 def test_regress_gate_passes_unmodified_fails_20pct_drop(tmp_path):

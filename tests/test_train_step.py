@@ -326,7 +326,7 @@ def test_pallas_lstm_loss_parity_with_scan(rng):
 
 def test_exact_gather_train_step_loss_parity(rng):
     """The padded-storage layout (replay.pallas_exact_gather — the TPU
-    default since BENCH r4) must be invisible to TRAINING, not just to
+    default since builders, round 4) must be invisible to TRAINING, not just to
     sampling: from identical params and identically-filled replays, the
     fused step's loss trajectory on padded storage is bit-identical to
     the unpadded spec's (the decode strips the pad before any math)."""
