@@ -220,7 +220,9 @@ def run_anakin_train(cfg: Config, *, max_training_steps: Optional[int] = None,
                                                   register_buffer)
         register_buffer("p0/anakin_carry", pytree_nbytes(carry))
         if cfg.telemetry.compile_enabled and active_monitor() is None:
-            compile_mon = CompileMonitor().install()
+            # a build records a ``compile`` span under the stage open on
+            # the compiling thread: its iteration and its call
+            compile_mon = CompileMonitor(telemetry).install()
         resources = ResourceMonitor(
             0, cfg.runtime.save_dir or ".",
             interval_s=cfg.telemetry.resources_interval_s,
@@ -239,39 +241,40 @@ def run_anakin_train(cfg: Config, *, max_training_steps: Optional[int] = None,
 
     def act_segment():
         nonlocal carry
-        t0 = time.time()
-        if dp > 1:
-            # act + ring-write fused in one sharded dispatch: each
-            # shard's blocks land in its local replay without ever
-            # leaving the shard, so there is no separate commit stage
-            carry, learner.replay_state, stats = act_fn(
-                acting_params(), carry, learner.replay_state,
-                np.int32(publish_count()))
-            t1 = t2 = time.time()
-        else:
-            carry, blocks, stats = act_fn(
-                acting_params(), carry,
-                np.int32(publish_count()))
+        with telemetry.stage("actor/act_scan", lanes=num_lanes,
+                             steps=seg_steps, shards=dp):
+            if dp > 1:
+                # act + ring-write fused in one sharded dispatch: each
+                # shard's blocks land in its local replay without ever
+                # leaving the shard, so there is no separate commit stage
+                carry, learner.replay_state, stats = act_fn(
+                    acting_params(), carry, learner.replay_state,
+                    np.int32(publish_count()))
+            else:
+                carry, blocks, stats = act_fn(
+                    acting_params(), carry,
+                    np.int32(publish_count()))
+        commit_s = 0.0
+        if dp == 1:
+            # commit latency only: the acting dispatch is its own stage;
+            # folding it in would make ingest_drain_latency_ms
+            # incomparable with the host path's pop-to-commit reading.
+            # The record carries it with telemetry off too, hence the
+            # clock reads beside the stage's own.
             t1 = time.time()
-            learner.replay_state = replay_add_many(
-                spec, learner.replay_state, blocks)
-            t2 = time.time()
-            # commit latency only (t2-t1): the acting dispatch is its
-            # own stage; folding it in would make ingest_drain_latency_ms
-            # incomparable with the host path's pop-to-commit reading
-            telemetry.observe("ingest/commit", t2 - t1)
-        telemetry.observe("actor/act_scan", t1 - t0)
-        telemetry.record_span("actor/act_scan", t0, t1,
-                              {"lanes": num_lanes, "steps": seg_steps,
-                               "shards": dp})
-        wv = publish_count()
-        for _ in range(num_lanes):
-            learner.ring.advance(seg_steps, wv)
-            metrics.on_block(seg_steps, None)
-        learner.env_steps += num_lanes * seg_steps
-        metrics.set_buffer_size(learner.ring.buffer_steps)
-        metrics.on_ingest_drain(num_lanes, t2 - t1)
-        pending_stats.append(stats)
+            with telemetry.stage("ingest/commit", blocks=num_lanes):
+                learner.replay_state = replay_add_many(
+                    spec, learner.replay_state, blocks)
+            commit_s = time.time() - t1
+        with telemetry.stage("anakin/accounting"):
+            wv = publish_count()
+            for _ in range(num_lanes):
+                learner.ring.advance(seg_steps, wv)
+                metrics.on_block(seg_steps, None)
+            learner.env_steps += num_lanes * seg_steps
+            metrics.set_buffer_size(learner.ring.buffer_steps)
+            metrics.on_ingest_drain(num_lanes, commit_s)
+            pending_stats.append(stats)
 
     def flush_stats():
         if not pending_stats:
@@ -335,39 +338,64 @@ def run_anakin_train(cfg: Config, *, max_training_steps: Optional[int] = None,
         triggers.start_first_interval()
         if cfg.runtime.save_interval:
             learner.save(0)
+        iteration = 0
         while ((deadline is None or time.time() < deadline)
                and learner.training_steps < max_steps):
-            if learner.ingestion_paused:
-                # rate limiter: collection is ahead of the collect:learn
-                # budget; only train until it reopens (the gate cannot be
-                # closed here — paused implies it is open)
-                learner._note_pause(True)
-            else:
-                learner._note_pause(False)
-                scans = (cfg.actor.anakin_scans_per_train
-                         if learner.ready else 1)
-                for _ in range(scans):
-                    act_segment()
-            if learner.ready and learner.training_steps < max_steps:
-                learner.step()
-            now = time.time()
-            triggers.poll(now, learner.training_steps)
-            if resources is not None:
-                # resource sampling rides the loop at the same cheap-time-
-                # check cadence the PlayerStack's supervise pass uses
-                resources.maybe_sample(now)
-            if compile_mon is not None and learner.training_steps:
-                # warm-up ends once training has started: act_fn and the
-                # train program have compiled; any further compile of a
-                # known fn with new avals is a retrace (idempotent latch)
-                compile_mon.mark_warm()
-            if now - last_log >= cfg.runtime.log_interval:
-                learner.flush_metrics()
-                flush_stats()
-                record = metrics.log(now - last_log)
-                if log_fn:
-                    log_fn({"player": 0, **record})
-                last_log = now
+            # one root span an iteration; every statement group below is
+            # a child, so the root's self time is what no child names
+            step0, env0 = learner.training_steps, learner.env_steps
+            with telemetry.stage("anakin/iteration", iter=iteration,
+                                 step=step0, env_steps=env0) as root:
+                paused = learner.ingestion_paused
+                if paused:
+                    # rate limiter: collection is ahead of the
+                    # collect:learn budget; only train until it reopens
+                    # (the gate cannot be closed here — paused implies it
+                    # is open)
+                    learner._note_pause(True)
+                else:
+                    learner._note_pause(False)
+                    scans = (cfg.actor.anakin_scans_per_train
+                             if learner.ready else 1)
+                    for _ in range(scans):
+                        act_segment()
+                if learner.ready and learner.training_steps < max_steps:
+                    learner.step()
+                with telemetry.stage("anakin/poll"):
+                    now = time.time()
+                    triggers.poll(now, learner.training_steps)
+                    if resources is not None:
+                        # resource sampling rides the loop at the same
+                        # cheap-time-check cadence the PlayerStack's
+                        # supervise pass uses
+                        resources.maybe_sample(now)
+                    if compile_mon is not None and learner.training_steps:
+                        # warm-up ends once training has started: act_fn
+                        # and the train program have compiled; any further
+                        # compile of a known fn with new avals is a
+                        # retrace (idempotent latch)
+                        compile_mon.mark_warm()
+                # counts at the iteration's boundary, before a log_fn
+                # that may end the loop by raising
+                root.tag(env_steps_written=learner.env_steps - env0,
+                         blocks_written=((learner.env_steps - env0)
+                                         // seg_steps),
+                         train_steps=learner.training_steps - step0,
+                         paused=paused)
+                if now - last_log >= cfg.runtime.log_interval:
+                    # the device is drained by flush_metrics and nothing
+                    # is dispatched until the next iteration
+                    with telemetry.stage("anakin/log"):
+                        learner.flush_metrics()
+                        with telemetry.stage("anakin/stats_fetch"):
+                            flush_stats()
+                        with telemetry.stage("metrics/record"):
+                            record = metrics.log(now - last_log)
+                        if log_fn:
+                            with telemetry.stage("anakin/log_fn"):
+                                log_fn({"player": 0, **record})
+                        last_log = now
+            iteration += 1
         learner.flush_metrics()
         flush_stats()
     finally:

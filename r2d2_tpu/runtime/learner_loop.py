@@ -1103,46 +1103,47 @@ class Learner:
         flush_metrics() (called at log time); the step counter is
         host-mirrored. Publish/checkpoint fire when their interval boundary
         falls inside the dispatched step range."""
-        prev = self._host_step
-        t0 = time.time()
-        if self.host_mode:
-            m = self._host_step_once()
-        elif self.service is not None:
-            m = self._service_step_once()
-        else:
-            self.train_state, self.replay_state, m = self._step_fn(
-                self.train_state, self.replay_state)
-        t1 = time.time()
         tele = self.tele
-        # host-side dispatch cost (the device executes asynchronously;
-        # device occupancy is what xprof captures measure)
-        tele.observe("learner/train_dispatch", t1 - t0)
-        tele.record_span("learner/train_dispatch", t0, t1,
-                         {"k": self._k, "step": prev})
-        self._host_step += self._k
-        step = self._host_step
-        self._pending_losses.append(m["loss"])  # scalar (k=1) or (k,) array
-        if self._learning_agg is not None:
-            # hold the dispatch's ld/ outputs (device values, no sync);
-            # aggregated into the 'learning' record block at flush time
-            self._learning_agg.on_dispatch(m)
-        if self._replay_agg is not None:
-            # same contract for the rd/ outputs (replay pillar, ISSUE 10)
-            self._replay_agg.on_dispatch(m)
+        with tele.stage("learner/step"):
+            prev = self._host_step
+            # host-side dispatch cost (the device executes
+            # asynchronously; device occupancy is what xprof captures
+            # measure)
+            with tele.stage("learner/train_dispatch", k=self._k, step=prev):
+                if self.host_mode:
+                    m = self._host_step_once()
+                elif self.service is not None:
+                    m = self._service_step_once()
+                else:
+                    self.train_state, self.replay_state, m = self._step_fn(
+                        self.train_state, self.replay_state)
+            self._host_step += self._k
+            step = self._host_step
+            # scalar (k=1) or (k,) array
+            self._pending_losses.append(m["loss"])
+            if self._learning_agg is not None:
+                # hold the dispatch's ld/ outputs (device values, no
+                # sync); aggregated into the 'learning' record block at
+                # flush time
+                self._learning_agg.on_dispatch(m)
+            if self._replay_agg is not None:
+                # same contract for the rd/ outputs (replay pillar,
+                # ISSUE 10)
+                self._replay_agg.on_dispatch(m)
 
-        rt = self.cfg.runtime
-        if (self.publish is not None
-                and step // rt.weight_publish_interval
-                    > prev // rt.weight_publish_interval):
-            t0 = time.time()
-            self.publish(self.train_state.params)
-            tele.observe("weights/publish", time.time() - t0)
-        if rt.save_interval and step // rt.save_interval > prev // rt.save_interval:
-            self.save(step // rt.save_interval)
-        if (self._snap_writer is not None and rt.snapshot_interval
-                and step // rt.snapshot_interval
-                    > prev // rt.snapshot_interval):
-            self.snapshot_replay()
+            rt = self.cfg.runtime
+            if (self.publish is not None
+                    and step // rt.weight_publish_interval
+                        > prev // rt.weight_publish_interval):
+                with tele.stage("weights/publish"):
+                    self.publish(self.train_state.params)
+            if (rt.save_interval
+                    and step // rt.save_interval > prev // rt.save_interval):
+                self.save(step // rt.save_interval)
+            if (self._snap_writer is not None and rt.snapshot_interval
+                    and step // rt.snapshot_interval
+                        > prev // rt.snapshot_interval):
+                self.snapshot_replay()
         return m
 
     def _capture_replay(self) -> dict:
@@ -1249,29 +1250,29 @@ class Learner:
                 "serial_chain": costs["serial_chain"],
             })
         if self._pending_losses:
-            t0 = time.time()
-            arrays = jax.device_get(self._pending_losses)
-            t1 = time.time()
-            self.tele.observe("learner/device_sync", t1 - t0)
-            self.tele.record_span("learner/device_sync", t0, t1,
-                                  {"losses": len(self._pending_losses)})
+            with self.tele.stage("learner/device_sync",
+                                 losses=len(self._pending_losses)):
+                arrays = jax.device_get(self._pending_losses)
             self._pending_losses.clear()
             for loss in np.concatenate([np.atleast_1d(a) for a in arrays]):
                 self.metrics.on_train_step(float(loss))
-        if self._learning_agg is not None:
-            pub = (int(self.weight_version_fn())
-                   if self.weight_version_fn is not None else None)
-            self.metrics.set_learning(self._learning_agg.flush(
-                self._host_step, publish_count=pub,
-                occupancy_versions=self.ring.live_versions()))
-        if self._replay_agg is not None:
-            # host placement: the HostReplay numpy twin supplies the
-            # sum-tree health + eviction snapshot the external-batch step
-            # cannot form in-graph (ISSUE 10)
-            host_stats = (self.host_replay.diag_raw()
-                          if self.host_mode else None)
-            self.metrics.set_replay_diag(
-                self._replay_agg.flush(host_stats=host_stats))
+        # the aggregators fetch their own device values: a second and a
+        # third transfer at every log boundary, after the sync above
+        with self.tele.stage("learner/diag_flush"):
+            if self._learning_agg is not None:
+                pub = (int(self.weight_version_fn())
+                       if self.weight_version_fn is not None else None)
+                self.metrics.set_learning(self._learning_agg.flush(
+                    self._host_step, publish_count=pub,
+                    occupancy_versions=self.ring.live_versions()))
+            if self._replay_agg is not None:
+                # host placement: the HostReplay numpy twin supplies the
+                # sum-tree health + eviction snapshot the external-batch step
+                # cannot form in-graph (ISSUE 10)
+                host_stats = (self.host_replay.diag_raw()
+                              if self.host_mode else None)
+                self.metrics.set_replay_diag(
+                    self._replay_agg.flush(host_stats=host_stats))
 
     def save(self, index: int) -> str:
         ts = self.train_state

@@ -30,6 +30,11 @@ burst. Late FIRST compiles (a new function after warm-up, e.g. an
 odd-size stager bucket) count as ``late_compiles`` — noteworthy, but not
 a retrace.
 
+Given a ``Telemetry``, every backend compile also records a ``compile``
+span (tag ``fn``: the name the log line gave on that thread just before)
+under whatever stage is open on the compiling thread, so a build inside
+a measured window names its iteration and its call.
+
 One monitor per process (module-level active slot): jax.monitoring has
 no per-listener unregister, so ONE dispatching listener is registered on
 first install and routes to whichever monitor is active.
@@ -98,7 +103,9 @@ class CompileMonitor:
 
     MAX_RETRACE_LOG = 32      # retained retrace events (newest kept)
 
-    def __init__(self):
+    def __init__(self, telemetry=None):
+        self._telemetry = telemetry    # where ``compile`` spans go
+        self._compiling = threading.local()   # .fn: the pxla line's name
         self._lock = threading.Lock()
         self.compiles = 0              # backend compiles (monitoring event)
         self.compile_time_s = 0.0
@@ -118,8 +125,15 @@ class CompileMonitor:
         with self._lock:
             self.compiles += 1
             self.compile_time_s += float(duration)
+        if self._telemetry is not None:
+            # the listener runs on the compiling thread, as the build ends
+            now = time.time()
+            self._telemetry.record_span(
+                "compile", now - duration, now,
+                {"fn": getattr(self._compiling, "fn", None)})
 
     def _on_compile(self, name: str, avals: str) -> None:
+        self._compiling.fn = name
         with self._lock:
             self.traced_compiles += 1
             seen = self._signatures.setdefault(name, set())

@@ -7,13 +7,18 @@ cheap no-op (one attribute check); the module-level NULL_TELEMETRY serves
 call sites that received no telemetry at all, so instrumented code never
 branches on None. Overhead with telemetry ON is budgeted < 2% env-steps/s
 (tools/e2e_bench.py --telemetry-ab measures it; PERF.md records the A/B).
+
+``Telemetry.stage(name)`` is the one entry point of a timed block: the
+stage histogram (where ``name`` is one of STAGES) and the nested span
+(spans.py) from one pair of clock reads. ``observe``/``record_span`` stay
+for sites that time themselves (host actors, serving, multihost).
 """
 
 import json
 import os
 import threading
 import time
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -93,6 +98,55 @@ def summarize_matrix(matrix: np.ndarray) -> Dict[str, Dict[str, float]]:
     return out
 
 
+class _Stage:
+    """One timed block (``Telemetry.stage``): a ``with`` target that can
+    take more tags before it closes (``tag``)."""
+
+    __slots__ = ("_tele", "_name", "_iter", "_tags", "_span", "_t0")
+
+    def __init__(self, tele: "Telemetry", name: str, iter: Any, tags: dict):
+        self._tele, self._name, self._iter, self._tags = (tele, name, iter,
+                                                          tags)
+        self._span = None
+
+    def tag(self, **tags) -> None:
+        self._tags.update(tags)
+
+    def __enter__(self) -> "_Stage":
+        tracer = self._tele.spans
+        if tracer.enabled:
+            self._span = tracer.begin(self._name, self._iter, self._tags)
+        else:
+            self._t0 = time.time()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._span is not None:
+            seconds = self._tele.spans.end(self._span)
+        else:
+            seconds = time.time() - self._t0
+        if self._name in STAGE_INDEX:
+            self._tele.timers.observe(self._name, seconds)
+
+
+class _NullStage:
+    """What ``stage`` hands out when there is nothing to time."""
+
+    __slots__ = ()
+
+    def tag(self, **tags) -> None:
+        pass
+
+    def __enter__(self) -> "_NullStage":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_STAGE = _NullStage()
+
+
 class Telemetry:
     """One per process. ``board``/``slot``: publication target for worker
     processes (the owner side instead passes the board to
@@ -138,8 +192,16 @@ class Telemetry:
                     tags: Optional[dict] = None) -> None:
         self.spans.record(name, t_start, t_end, tags)
 
-    def span(self, name: str, **tags):
-        return self.spans.span(name, **tags)
+    def stage(self, name: str, *, iter: Any = None, **tags):
+        """Time the ``with`` block once: into the stage histogram where
+        ``name`` is one of STAGES, and as a span under the one open on
+        this thread. A root names its ``iter`` (children inherit it).
+        Disabled: one attribute check, no clock read; ``spans=false``
+        alone: the histogram only."""
+        if not self.enabled or not (self.spans.enabled
+                                    or name in STAGE_INDEX):
+            return _NULL_STAGE
+        return _Stage(self, name, iter, tags)
 
     # -- publication / aggregation --
 

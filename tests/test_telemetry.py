@@ -162,18 +162,143 @@ def test_span_tracer_ring_drops_oldest():
 def test_span_tracer_disabled_is_noop():
     tr = SpanTracer(ring_size=16, enabled=False)
     tr.record("a", 0.0, 1.0)
-    with tr.span("b"):
-        pass
     assert tr.drain() == []
+    # spans off alone: a stage still feeds its histogram row, records no
+    # span, and a name outside STAGES has nothing left to do
+    tele = Telemetry(spans=False)
+    with tele.stage("actor/act_scan", lanes=2):
+        pass
+    with tele.stage("anakin/poll"):
+        pass
+    assert tele.spans.drain() == []
+    assert tele.interval_summary()["actor/act_scan"]["count"] == 1
 
 
-def test_span_context_manager_records_on_raise():
-    tr = SpanTracer(ring_size=16)
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts what is open."""
+    open_now = 0
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    def __enter__(self):
+        type(self).open_now += 1
+        return self
+
+    def __exit__(self, *exc):
+        type(self).open_now -= 1
+
+
+def test_stage_closes_span_and_annotation_on_raise(monkeypatch):
+    tele = Telemetry()
+    monkeypatch.setattr(_FakeAnnotation, "open_now", 0)
+    tele.spans._annotate = _FakeAnnotation
     with pytest.raises(RuntimeError):
-        with tr.span("boom", slot=3):
-            raise RuntimeError("x")
-    (ev,) = tr.drain()
-    assert ev["name"] == "boom" and ev["tags"] == {"slot": 3}
+        with tele.stage("anakin/iteration", iter=4):
+            with tele.stage("boom", slot=3):
+                assert _FakeAnnotation.open_now == 2
+                raise RuntimeError("x")
+    assert _FakeAnnotation.open_now == 0
+    assert tele.spans._local.stack == []
+    boom, root = sorted(tele.spans.drain(), key=lambda e: -e["id"])
+    assert boom["name"] == "boom" and boom["tags"] == {"slot": 3}
+    assert boom["parent"] == root["id"] and boom["iter"] == 4
+    # the next span on this thread is a root again
+    with tele.stage("after"):
+        pass
+    (after,) = tele.spans.drain()
+    assert after["parent"] is None and after["iter"] is None
+
+
+def test_stage_nests_parent_iter_self_across_two_threads():
+    tele = Telemetry()
+    barrier = threading.Barrier(2)
+
+    def loop(iteration):
+        barrier.wait(timeout=10)      # both roots open at once
+        with tele.stage("anakin/iteration", iter=iteration) as root:
+            with tele.stage("actor/act_scan", lanes=1):
+                time.sleep(0.02)
+                barrier.wait(timeout=10)
+            with tele.stage("learner/step"):
+                with tele.stage("learner/train_dispatch"):
+                    time.sleep(0.01)
+                # a span that is over already (the compile listener's
+                # path) hangs under the open one
+                now = time.time()
+                tele.record_span("compile", now - 0.004, now, {"fn": "f"})
+            time.sleep(0.005)
+            root.tag(train_steps=1)
+
+    threads = [threading.Thread(target=loop, args=(i,), name=f"loop{i}")
+               for i in (10, 11)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    events = tele.spans.drain()
+    assert len({e["id"] for e in events}) == len(events) == 10
+    for i in (10, 11):
+        mine = {e["name"]: e for e in events if e["tid"] == f"loop{i}"}
+        root, step = mine["anakin/iteration"], mine["learner/step"]
+        assert root["parent"] is None and root["tags"] == {"train_steps": 1}
+        assert all(e["iter"] == i for e in mine.values())
+        assert mine["actor/act_scan"]["parent"] == root["id"]
+        assert step["parent"] == root["id"]
+        assert mine["learner/train_dispatch"]["parent"] == step["id"]
+        assert mine["compile"]["parent"] == step["id"]
+        # self = duration less what the direct children cover
+        assert root["self"] == pytest.approx(
+            root["dur"] - mine["actor/act_scan"]["dur"] - step["dur"])
+        assert 0.004 < root["self"] < root["dur"]
+        assert step["self"] == pytest.approx(
+            step["dur"] - mine["learner/train_dispatch"]["dur"]
+            - mine["compile"]["dur"])
+        leaf = mine["learner/train_dispatch"]
+        assert leaf["self"] == pytest.approx(leaf["dur"])
+    # the stage histogram was fed where the name is one of STAGES, only
+    summary = tele.interval_summary()
+    assert summary["actor/act_scan"]["count"] == 2
+    assert summary["learner/train_dispatch"]["count"] == 2
+    assert "anakin/iteration" not in summary
+
+
+def test_stage_disabled_reads_no_clock(monkeypatch):
+    from r2d2_tpu.telemetry import core, spans
+
+    def no_clock():
+        raise AssertionError("a disabled stage read the clock")
+
+    off = Telemetry(enabled=False)
+    monkeypatch.setattr(core.time, "time", no_clock)
+    monkeypatch.setattr(spans.time, "time", no_clock)
+    with off.stage("actor/act_scan", iter=1, lanes=2) as st:
+        st.tag(more=1)
+    with NULL_TELEMETRY.stage("learner/step"):
+        pass
+    assert off.spans.drain() == []
+
+
+def test_stage_jsonl_row_keeps_old_keys_and_gains_the_tree(tmp_path):
+    tele = Telemetry(name="anakin-p0", flush_interval_s=60.0)
+    path = str(tmp_path / "spans_player0.jsonl")
+    tele.start_drain(path)
+    t0 = time.time()
+    with tele.stage("anakin/iteration", iter=0, step=16):
+        with tele.stage("actor/act_scan", lanes=64):
+            pass
+    tele.close()
+    root, child = parse_jsonl(path)
+    # what benchmarks/runners/anakin.py and tools/inspect.py read
+    assert {"name", "ts", "dur", "tid", "tags", "pid"} <= set(child)
+    assert child["pid"] == "anakin-p0" and child["tid"] == "MainThread"
+    assert child["tags"] == {"lanes": 64}
+    assert t0 <= root["ts"] <= child["ts"] <= time.time()   # unix seconds
+    assert child["ts"] + child["dur"] <= root["ts"] + root["dur"]
+    assert set(child) - {"name", "ts", "dur", "tid", "tags", "pid"} == {
+        "id", "parent", "iter", "self"}
+    assert (child["parent"], child["iter"]) == (root["id"], 0)
 
 
 def test_span_tracer_prunes_dead_thread_rings():
@@ -275,8 +400,9 @@ def test_telemetry_facade_merges_local_and_board():
 def test_null_telemetry_is_inert():
     NULL_TELEMETRY.observe("actor/env_step", 1.0)
     NULL_TELEMETRY.record_span("x", 0.0, 1.0)
-    with NULL_TELEMETRY.span("y"):
-        pass
+    with NULL_TELEMETRY.stage("y", iter=0) as st:
+        st.tag(z=1)
+    assert NULL_TELEMETRY.spans.drain() == []
     assert NULL_TELEMETRY.interval_summary() == {}
     assert not NULL_TELEMETRY.enabled
 
@@ -310,6 +436,8 @@ def test_chrome_trace_events_schema():
     x = [e for e in events if e["ph"] == "X"]
     meta = [e for e in events if e["ph"] == "M"]
     assert len(x) == 1
+    # the tree rides in args beside the tags (a root: no parent, no iter)
+    assert x[0]["args"] == {"slot": 0, "id": 1}
     assert x[0]["ts"] == pytest.approx(1.0e6)
     assert x[0]["dur"] == pytest.approx(0.5e6)
     assert x[0]["pid"] == 3
@@ -323,13 +451,15 @@ def test_export_chrome_trace_merges_files(tmp_path):
             for i in range(3):
                 f.write(json.dumps({
                     "name": "actor/block_emit", "ts": 100.0 + i,
-                    "dur": 0.5, "tid": "t", "pid": proc}) + "\n")
+                    "dur": 0.5, "tid": "t", "pid": proc, "id": 10 + i,
+                    "parent": 9, "iter": 4, "self": 0.5}) + "\n")
     out = str(tmp_path / "trace.json")
     n = export_chrome_trace(str(tmp_path), out)
     assert n == 6
     trace = json.load(open(out))
     x = [e for e in trace["traceEvents"] if e["ph"] == "X"]
     assert len(x) == 6
+    assert x[0]["args"] == {"id": 10, "parent": 9, "iter": 4}
     assert len({e["pid"] for e in x}) == 2   # one pid row per process
 
 
@@ -551,6 +681,201 @@ def test_render_record_without_telemetry():
     from r2d2_tpu.tools.inspect import render_record
     frame = render_record({"t": 1.0})
     assert "telemetry.enabled" in frame
+
+
+# ---------------------------------------------------------------------------
+# the fused loop's span tree: one tiny run_anakin_train under a CPU
+# jax.profiler capture, with a retrace forced from log_fn
+
+def test_compile_monitor_hangs_a_build_under_the_open_stage():
+    from r2d2_tpu.telemetry import CompileMonitor
+    tele = Telemetry()
+    mon = CompileMonitor(tele)          # not installed: the callbacks alone
+    with tele.stage("anakin/iteration", iter=7):
+        with tele.stage("learner/train_dispatch"):
+            mon._on_compile("jit(step)", "f32[8]")
+            t0 = time.time()
+            mon._on_backend_compile(0.25)
+    spans = {e["name"]: e for e in tele.spans.drain()}
+    build = spans["compile"]
+    assert build["tags"] == {"fn": "jit(step)"}
+    assert build["dur"] == pytest.approx(0.25)
+    assert build["ts"] + build["dur"] == pytest.approx(t0, abs=0.05)
+    assert build["parent"] == spans["learner/train_dispatch"]["id"]
+    assert build["iter"] == 7
+    assert mon.compiles == 1
+    # without a Telemetry it only counts, as before
+    CompileMonitor()._on_backend_compile(0.1)
+
+
+ITERATION_CHILDREN = ["actor/act_scan", "ingest/commit", "anakin/accounting",
+                      "learner/step", "anakin/poll", "anakin/log"]
+LOG_CHILDREN = ["learner/device_sync", "learner/diag_flush",
+                "anakin/stats_fetch", "metrics/record", "anakin/log_fn"]
+RETRACE_AT = 12      # the log_fn call (= iteration) that forces the retrace
+
+
+@pytest.fixture(scope="module")
+def fused_loop_run(tmp_path_factory):
+    """One run of the fused loop, small enough for the CPU, that logs at
+    every iteration: each iteration then ends on ``device_sync``, so its
+    children hold the device's work and not only the enqueues."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from r2d2_tpu.config import Config
+    from r2d2_tpu.runtime.anakin_loop import run_anakin_train
+
+    out = tmp_path_factory.mktemp("fused_loop")
+    lanes, block = 8, 40
+    cfg = Config().replace(**{
+        "env.game_name": "Fake", "env.frame_height": 24,
+        "env.frame_width": 24, "env.frame_stack": 2,
+        "env.episode_len": block,
+        "network.hidden_dim": 16, "network.cnn_out_dim": 32,
+        "network.conv_layers": ((8, 4, 2), (16, 3, 1)),
+        "sequence.burn_in_steps": 4, "sequence.learning_steps": 5,
+        "sequence.forward_steps": 3,
+        "replay.capacity": 1600, "replay.block_length": block,
+        "replay.batch_size": 16, "replay.learning_starts": 160,
+        "actor.on_device": True, "actor.anakin_lanes": lanes,
+        "runtime.save_interval": 0, "runtime.save_dir": str(out),
+        "runtime.log_interval": 1e-6,
+    })
+    probe = jax.jit(lambda x: x * 2 + 1)
+    records = []
+
+    def log_fn(record):
+        # the n-th call comes from iteration n: every iteration logs
+        if len(records) == 0:
+            probe(np.zeros(2, np.float32))
+        elif len(records) == RETRACE_AT:
+            probe(np.zeros(3, np.float32))       # same function, new shape
+        records.append(record)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(out / "trace"), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test_clock",
+                                          unix_ns=time.time_ns()):
+            pass
+        stacks = run_anakin_train(cfg, max_training_steps=30,
+                                  max_seconds=300, log_fn=log_fn)
+    finally:
+        jax.profiler.stop_trace()
+    (capture,) = glob.glob(str(out / "trace" / "**" / "*.xplane.pb"),
+                           recursive=True)
+    host_events = []
+    for plane in ProfileData.from_file(capture).planes:
+        if plane.name == "/host:CPU":
+            host_events += [(e.name, e.start_ns, dict(e.stats))
+                            for line in plane.lines for e in line.events
+                            if "/" in e.name or e.name == "test_clock"]
+    return {"rows": parse_jsonl(str(out / "spans_player0.jsonl")),
+            "records": records, "host_events": host_events,
+            "learner": stacks[0].learner, "lanes": lanes, "block": block}
+
+
+def test_fused_loop_iteration_is_tiled_by_its_children(fused_loop_run):
+    rows, records = fused_loop_run["rows"], fused_loop_run["records"]
+    kids = {}
+    for r in rows:
+        kids.setdefault(r["parent"], []).append(r)
+    roots = [r for r in rows if r["name"] == "anakin/iteration"]
+    assert [r["iter"] for r in roots] == list(range(len(roots)))
+    assert len(roots) == len(records) >= 30
+    assert all(r["parent"] is None for r in roots)
+    trained = [r for r in roots if r["tags"]["train_steps"]]
+    assert len(trained) >= 30
+    for root in trained:
+        names = [k["name"] for k in sorted(kids[root["id"]],
+                                           key=lambda k: k["ts"])]
+        assert names == ITERATION_CHILDREN
+        by = {k["name"]: k for k in kids[root["id"]]}
+        assert [k["name"] for k in sorted(
+            kids[by["anakin/log"]["id"]], key=lambda k: k["ts"])
+        ] == LOG_CHILDREN
+        assert [k["name"] for k in kids[by["learner/step"]["id"]]
+                ] == ["learner/train_dispatch"]
+        # everything under a root carries its identifier
+        assert all(k["iter"] == root["iter"] for k in kids[root["id"]])
+        # counts at the same boundaries
+        tags = root["tags"]
+        assert tags["env_steps_written"] == (fused_loop_run["lanes"]
+                                             * fused_loop_run["block"])
+        assert tags["blocks_written"] == fused_loop_run["lanes"]
+        assert tags["train_steps"] == 1 and tags["paused"] is False
+        assert root["self"] == pytest.approx(
+            root["dur"] - sum(k["dur"] for k in kids[root["id"]]))
+    # the counters at a root's start chain through the iterations
+    for a, b in zip(roots, roots[1:]):
+        assert b["tags"]["env_steps"] == (a["tags"]["env_steps"]
+                                          + a["tags"]["env_steps_written"])
+        assert b["tags"]["step"] == a["tags"]["step"] + a["tags"]["train_steps"]
+    learner = fused_loop_run["learner"]
+    assert (roots[-1]["tags"]["step"] + roots[-1]["tags"]["train_steps"]
+            == learner.training_steps)
+    # a coverage count, not a speed: the first two iterations compile
+    steady = roots[2:]
+    covered = [1.0 - r["self"] / r["dur"] for r in steady]
+    assert sum(c >= 0.95 for c in covered) >= 0.9 * len(steady), sorted(
+        covered)[:5]
+    # names that are stages kept their histogram rows, new names have none
+    stages = set()
+    for record in records:
+        stages |= set(record["stages"])
+    assert {"actor/act_scan", "ingest/commit", "learner/train_dispatch",
+            "learner/device_sync"} <= stages
+    assert not stages - set(STAGES)
+
+
+def test_fused_loop_spans_stand_on_the_profilers_clock(fused_loop_run):
+    rows, events = fused_loop_run["rows"], fused_loop_run["host_events"]
+    (clock,) = [e for e in events if e[0] == "test_clock"]
+    offset_ns = clock[1] - clock[2]["unix_ns"]
+    on_plane = {stats["id"]: (name, start, stats)
+                for name, start, stats in events if "id" in stats}
+    assert {name for name, _, _ in on_plane.values()} >= set(
+        ["anakin/iteration"] + ITERATION_CHILDREN + LOG_CHILDREN
+        + ["learner/train_dispatch"])
+    late_ms = []
+    for row in rows:
+        if row["name"] == "compile":     # over before it is recorded
+            continue
+        name, start_ns, stats = on_plane[row["id"]]
+        assert name == row["name"]
+        assert stats.get("parent") == row["parent"]
+        assert stats.get("iter") == row["iter"]
+        late_ms.append(abs(row["ts"] * 1e9 + offset_ns - start_ns) / 1e6)
+    late_ms.sort()
+    assert len(late_ms) > 300
+    assert late_ms[len(late_ms) // 2] < 1.0
+    assert late_ms[int(0.9 * len(late_ms))] < 1.0
+
+
+def test_fused_loop_retrace_names_its_iteration_and_call(fused_loop_run):
+    rows = fused_loop_run["rows"]
+    by_id = {r["id"]: r for r in rows}
+    builds = [r for r in rows if r["name"] == "compile"]
+    # warm-up's builds sit in the iterations that made them
+    assert {by_id[b["parent"]]["name"] for b in builds
+            if b["iter"] < RETRACE_AT} >= {
+        "actor/act_scan", "ingest/commit", "learner/train_dispatch"}
+    (retrace,) = [b for b in builds if b["iter"] >= RETRACE_AT]
+    assert retrace["iter"] == RETRACE_AT
+    assert "lambda" in retrace["tags"]["fn"]
+    hook = by_id[retrace["parent"]]
+    assert hook["name"] == "anakin/log_fn"
+    root = by_id[by_id[hook["parent"]]["parent"]]
+    assert root["name"] == "anakin/iteration" and root["iter"] == RETRACE_AT
+    assert hook["ts"] <= retrace["ts"] + 1e-3
+    assert retrace["ts"] + retrace["dur"] <= hook["ts"] + hook["dur"] + 1e-3
+    # the monitor's own account agrees: one retrace, after warm-up
+    last = fused_loop_run["records"][-1]["resources"]["compile"]
+    assert last["retraces_total"] == 1
 
 
 # ---------------------------------------------------------------------------
