@@ -224,12 +224,16 @@ class OptimConfig:
     # backend is TPU — the measured winner there, builders, round 3; the XLA
     # gather path is the correct-everywhere fallback).
     pallas_obs_decode: str = "auto"
-    # Pallas decode output layout: "planar" emits (B,T,K,H,W) + an outer
-    # transpose (the measured round-3 design; the transpose is a ~1.6
-    # ms/step HBM layout copy in the profile); "nhwc" interleaves K into
-    # the lane dim in-kernel so the (B,T,H,W,K) contract is a free
-    # reshape. Default planar pending the TPU A/B (bench.py measures an
-    # nhwc-decode cell).
+    # Pallas decode output layout. "planar" (the default) is whatever the
+    # TPU path emits by shape (ops/pallas_kernels.py decode_route): where
+    # the batch tiles the 128 lanes and storage is tile-padded (the exact
+    # gather's), the kernel writes the first convolution's own layout,
+    # frame index in lanes and the K planes in the tile's sublanes, so XLA
+    # copies nothing between the kernel and the convolution (PR 26: the
+    # (B,T,K,H,W) planar array padded 84x84 -> 96x128 and the 1.7 ms
+    # layout copy behind it are gone); other shapes keep the planar
+    # (B,T,K,H,W) kernel + outer transpose. "nhwc" interleaves K into the
+    # lane dim in-kernel (measured a loss; kept for bench.py's cell).
     pallas_decode_layout: str = "planar"
     # Double-DQN only: run the online and target unrolls interleaved in ONE
     # lax.scan instead of two sequential while-loops (which XLA cannot

@@ -81,13 +81,18 @@ def _decode_inputs(net: NetworkApply, spec: ReplaySpec, batch: SampleBatch,
                    use_pallas: bool,
                    nhwc: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """THE storage→network decode (one place for every unroll path): uint8
-    frame rows → stacked normalized obs (B,T,H,W,K) (fused pallas kernel on
-    TPU, jnp gather elsewhere — ops/pallas_kernels.py; out_height/out_width
-    strip any exact-gather storage tile padding), action indices → one-hot
-    (-1 encodes the null action as zeros). Decodes directly into the network's compute
-    dtype: under the bf16 policy this skips materializing the 4x-larger f32
-    obs intermediate that XLA would cast at the conv boundary anyway
-    (PERF.md profile: that transpose+cast copy was ~2.5 ms/step)."""
+    frame rows → stacked normalized obs of logical shape (B,T,H,W,K), action
+    indices → one-hot (-1 encodes the null action as zeros). Which decode
+    runs follows from the input's shapes (ops/pallas_kernels.py
+    decode_route): on TPU the Pallas kernel that writes the first
+    convolution's own layout and hands over ``LaneFrames`` (the torso's
+    batch, frame index in lanes, nothing for XLA to copy in between) where
+    the batch tiles the lanes and storage is tile-padded, the planar Pallas
+    kernel for other shapes, the jnp gather elsewhere; out_height/out_width
+    strip any exact-gather storage tile padding. Decodes directly into the
+    network's compute dtype: under the bf16 policy this skips materializing
+    the 4x-larger f32 obs intermediate that XLA would cast at the conv
+    boundary anyway."""
     from r2d2_tpu.ops.pallas_kernels import stack_frames
     with jax.named_scope("obs_decode"):
         stacked = stack_frames(batch.obs, spec.seq_window, spec.frame_stack,

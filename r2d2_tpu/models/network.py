@@ -248,6 +248,22 @@ class HoistedLSTM(nn.Module):
         return carry, outputs.swapaxes(0, 1)                  # (B, T, H)
 
 
+def _torso_batch(obs_seq, dtype):
+    """The torso's batch of frames and the way back from it: (frames
+    (N, H, W, K), rows (N, D) -> (B, T, D)). A (B, T, H, W, K) array
+    flattens sequence by sequence; ``LaneFrames`` (the TPU decode,
+    ops/pallas_kernels.py) are flattened already, in the order that puts
+    the frame index in the first convolution's lanes, and know the way
+    back. The torso treats frames one by one, so the order changes no
+    value."""
+    from r2d2_tpu.ops.pallas_kernels import LaneFrames
+    if isinstance(obs_seq, LaneFrames):
+        return obs_seq.frames.astype(dtype), obs_seq.sequence
+    batch, seq = obs_seq.shape[0], obs_seq.shape[1]
+    return (obs_seq.astype(dtype).reshape(batch * seq, *obs_seq.shape[2:]),
+            lambda rows: rows.reshape(batch, seq, rows.shape[-1]))
+
+
 class R2D2Network(nn.Module):
     """The full recurrent Q-network.
 
@@ -266,7 +282,8 @@ class R2D2Network(nn.Module):
     @nn.compact
     def __call__(
         self,
-        obs_seq: jnp.ndarray,       # (B, T, H, W, stack) normalized [0,1]
+        obs_seq: jnp.ndarray,       # (B, T, H, W, stack) normalized [0,1],
+                                    # or LaneFrames of that logical shape
         last_action_seq: jnp.ndarray,  # (B, T, action_dim) one-hot f32
         hidden: jnp.ndarray,        # (B, 2, hidden_dim) packed
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -290,11 +307,11 @@ class R2D2Network(nn.Module):
         # jax.named_scope, so every HLO op carries the component in its
         # op_name metadata and xprof traces attribute device time per
         # component (telemetry/traceparse.py keys on these exact tokens).
-        flat = obs_seq.astype(dtype).reshape(batch * seq, *obs_seq.shape[2:])
+        flat, to_sequence = _torso_batch(obs_seq, dtype)
         latent = ConvTorso(cfg.cnn_out_dim, cfg.conv_layers, dtype,
                            space_to_depth=cfg.space_to_depth,
                            name="torso")(flat)
-        latent = latent.reshape(batch, seq, cfg.cnn_out_dim)
+        latent = to_sequence(latent)
 
         rnn_in = jnp.concatenate(
             [latent, last_action_seq.astype(dtype)], axis=-1
@@ -347,7 +364,7 @@ def dual_sequence_q(net: "NetworkApply", params_a, params_b,
     dtype = net.module.compute_dtype
     batch, seq = obs_seq.shape[0], obs_seq.shape[1]
 
-    flat = obs_seq.astype(dtype).reshape(batch * seq, *obs_seq.shape[2:])
+    flat, to_sequence = _torso_batch(obs_seq, dtype)
     torso = ConvTorso(cfg.cnn_out_dim, cfg.conv_layers, dtype,
                       space_to_depth=cfg.space_to_depth)
     # explicit component scopes: unlike the module path, these raw
@@ -360,8 +377,7 @@ def dual_sequence_q(net: "NetworkApply", params_a, params_b,
     la = last_action_seq.astype(dtype)
 
     def rnn_in(lat):
-        return jnp.concatenate([lat.reshape(batch, seq, cfg.cnn_out_dim), la],
-                               axis=-1)
+        return jnp.concatenate([to_sequence(lat), la], axis=-1)
 
     def lstm_bits(p):
         lp = p["params"]["lstm"]

@@ -1,39 +1,65 @@
 """Pallas TPU kernels for the learner's data-decode hot path.
 
 ``stack_frames``: expand a raw uint8 frame row into the frame-stacked,
-normalized f32 observation tensor the conv torso consumes:
+normalized observation tensor the conv torso consumes, in the network's
+compute dtype:
 
-    obs (B, T+K-1, H, W) uint8  →  (B, T, H, W, K) float32 in [0, 1]
+    obs (B, T+K-1, H, W) uint8  →  (B, T, H, W, K) in [0, 1]
     out[b, t, h, w, k] = obs[b, t + k, h, w] / 255
 
 This is the reference learner's obs_idx gather + /255
 (/root/reference/worker.py:310,330-331) — a pure data-movement + elementwise
-op. The XLA lowering of the jnp version materializes the (B, T, K, H, W)
-uint8 gather, then a transposed f32 copy (5x the input bytes through HBM);
-the pallas kernel streams each batch row through VMEM once and emits the
-stacked f32 directly, fusing window expansion, transpose, dtype conversion,
-and normalization.
+op: normalised in f32, rounded once into the compute dtype. Which
+implementation runs follows from the input's shapes (``decode_route``).
 
-Grid: (batch, seq_window), t fastest. The input spec maps every t to the
-same uint8 row block, so Pallas's revisiting optimization DMAs each row
-into VMEM once per batch index and the K-frame windows are VMEM slices;
-the output streams one timestep slab per program.
+What the TPU path emits (PR 26): ``stack_frames_lanes`` writes the stacked
+observations in the layout the first convolution reads, and hands them over
+as ``LaneFrames`` — the torso's batch, already flattened. XLA runs the whole
+torso with the FRAME INDEX IN LANES: minor to major N, K, W, H, tile (4, 128)
+with two bf16 rows to a 32-bit word, so the K = 4 planes fill a tile's
+sublanes and 128 frames its lanes (bf16[7040,84,84,4]{0,3,2,1:T(4,128)(2,1)}
+at B128 x T55, 397 MB). The kernel's output (H, W, tile, K, 128) has those
+very bytes, XLA's transpose of it is a bitcast, and nothing is copied
+between the kernel and the convolution. Before, the planar kernel below
+wrote (B, T, K, H, W) with (84, 84) minor, padded to 96 x 128 (692 MB), and
+a ``network_glue`` copy read that and wrote the 397 MB: 3.15 ms a step for
+what now takes 0.6 (my chip runs, PR 26).
 
-Layout note (measured, round 3): the kernel emits (B, T, K, H, W) — K
+How: a lane tile is 128 frames of one time step — 128 sequences' step t
+where the batch fills the lanes, or step i of each of 128/B time segments
+where it does not (``lane_order``; the network brings the latent back to
+(B, T, D), ``LaneFrames.sequence``). Plane k of tile i is stored step i + k
+of those frames, so the kernel walks the stored steps once: 128 frames' rows
+arrive by DMA as 32-bit words (four stored rows a word), one sublane-strided
+load puts a word row of all 128 frames on sublanes, a 128 x 128 transpose
+puts the frames on lanes, each byte is normalised and rounded (to bf16 by
+hand, in integer arithmetic: two planes share a word), kept in VMEM for the
+K - 1 tiles that still need it, and written with 32-bit strided stores.
+Mosaic took this route as written; what it had refused before (PERF.md §6:
+16-bit strided stores, lane-merging reshapes, a trailing K = 4) is not on
+it. The kernel's lowering is the same size for every window and batch: time,
+rows and segments loop in the grid or a ``fori_loop``.
+
+Layout note (measured, round 3), for the planar kernel
+``stack_frames_pallas``, which stays the Pallas path of shapes the lanes
+route does not take (a batch that does not tile 128 lanes, storage that is
+not tile-padded, an odd stack in bf16): it emits (B, T, K, H, W) — K
 *before* the spatial dims — and the wrapper transposes to the public
 (B, T, H, W, K) contract outside the kernel. Emitting K minor-most
 directly is catastrophic on TPU: the (8, 128) register tile pads the
 trailing (84, 4) dims to (88, 128), inflating the HBM buffer 32x (26 GB
-at batch 128) and a full-window VMEM block to 416 MB. With (84, 84)
-minor the padding is 1.6x and the per-timestep VMEM slab is ~180 KB; the
-explicit transpose lands inside the jitted train step where XLA folds it
-into its own layout assignment for the conv torso. No custom VJP is
-needed: observations carry no gradient (grads flow to params only).
+at batch 128). With (84, 84) minor the padding is 1.6x and the
+per-timestep VMEM slab is ~180 KB; XLA then pays the layout copy in front
+of the conv torso. Grid (batch, seq_window), t fastest: the input spec
+maps every t to the same uint8 row block, so each row is DMA'd once per
+batch index and the K-frame windows are VMEM slices. No custom VJP is
+needed anywhere here: observations carry no gradient.
 
 ``stack_frames_reference`` is the jnp twin — the test oracle and the
 non-TPU fallback.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -227,6 +253,295 @@ def stack_frames_pallas_nhwc(obs: jnp.ndarray, seq_window: int,
                                out_width=out_width)
 
 
+# ---------------------------------------------------------------------------
+# Frame-in-lanes decode: the first convolution's own layout.
+
+LANES = 128          # lanes of a vector register: frames of one lane tile
+_SUBLANE_ROWS = 32   # stored uint8 rows of one (32, 128) tile: an input block
+_WORD_ROWS = _SUBLANE_ROWS // 4   # the same block as rows of 32-bit words
+
+
+def lane_order(batch: int, seq_window: int):
+    """(columns, group, steps) of the lane order, or None where the batch
+    does not tile the lanes. A lane tile holds 128 frames: one time step of
+    a column of 128 sequences (group = 1), or, of a batch under 128, one
+    step of each of ``group`` time segments ``steps`` steps long. Frame
+    n = ((c * steps + i) * group + s) * (batch / columns) + b' is time step
+    t = s * steps + i of sequence b = c * 128 + b'. Steps past the window
+    (group * steps > seq_window) repeat the window's last frames and are
+    dropped by ``LaneFrames.sequence``."""
+    if batch % LANES == 0:
+        return batch // LANES, 1, seq_window
+    if LANES % batch == 0:
+        group = LANES // batch
+        return 1, group, -(-seq_window // group)
+    return None
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["frames"], meta_fields=["batch", "seq_window"])
+@dataclasses.dataclass(frozen=True)
+class LaneFrames:
+    """Stacked observations as the torso's batch, in ``lane_order``:
+    ``frames`` is (columns * steps * 128, H, W, K). Stands in for the
+    (B, T, H, W, K) array of the network's contract (``shape`` is that
+    logical shape): the network runs its torso over ``frames`` as they are
+    and brings the latent back to (B, T, D) with ``sequence``."""
+    frames: jnp.ndarray
+    batch: int
+    seq_window: int
+
+    @property
+    def shape(self):
+        return (self.batch, self.seq_window) + self.frames.shape[1:]
+
+    def sequence(self, rows: jnp.ndarray) -> jnp.ndarray:
+        """(frames, D) rows in lane order -> (B, T, D)."""
+        columns, group, steps = lane_order(self.batch, self.seq_window)
+        rows = rows.reshape(columns, steps, group, self.batch // columns,
+                            rows.shape[-1])
+        rows = rows.transpose(0, 3, 2, 1, 4).reshape(
+            self.batch, group * steps, rows.shape[-1])
+        return rows[:, :self.seq_window]
+
+
+def lane_fill(batch: int, seq_window: int) -> float:
+    """Share of the torso's batch in ``lane_order`` that is window frames
+    (the rest pads the last lane tile of each segment)."""
+    columns, _, steps = lane_order(batch, seq_window)
+    return batch * seq_window / (columns * steps * LANES)
+
+
+def lanes_route(obs_shape, seq_window: int, frame_stack: int,
+                out_dtype) -> bool:
+    """Whether ``stack_frames_lanes`` takes this input, from what the input
+    shows: the batch tiles the lanes, the stored frame is padded to whole
+    uint8 tiles no wider than the lanes (the exact gather's storage), and
+    the compute type is float32, or bfloat16 with an even stack (pairs of
+    planes share a 32-bit word)."""
+    batch, _, height, width = obs_shape
+    dtype = jnp.dtype(out_dtype)
+    return (lane_order(batch, seq_window) is not None
+            and height % _SUBLANE_ROWS == 0 and width == LANES
+            and (dtype == jnp.float32
+                 or (dtype == jnp.bfloat16 and frame_stack % 2 == 0)))
+
+
+_MAX_TILES = 5       # lane tiles a grid step emits at most
+
+
+def _tiles_per_step(steps: int) -> int:
+    """Lane tiles one grid step emits: the largest divisor of the steps up
+    to ``_MAX_TILES``. A pixel's words of one output block are one run in
+    HBM, 1 KiB a lane tile, and longer runs write faster; past five tiles
+    the larger blocks cost more at the pipeline's ends than the runs win
+    (my chip runs, PR 26, the kernel alone: B128 x T55 0.849 / 0.798 /
+    0.819 ms at 1 / 5 / 11 tiles, B64 x T125 0.913 / 0.873 / 0.889 / 0.897
+    at 1 / 3 / 7 / 9), and would crowd the input out of VMEM."""
+    return max(u for u in range(1, _MAX_TILES + 1) if steps % u == 0)
+
+
+def _stack_kernel_lanes(geom, in_hbm, out_ref, inbuf, sem, *state):
+    # Grid (column of 128 sequences, block of 32 stored rows, group of U
+    # lane tiles), in order. A stored time step ("slot" j) is 128 frames
+    # (rows r = s * batch_tile + b: every segment's step j); lane tile i's
+    # plane k is slot i + k, so a grid step decodes slots g*U + K-1 ..
+    # (g+1)*U + K-2 (the first one of a block also the K-1 before them),
+    # each once, and emits a tile per slot from it and the K-1 slots kept
+    # in VMEM (``state``: the last slot's rounded bits, and a ring of the
+    # words older tiles still need). Slot j+1's rows arrive by DMA while
+    # slot j is decoded. in_hbm: the whole (B, T+K-1, Hs, 128) uint8
+    # window; out_ref: (32, W, U, K, 128), written as (32, W*U*Q, 128)
+    # 32-bit words, Q sublanes a pixel and tile: the K float32 planes, or
+    # K/2 pairs of bfloat16 planes (plane 2q in the low half).
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (batch_tile, group, steps, tiles, row_len, frame_stack, out_height,
+     out_width, packed, interpret) = geom
+    col, hb, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    slots = steps + frame_stack - 1
+    width8 = -(-out_width // 8) * 8
+    planes = frame_stack // 2 if packed else frame_stack
+    lag_step = 2 if packed else 1
+    ring_len = (planes - 1) * lag_step
+    if packed:
+        prev, ring = state if ring_len else (state[0], None)
+    else:
+        prev, ring = None, (state[0] if ring_len else None)
+    # the input block as 32-bit words: word row q of frame r holds stored
+    # rows 4q..4q+3 (one a byte) of 128 pixels
+    words = inbuf.bitcast(jnp.int32).reshape(2 * LANES * _WORD_ROWS, LANES)
+    inv = jnp.float32(1.0 / 255.0)
+
+    def segment_copy(j, s):
+        # segment s's stored step of slot j; past the window: its last
+        tau = jnp.minimum(s * steps + j, row_len - 1)
+        return pltpu.make_async_copy(
+            in_hbm.at[pl.ds(col * LANES, batch_tile), tau,
+                      pl.ds(hb * _SUBLANE_ROWS, _SUBLANE_ROWS)],
+            inbuf.at[j % 2, pl.ds(s * batch_tile, batch_tile)],
+            sem.at[j % 2])
+
+    def start(j):
+        jax.lax.fori_loop(
+            0, group, lambda s, c: (segment_copy(j, s).start(), c)[1], 0)
+
+    def store_plane(h, p, tile, plane):
+        # word plane p of pixel row h in lane tile ``tile`` of the block:
+        # one sublane row of every pixel's (tiles * planes, 128) words, a
+        # single strided store
+        if not interpret:
+            out_words = out_ref.bitcast(jnp.int32) if packed else out_ref
+            out_words.reshape(_SUBLANE_ROWS, out_width * tiles * planes,
+                              LANES)[
+                h, pl.ds(tile * planes + p, out_width,
+                         stride=tiles * planes), :] = plane
+        elif packed:
+            # the interpreter cannot write through a ref's view: the same
+            # store, a half word at a time
+            for e, half in enumerate((jax.lax.shift_left(plane, 16),
+                                      jax.lax.bitwise_and(plane, -0x10000))):
+                out_ref[h, :, tile, 2 * p + e, :] = (
+                    jax.lax.bitcast_convert_type(half, jnp.float32)
+                    .astype(out_ref.dtype))
+        else:
+            out_ref[h, :, tile, p, :] = plane
+
+    def slot(j, carry):
+        @pl.when(j == 0)
+        def _():
+            start(j)
+
+        @pl.when(j + 1 < slots)
+        def _():
+            start(j + 1)
+
+        jax.lax.fori_loop(
+            0, group, lambda s, c: (segment_copy(j, s).wait(), c)[1], 0)
+        # the tile this slot completes; the block's first K-1 slots
+        # complete none and write where the next one overwrites
+        tile = jnp.maximum(j - (frame_stack - 1) - g * tiles, 0)
+
+        def word_row(q, carry):
+            # 128 frames' word row q, frames on sublanes -> frames on lanes
+            x = words[pl.ds((j % 2) * (LANES * _WORD_ROWS) + q, LANES,
+                            stride=_WORD_ROWS), :]
+            x = x.T[:width8]                              # (W, 128 frames)
+            for byte in range(4):
+                h = q * 4 + byte
+                level = jax.lax.bitwise_and(
+                    jax.lax.shift_right_logical(x, 8 * byte), 0xFF)
+                # normalise in f32, round once into the compute type (the
+                # reference's /255 then cast; inside a jit XLA turns that
+                # divide into this multiply as well)
+                val = level.astype(jnp.float32) * inv
+                if packed:
+                    # round to nearest even into the high half by hand:
+                    # the low and high plane of a pair meet in one word
+                    bits = jax.lax.bitcast_convert_type(val, jnp.int32)
+                    bits = bits + 0x7FFF + jax.lax.bitwise_and(
+                        jax.lax.shift_right_logical(bits, 16), 1)
+                    item = jax.lax.bitwise_or(
+                        jax.lax.bitwise_and(bits, -0x10000), prev[h])
+                    prev[h] = jax.lax.shift_right_logical(bits, 16)
+                else:
+                    item = val
+                for p in range(planes):
+                    lag = (planes - 1 - p) * lag_step
+                    plane = item if lag == 0 else ring[
+                        (j + ring_len - lag) % ring_len, h]
+                    store_plane(h, p, tile, plane[:out_width])
+                if ring_len:
+                    ring[j % ring_len, h] = item
+            return carry
+
+        word_rows = -(-out_height // 4)
+        jax.lax.fori_loop(
+            0, jnp.minimum(_WORD_ROWS, word_rows - hb * _WORD_ROWS),
+            word_row, 0)
+        return carry
+
+    jax.lax.fori_loop(
+        jnp.where(g == 0, 0, g * tiles + frame_stack - 1),
+        (g + 1) * tiles + frame_stack - 1, slot, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+def stack_frames_lanes(obs: jnp.ndarray, seq_window: int, frame_stack: int,
+                       interpret: bool = False, out_dtype=jnp.float32,
+                       out_height=None, out_width=None) -> LaneFrames:
+    """The decode into the first convolution's own layout: frames in lanes,
+    the K planes in the tile's sublanes. Takes what ``lanes_route`` admits.
+
+    The kernel writes (H, W, tile, K, 128): frame n = tile * 128 + lane of
+    ``lane_order`` in lanes, a pixel's K planes in the sublanes under it.
+    Seen as (n, h, w, k) those are the bytes of the TPU's layout of the
+    convolution's input, minor to major N, K, W, H with tile (4, 128) and
+    two bf16 rows to a 32-bit word, so XLA makes a bitcast of the transpose
+    below and puts no copy between the kernel and the convolution."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, row_len, height, width = obs.shape
+    out_height = height if out_height is None else out_height
+    out_width = width if out_width is None else out_width
+    assert lanes_route(obs.shape, seq_window, frame_stack, out_dtype)
+    columns, group, steps = lane_order(batch, seq_window)
+    tiles = _tiles_per_step(steps)
+    batch_tile = batch // columns
+    packed = jnp.dtype(out_dtype).itemsize == 2
+    planes = frame_stack // 2 if packed else frame_stack
+    width8 = -(-out_width // 8) * 8
+    ring_len = (planes - 1) * (2 if packed else 1)
+    plane_block = (_SUBLANE_ROWS, width8, LANES)
+    state = ([pltpu.VMEM(plane_block, jnp.int32)] if packed else []) + (
+        [pltpu.VMEM((ring_len,) + plane_block,
+                    jnp.int32 if packed else out_dtype)] if ring_len else [])
+    itemsize = jnp.dtype(out_dtype).itemsize
+    block_words = _SUBLANE_ROWS * width8 * LANES
+    out_block_bytes = (_SUBLANE_ROWS * out_width * tiles * frame_stack * LANES
+                       * itemsize)
+    out_bytes = (out_height * out_width * columns * steps * frame_stack
+                 * LANES * itemsize)
+    # two output blocks, two input blocks, the kept slots, and room for
+    # Mosaic's own; no more, so that XLA can keep the input in VMEM too
+    vmem_bytes = (2 * out_block_bytes + 2 * LANES * _SUBLANE_ROWS * width
+                  + 4 * block_words * (ring_len + packed) + 4 * 2**20)
+    kernel = functools.partial(
+        _stack_kernel_lanes,
+        (batch_tile, group, steps, tiles, seq_window + frame_stack - 1,
+         frame_stack, out_height, out_width, packed, interpret))
+    words = pl.pallas_call(
+        kernel,
+        grid=(columns, -(-out_height // _SUBLANE_ROWS), steps // tiles),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(
+            (_SUBLANE_ROWS, out_width, tiles, frame_stack, LANES),
+            lambda c, hb, g: (hb, 0, c * (steps // tiles) + g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (out_height, out_width, columns * steps, frame_stack, LANES),
+            out_dtype),
+        scratch_shapes=[
+            pltpu.VMEM((2, LANES, _SUBLANE_ROWS, width), obs.dtype),
+            pltpu.SemaphoreType.DMA((2,))] + state,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=vmem_bytes),
+        # what XLA's memory-space assignment goes by: told that this call
+        # moves bytes and does no arithmetic, it keeps the gather's output
+        # (this call's input) in VMEM, where there is room beside the
+        # kernel's own blocks, and the gather writes half its HBM traffic
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=obs.size + out_bytes),
+        interpret=interpret,
+    )(obs)
+    frames = words.transpose(2, 4, 0, 1, 3).reshape(
+        -1, out_height, out_width, frame_stack)
+    return LaneFrames(frames, batch, seq_window)
+
+
 def resolve_pallas_setting(setting, field: str = "pallas setting") -> bool:
     """Resolve a pallas tri-state config knob: "on", "off", or "auto" =
     pallas iff the default backend is TPU (the measured winner there —
@@ -251,15 +566,39 @@ def resolve_pallas_obs_decode(setting) -> bool:
     return resolve_pallas_setting(setting, "pallas_obs_decode")
 
 
+def decode_route(obs_shape, seq_window: int, frame_stack: int,
+                 use_pallas: bool = False, out_dtype=jnp.float32,
+                 nhwc: bool = False) -> str:
+    """The decode ``stack_frames`` takes for this input, by name:
+    "reference" (jnp), "nhwc" (optim.pallas_decode_layout asked for it),
+    "lanes" (the first convolution's own layout, where ``lanes_route``
+    admits the input) or "planar" (the Pallas kernel for every other
+    shape)."""
+    if not use_pallas:
+        return "reference"
+    if nhwc:
+        return "nhwc"
+    if lanes_route(obs_shape, seq_window, frame_stack, out_dtype):
+        return "lanes"
+    return "planar"
+
+
 def stack_frames(obs: jnp.ndarray, seq_window: int, frame_stack: int,
                  use_pallas: bool = False,
                  out_dtype=jnp.float32,
                  out_height=None,
                  nhwc: bool = False,
-                 out_width=None) -> jnp.ndarray:
-    """Dispatch: pallas on TPU when requested (``nhwc`` selects the
-    transpose-free NHWC-emitting kernel), jnp otherwise."""
-    if use_pallas:
+                 out_width=None):
+    """Dispatch by ``decode_route``: pallas on TPU when requested, jnp
+    otherwise. Returns the (B, T, H, W, K) array, or on the "lanes" route
+    ``LaneFrames`` of that logical shape."""
+    route = decode_route(obs.shape, seq_window, frame_stack, use_pallas,
+                         out_dtype, nhwc)
+    if route == "lanes":
+        return stack_frames_lanes(obs, seq_window, frame_stack,
+                                  out_dtype=out_dtype, out_height=out_height,
+                                  out_width=out_width)
+    if route != "reference":
         return stack_frames_pallas(obs, seq_window, frame_stack,
                                    out_dtype=out_dtype, out_height=out_height,
                                    nhwc=nhwc, out_width=out_width)

@@ -100,6 +100,28 @@ def run_chip_checks(only: str = "") -> int:
                                       np.asarray(want, np.float32))
     add("decode_padded_strip", decode_padded)
 
+    # the TPU path's decode (frames in lanes, the first convolution's own
+    # layout) at the cells' shapes, the reference check's and float32
+    def decode_lanes(batch, window, dtype):
+        def check():
+            rng = fresh_rng()
+            from r2d2_tpu.ops.pallas_kernels import (stack_frames_lanes,
+                                                     stack_frames_reference)
+            obs = jnp.asarray(
+                rng.integers(0, 256, (batch, window + 3, 96, 128)), jnp.uint8)
+            got = stack_frames_lanes(obs, window, 4, False, dtype, 84, 84)
+            seq = got.sequence(got.frames.reshape(got.frames.shape[0], -1))
+            want = stack_frames_reference(obs, window, 4, dtype, 84, 84)
+            np.testing.assert_allclose(
+                np.asarray(seq, np.float32).reshape(want.shape),
+                np.asarray(want, np.float32),
+                rtol=2e-7 if dtype == jnp.float32 else 0.0, atol=0.0)
+        return check
+    add("decode_lanes_b128_t55", decode_lanes(128, 55, jnp.bfloat16))
+    add("decode_lanes_b64_t125", decode_lanes(64, 125, jnp.bfloat16))
+    add("decode_lanes_b8_t55", decode_lanes(8, 55, jnp.bfloat16))
+    add("decode_lanes_b128_t55_f32", decode_lanes(128, 55, jnp.float32))
+
     # --- replay window gathers ------------------------------------------
     def row_gather():
         rng = fresh_rng()

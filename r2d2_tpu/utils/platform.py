@@ -112,6 +112,30 @@ def _libtpu_version() -> Optional[str]:
         return None
 
 
+def _decode_facts(cfg, bf16: bool, use_pallas: bool):
+    """(route, lane fill) of the train step's observation decode for
+    ``cfg``'s shapes, given what its bf16 and Pallas-decode switches
+    resolved to: ``ops/pallas_kernels.py decode_route`` on the sampled
+    window as ``learner/train_step.py _decode_inputs`` hands it over. The
+    fill is None off the "lanes" route."""
+    import jax.numpy as jnp
+
+    from r2d2_tpu.ops.pallas_kernels import decode_route, lane_fill
+    from r2d2_tpu.replay.structs import ReplaySpec
+
+    spec = ReplaySpec.from_config(cfg)
+    route = decode_route(
+        (spec.batch_size, spec.seq_window + spec.frame_stack - 1,
+         spec.stored_frame_height, spec.stored_frame_width),
+        spec.seq_window, spec.frame_stack,
+        use_pallas=use_pallas,
+        out_dtype=jnp.bfloat16 if bf16 else jnp.float32,
+        nhwc=str(cfg.optim.pallas_decode_layout).lower() == "nhwc")
+    if route != "lanes":
+        return route, None
+    return route, round(lane_fill(spec.batch_size, spec.seq_window), 4)
+
+
 def runtime_report(cfg) -> dict:
     """What this process actually runs on: platform, device kind and count,
     library versions, the compile-cache directory and the values
@@ -126,6 +150,11 @@ def runtime_report(cfg) -> dict:
     from r2d2_tpu.ops.pallas_kernels import resolve_pallas_setting as on
 
     devs = jax.devices()
+    bf16 = on(cfg.network.bf16, "network.bf16")
+    pallas_obs_decode = on(cfg.optim.pallas_obs_decode,
+                           "optim.pallas_obs_decode")
+    decode_layout, decode_lane_fill = _decode_facts(cfg, bf16,
+                                                    pallas_obs_decode)
     return {
         "platform": devs[0].platform,
         "device_kind": devs[0].device_kind,
@@ -137,9 +166,13 @@ def runtime_report(cfg) -> dict:
                           if jax.config.jax_enable_compilation_cache
                           else None),
         "resolved": {
-            "bf16": on(cfg.network.bf16, "network.bf16"),
-            "pallas_obs_decode": on(cfg.optim.pallas_obs_decode,
-                                    "optim.pallas_obs_decode"),
+            "bf16": bf16,
+            "pallas_obs_decode": pallas_obs_decode,
+            # the decode the train step's shapes take (static per shape),
+            # and on the "lanes" route the share of the torso's batch that
+            # is window frames (the rest pads the last lane tile)
+            "decode_layout": decode_layout,
+            "decode_lane_fill": decode_lane_fill,
             "pallas_sample_gather": on(cfg.replay.pallas_sample_gather,
                                        "replay.pallas_sample_gather"),
             "pallas_exact_gather": on(cfg.replay.pallas_exact_gather,
