@@ -1,0 +1,15 @@
+"""Shared by the ``moe_*`` readers: the summed self-time share of several
+scopes of the expert layers (``benchmarks/layer_metrics/_share.py`` for
+one)."""
+
+from benchmarks.layer_metrics._share import self_share
+
+ROUTING = ("moe_dispatch", "moe_combine")
+# XLA:TPU names a grouped product's call ragged-dot-none and keeps no scope
+ALL = ("moe_router", "moe_experts", "ragged-dot", "moe_shared") + ROUTING
+
+
+def summed_share(ctx, tokens):
+    shares = [self_share(ctx, token) for token in tokens]
+    found = [s for s in shares if s is not None]
+    return sum(found) if found else None

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from r2d2_tpu.models.network import (NetworkApply, initial_hidden,
+from r2d2_tpu.models.network import (NetworkApply,
                                      is_quant_bundle, make_inference_bundle,
                                      quantized_inference_apply)
 
@@ -232,8 +232,7 @@ class ActorPolicy(_QuantPolicyMixin):
 
     def reset_state(self) -> None:
         """Per-episode state reset (ref model.py:86-87, worker.py:584-591)."""
-        self.hidden = jax.device_put(
-            initial_hidden(1, self.net.config.hidden_dim), self._cpu)
+        self.hidden = jax.device_put(self.net.init_state(1), self._cpu)
         h, w, s = self.net.obs_hw
         self.stacked = np.zeros((h, w, s), np.float32)
         self.last_action = np.int32(-1)
@@ -342,7 +341,7 @@ class BatchedActorPolicy(_QuantPolicyMixin):
         h, w, s = self.net.obs_hw
         n = self.num_lanes
         # host numpy (not device arrays) so reset_lane mutates one row
-        self.hidden = np.zeros((n, 2, self.net.config.hidden_dim), np.float32)
+        self.hidden = np.zeros((n, 2, self.net.state_half), np.float32)
         self.stacked = np.zeros((n, h, w, s), np.float32)
         self.last_action = np.full(n, -1, np.int32)
 

@@ -145,7 +145,7 @@ def main(argv=None) -> int:
         if args.shm:
             shm_t = ShmServeTransport(
                 endpoint.submit, (cfg.env.frame_height, cfg.env.frame_width),
-                action_dim, cfg.network.hidden_dim,
+                action_dim, net.state_half,
                 request_slots=cfg.serve.request_ring_slots,
                 tracing=tracing)
             transports.append(shm_t)

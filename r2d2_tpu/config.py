@@ -62,6 +62,71 @@ class EnvConfig:
 
 
 @dataclass(frozen=True)
+class CoreConfig:
+    """The memory core between the torso and the dueling head
+    (``models/cores/``). ``kind="lstm"`` is the R2D2 LSTM of width
+    ``network.hidden_dim`` and reads none of the other keys. ``"mla_moe"`` is
+    a stack of DeepSeek-V3-form layers (latent attention, then a dense or a
+    mixture-of-experts SwiGLU) whose keys are spelt as the source model's
+    ``config.json`` spells them; the defaults are Moonlight-16B-A3B's
+    (https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json).
+    The source's keys that choose between forms (``q_lora_rank``,
+    ``scoring_func``, ``topk_method``, ``n_group``, ``topk_group``,
+    ``norm_topk_prob``, ``moe_layer_freq``) are not here: the core implements
+    the one form that config names (models/cores/mla_moe.py).
+    Three keys are this program's own: ``experts_held`` / ``expert_offset``
+    (the router scores all ``n_routed_experts`` and picks
+    ``num_experts_per_tok`` of them; this chip computes the experts
+    ``expert_offset .. expert_offset + experts_held - 1`` and leaves out what
+    the others would add) and ``memory_len`` (positions of latent cache the
+    recurrent state carries)."""
+
+    kind: str = "lstm"
+    hidden_size: int = 2048
+    num_hidden_layers: int = 27
+    num_attention_heads: int = 16
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 50000.0
+    rms_norm_eps: float = 1e-5
+    intermediate_size: int = 11264
+    moe_intermediate_size: int = 1408
+    first_k_dense_replace: int = 1
+    n_routed_experts: int = 64
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    routed_scaling_factor: float = 2.446
+    experts_held: int = 64
+    expert_offset: int = 0
+    memory_len: int = 40
+
+    def __post_init__(self):
+        if self.kind not in ("lstm", "mla_moe"):
+            raise ValueError(
+                f"network.core.kind ({self.kind!r}) must be 'lstm' or "
+                "'mla_moe'")
+        if self.kind != "mla_moe":
+            return
+        if not (0 <= self.expert_offset and self.experts_held >= 1
+                and self.expert_offset + self.experts_held
+                <= self.n_routed_experts):
+            raise ValueError(
+                f"network.core: experts {self.expert_offset} .. "
+                f"{self.expert_offset + self.experts_held - 1} are not "
+                f"among the {self.n_routed_experts} routed experts")
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError(
+                "network.core.num_experts_per_tok exceeds n_routed_experts")
+        if self.qk_rope_head_dim % 2 or self.memory_len < 1 \
+                or self.num_hidden_layers < 1:
+            raise ValueError(
+                "network.core: qk_rope_head_dim must be even, memory_len "
+                "and num_hidden_layers at least 1")
+
+
+@dataclass(frozen=True)
 class NetworkConfig:
     """Recurrent dueling/double DQN architecture (ref config.py:54-57, model.py:22-46)."""
 
@@ -131,6 +196,10 @@ class NetworkConfig:
     # probe runs the f32 twin on the live batch and feeds the record's
     # 'quant' block + the quant_divergence alert rule.
     inference_dtype: str = "f32"
+    # The memory core (models/cores/): the LSTM by default. Nested, so that
+    # whoever is handed ``cfg.network`` alone has the core's sizes too;
+    # dotted overrides reach it as ``network.core.<key>``.
+    core: CoreConfig = field(default_factory=CoreConfig)
 
 
 @dataclass(frozen=True)
@@ -1760,13 +1829,24 @@ class Config:
         cfg.replace(**{"replay.capacity": 1000, "actor.num_actors": 4})
         """
         updates: Dict[str, Dict[str, Any]] = {}
+        nested: Dict[Tuple[str, str], Dict[str, Any]] = {}
         for key, value in dotted.items():
             if "." not in key:
                 raise KeyError(f"override key must be dotted (section.field): {key!r}")
             section, fname = key.split(".", 1)
             if "." in fname:
-                raise KeyError(f"only one nesting level supported: {key!r}")
-            updates.setdefault(section, {})[fname] = value
+                # section.group.field: a section's own nested dataclass
+                # (network.core.kind)
+                group, leaf = fname.split(".", 1)
+                if "." in leaf or (section, group) not in _NESTED_TYPES:
+                    raise KeyError(f"no such nested override: {key!r}")
+                nested.setdefault((section, group), {})[leaf] = value
+            else:
+                updates.setdefault(section, {})[fname] = value
+        for (section, group), fields in nested.items():
+            base = updates.setdefault(section, {}).get(
+                group, getattr(getattr(self, section), group))
+            updates[section][group] = dataclasses.replace(base, **fields)
         replaced = {}
         for section, fields in updates.items():
             sub = getattr(self, section)
@@ -1792,6 +1872,8 @@ class Config:
                 if isinstance(value, list):
                     sub[key] = tuple(
                         tuple(x) if isinstance(x, list) else x for x in value)
+                elif (f.name, key) in _NESTED_TYPES:
+                    sub[key] = _NESTED_TYPES[(f.name, key)](**value)
             kwargs[f.name] = _SECTION_TYPES[f.name](**sub)
         return cls(**kwargs)
 
@@ -1808,6 +1890,9 @@ _SECTION_TYPES = {
     "mesh": MeshConfig, "runtime": RuntimeConfig,
     "telemetry": TelemetryConfig,
 }
+
+# a section's own nested dataclasses: (section, field) -> type
+_NESTED_TYPES = {("network", "core"): CoreConfig}
 
 # Field annotations are strings (PEP 563 via `from __future__ import
 # annotations`); only scalar fields are CLI-settable.
@@ -1875,6 +1960,9 @@ def parse_overrides(cfg: Config, argv: List[str]) -> Config:
         if section not in {f.name for f in dataclasses.fields(cfg)}:
             raise SystemExit(f"unknown config section {section!r}")
         sub = getattr(cfg, section)
+        group, _, leaf = fname.partition(".")
+        if leaf and (section, group) in _NESTED_TYPES:
+            sub, fname = getattr(sub, group), leaf      # network.core.kind
         matching = {f.name: f for f in dataclasses.fields(sub)}
         if fname not in matching:
             raise SystemExit(f"unknown field {fname!r} in section {section!r}")

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import optax
 
 from r2d2_tpu.config import OptimConfig
+from r2d2_tpu.models.cores import require_lstm
 from r2d2_tpu.models.network import NetworkApply
 from r2d2_tpu.ops.indexing import (
     frame_stack_indices,
@@ -107,12 +108,14 @@ def _decode_inputs(net: NetworkApply, spec: ReplaySpec, batch: SampleBatch,
 
 def _unrolled_q(net: NetworkApply, spec: ReplaySpec, params,
                 batch: SampleBatch, use_pallas: bool = False,
-                nhwc: bool = False) -> jnp.ndarray:
+                nhwc: bool = False) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Decode (see _decode_inputs) and unroll the full window from the
-    stored hidden state. Returns (B, T, A) f32 Q-values."""
+    stored hidden state. Returns (B, T, A) f32 Q-values and the counters
+    the core sowed ({} for the LSTM)."""
     stacked, last_action = _decode_inputs(net, spec, batch, use_pallas, nhwc)
-    q, _ = net.module.apply(params, stacked, last_action, batch.hidden)
-    return q
+    q, _, counters = net.apply_learner(params, stacked, last_action,
+                                       batch.hidden)
+    return q, counters
 
 
 def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
@@ -131,10 +134,12 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
     # double-DQN only: interleave the two unrolls' recurrent chains in one
     # scan (two sequential while-loops cannot overlap — see
     # models/network.py dual_sequence_q); identical math, parity-tested
-    fused_dual = use_double and resolve_pallas_setting(
-        optim.fused_double_unroll, "optim.fused_double_unroll")
+    fused_dual = (use_double and net.config.core.kind == "lstm"
+                  and resolve_pallas_setting(optim.fused_double_unroll,
+                                             "optim.fused_double_unroll"))
 
     def loss_fn(params, target_params, batch: SampleBatch):
+        counters = {}
         if fused_dual:
             from r2d2_tpu.models.network import dual_sequence_q
             stacked, last_action = _decode_inputs(net, spec, batch,
@@ -143,15 +148,15 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
                 net, params, target_params, stacked, last_action,
                 batch.hidden, batch.hidden)
         else:
-            q_online = _unrolled_q(net, spec, params, batch, use_pallas,
-                                   nhwc)
+            q_online, counters = _unrolled_q(net, spec, params, batch,
+                                             use_pallas, nhwc)
 
         # the target unroll stays on the non-fused double path below, so
         # it is computed BEFORE entering the loss scope — its ops keep
         # their torso/lstm/head component scopes un-nested
         if use_double and not fused_dual:
-            q_target_all = _unrolled_q(net, spec, target_params, batch,
-                                       use_pallas, nhwc)
+            q_target_all, _ = _unrolled_q(net, spec, target_params, batch,
+                                          use_pallas, nhwc)
 
         # "loss" component scope (ISSUE 9): everything below is gathers
         # + masked reductions over the unrolled Q — cheap, but
@@ -207,6 +212,9 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             "mask": mask,
             "q_chosen": q_chosen,
         }
+        if counters:
+            # the mla_moe core's routing counters of the online forward
+            aux["moe"] = counters
         return loss, aux
 
     return loss_fn
@@ -259,6 +267,11 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             updates, opt_state = tx.update(grads, train_state.opt_state,
                                            train_state.params)
             params = optax.apply_updates(train_state.params, updates)
+        if "moe" in aux:
+            # the mean the mla_moe core centred its routers' inputs on goes
+            # among the parameters, where acting reads it
+            from r2d2_tpu.models.cores.mla_moe import store_router_means
+            params = store_router_means(params, aux["moe"]["input_mean"])
 
         # priority write-back, atomic with the sample (no staleness window)
         tree = tree_update(
@@ -284,6 +297,10 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             "mean_q": aux["mean_q"],
             "grad_norm": grad_norm,
         }
+        if "moe" in aux:
+            # the mla_moe core's routing counters of this step
+            metrics.update({f"moe/{k}": v for k, v in aux["moe"].items()
+                            if k != "input_mean"})
         if diag is not None:
             from r2d2_tpu.telemetry.learning import fused_diagnostics
             # pre-update params: consistent with the batch just trained on
@@ -324,6 +341,7 @@ def make_external_batch_step(net: NetworkApply, spec: ReplaySpec,
     compiled program follows THEIR shardings, which is how the tensor-
     parallel path reuses this exact step (parallel/tensor_parallel.py).
     """
+    require_lstm(net.config, "the external-batch train step")
     loss_fn = make_loss_fn(net, spec, optim, use_double)
     tx = make_optimizer(optim)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)

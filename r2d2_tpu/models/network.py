@@ -285,7 +285,8 @@ class R2D2Network(nn.Module):
         obs_seq: jnp.ndarray,       # (B, T, H, W, stack) normalized [0,1],
                                     # or LaneFrames of that logical shape
         last_action_seq: jnp.ndarray,  # (B, T, action_dim) one-hot f32
-        hidden: jnp.ndarray,        # (B, 2, hidden_dim) packed
+        hidden: jnp.ndarray,        # (B, 2, core.state_half) packed
+        window_stats: bool = False,  # the learner's call (models/cores/)
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         cfg = self.config
         if not isinstance(cfg.space_to_depth, bool):
@@ -317,24 +318,18 @@ class R2D2Network(nn.Module):
             [latent, last_action_seq.astype(dtype)], axis=-1
         )
 
-        # Time-batched LSTM with the input projection hoisted out of the
-        # scan (ref model.py:33 — torch nn.LSTM batch_first).
-        from r2d2_tpu.ops.pallas_kernels import resolve_pallas_setting
-        cell = HoistedLSTM(features=cfg.hidden_dim, dtype=dtype,
-                           unroll=cfg.scan_unroll,
-                           use_pallas=resolve_pallas_setting(
-                               cfg.pallas_lstm, "network.pallas_lstm"),
-                           pallas_block_t=cfg.pallas_lstm_block,
-                           pallas_interpret=cfg.pallas_lstm_interpret,
-                           name="lstm")
-        carry = unpack_hidden(hidden.astype(dtype))
-        carry, outputs = cell(carry, rnn_in)
+        # The memory core (models/cores/): the LSTM, or whichever
+        # ``cfg.core.kind`` names. It builds its modules here, under its
+        # own scope name.
+        from r2d2_tpu.models.cores import make_core
+        core = make_core(cfg, dtype)
+        outputs, new_hidden = core.unroll(rnn_in, hidden, window_stats)
 
         q = DuelingHead(
             self.action_dim, cfg.hidden_dim, cfg.use_dueling, dtype, name="head"
-        )(outputs.reshape(batch * seq, cfg.hidden_dim))
+        )(outputs.reshape(batch * seq, core.out_dim))
         q = q.reshape(batch, seq, self.action_dim)
-        return q, pack_hidden(carry).astype(jnp.float32)
+        return q, new_hidden
 
 
 def dual_sequence_q(net: "NetworkApply", params_a, params_b,
@@ -361,6 +356,8 @@ def dual_sequence_q(net: "NetworkApply", params_a, params_b,
     between the remaining two).
     """
     cfg = net.config
+    from r2d2_tpu.models.cores import require_lstm
+    require_lstm(cfg, "dual_sequence_q")
     dtype = net.module.compute_dtype
     batch, seq = obs_seq.shape[0], obs_seq.shape[1]
 
@@ -680,17 +677,48 @@ class NetworkApply:
                     f"shrinks the {frame_height}x{frame_width} frame to "
                     f"{h}x{w}; use smaller network.conv_layers for this "
                     "frame size")
+        if config.core.kind != "lstm" and config.inference_dtype != "f32":
+            raise ValueError(
+                "network.inference_dtype other than 'f32' quantizes the "
+                "LSTM's acting forward (quantized_inference_apply); "
+                f"network.core.kind={config.core.kind!r} has no such twin")
         self.module = R2D2Network(action_dim=action_dim, config=config)
+        from r2d2_tpu.models.cores import make_core
+        self.core = make_core(config, self.module.compute_dtype)
+
+    @property
+    def state_half(self) -> int:
+        """Half the width of the packed recurrent-state row (B, 2, .)."""
+        return self.core.state_half
+
+    def init_state(self, batch_size: int) -> jnp.ndarray:
+        """The packed state an episode starts from."""
+        return self.core.init_state(batch_size)
 
     def init(self, key: jax.Array):
         h, w, s = self.obs_hw
         obs = jnp.zeros((1, 1, h, w, s), jnp.float32)
         la = jnp.zeros((1, 1, self.action_dim), jnp.float32)
-        hid = initial_hidden(1, self.config.hidden_dim)
-        return self.module.init(key, obs, la, hid)
+        args = (key, obs, la, self.init_state(1))
+        if self.config.core.kind == "lstm":
+            return self.module.init(*args)
+        # a core of half a billion parameters is drawn on the device in one
+        # program, not leaf by leaf; only "params" is kept (the core sows
+        # counters while it runs)
+        return {"params": jax.jit(self.module.init)(*args)["params"]}
 
     def apply(self, params, obs_seq, last_action_seq, hidden):
         return self.module.apply(params, obs_seq, last_action_seq, hidden)
+
+    def apply_learner(self, params, obs_seq, last_action_seq, hidden):
+        """The learner's forward pass over a batch of windows: ``apply``
+        with the core's ``window_stats`` on, and the counters the core sowed
+        into the ``moe`` collection while it ran (the ``mla_moe`` core's
+        routing: models/cores/mla_moe.py; {} for a core that sows none)."""
+        from r2d2_tpu.models.cores.mla_moe import moe_counters
+        (q, new_hidden), sown = self.module.apply(
+            params, obs_seq, last_action_seq, hidden, True, mutable=["moe"])
+        return q, new_hidden, moe_counters(sown)
 
 
 def init_network(

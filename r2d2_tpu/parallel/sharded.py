@@ -323,6 +323,8 @@ def make_sharded_learner_step(net: NetworkApply, spec: ReplaySpec,
     per-shard and merged tree-health views (the prerequisite
     instrumentation for rebalancing a sharded replay, ROADMAP item 3).
     """
+    from r2d2_tpu.models.cores import require_lstm
+    require_lstm(net.config, "the dp-sharded learner step")
     loss_fn = make_loss_fn(net, spec, optim, use_double)
     tx = make_optimizer(optim)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)

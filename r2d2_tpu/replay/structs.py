@@ -26,6 +26,7 @@ import numpy as np
 from flax import struct
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.cores import state_half
 from r2d2_tpu.ops.sum_tree import tree_num_layers
 
 
@@ -44,6 +45,8 @@ class ReplaySpec:
     frame_stack: int
     frame_height: int
     frame_width: int
+    # half the packed recurrent-state row (2, hidden_dim): the memory core's
+    # ``state_half`` (models/cores/), the LSTM's width for the LSTM
     hidden_dim: int
     batch_size: int
     prio_exponent: float
@@ -82,7 +85,7 @@ class ReplaySpec:
             frame_stack=cfg.env.frame_stack,
             frame_height=cfg.env.frame_height,
             frame_width=cfg.env.frame_width,
-            hidden_dim=cfg.network.hidden_dim,
+            hidden_dim=state_half(cfg.network),
             batch_size=cfg.replay.batch_size,
             prio_exponent=cfg.replay.prio_exponent,
             is_exponent=cfg.replay.importance_sampling_exponent,

@@ -66,6 +66,10 @@ class Learner:
         self._learning_agg = (LearningAggregator(
             player_idx, cfg.runtime.save_dir, cfg.telemetry.nan_policy,
             cfg.optim.lr) if self._diag is not None else None)
+        # the mla_moe core's routing counters (record block 'moe')
+        from r2d2_tpu.telemetry.learning import MoeAggregator
+        self._moe_agg = (MoeAggregator(cfg.network.core)
+                         if cfg.network.core.kind == "mla_moe" else None)
         # replay & data-pathology pillar (ISSUE 10): same spec/aggregator
         # pattern — a ReplayDiag fuses sum-tree health, sample-lifetime
         # accounting and lane composition into the step; None (the
@@ -1130,6 +1134,8 @@ class Learner:
                 # same contract for the rd/ outputs (replay pillar,
                 # ISSUE 10)
                 self._replay_agg.on_dispatch(m)
+            if self._moe_agg is not None:
+                self._moe_agg.on_dispatch(m)
 
             rt = self.cfg.runtime
             if (self.publish is not None
@@ -1229,7 +1235,9 @@ class Learner:
         nan_policy=halt raises out of this flush, stopping the run at the
         log boundary that first observed the poisoned step)."""
         if (not self._costs_attached and self.cfg.telemetry.enabled
-                and self.cfg.telemetry.costmodel_enabled):
+                and self.cfg.telemetry.costmodel_enabled
+                # the analytic table counts the LSTM network only
+                and self.cfg.network.core.kind == "lstm"):
             # one-shot cost-model block (ISSUE 9): analytic per-component
             # flops/bytes for THIS config — pure host math, no compile —
             # attached at the first flush so the run's very first record
@@ -1265,6 +1273,8 @@ class Learner:
                 self.metrics.set_learning(self._learning_agg.flush(
                     self._host_step, publish_count=pub,
                     occupancy_versions=self.ring.live_versions()))
+            if self._moe_agg is not None:
+                self.metrics.set_moe(self._moe_agg.flush())
             if self._replay_agg is not None:
                 # host placement: the HostReplay numpy twin supplies the
                 # sum-tree health + eviction snapshot the external-batch step

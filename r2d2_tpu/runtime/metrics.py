@@ -82,6 +82,7 @@ class TrainMetrics:
         # OMITTED entirely when learning diagnostics are off (consumers
         # key on its presence, like the 'stages' block)
         self._learning = None
+        self._moe = None
 
         # sharded-anakin composition block (ISSUE 8): per-shard rows +
         # the env-step imbalance ratio, set at each stats flush by the
@@ -230,6 +231,14 @@ class TrainMetrics:
         None = nothing this interval (no training steps, or diagnostics
         disabled) and the record carries no 'learning' key."""
         self._learning = block
+
+    def set_moe(self, block: Optional[dict]) -> None:
+        """Attach the interval's expert-routing block (per expert layer:
+        histogram of chosen experts, pairs on held experts, their max/mean
+        load, router entropy, dropped — telemetry/learning.py
+        MoeAggregator); None = the core routes nothing and the record
+        carries no 'moe' key."""
+        self._moe = block
 
     def set_anakin(self, block: Optional[dict]) -> None:
         """Attach the interval's sharded-anakin block (per-shard env
@@ -426,6 +435,9 @@ class TrainMetrics:
             # emission so a training pause doesn't replay stale numbers
             record["learning"] = self._learning
             self._learning = None
+        if self._moe is not None:
+            record["moe"] = self._moe
+            self._moe = None
         if self._anakin is not None:
             # ONE anakin block per interval (ISSUE 8), consumed like the
             # learning block; emitted before the sentinel pass so the

@@ -724,7 +724,7 @@ class PlayerStack:
                 self._serve_transport = ShmServeTransport(
                     self.serve_endpoint.submit,
                     (cfg.env.frame_height, cfg.env.frame_width),
-                    self.net.action_dim, cfg.network.hidden_dim,
+                    self.net.action_dim, self.net.state_half,
                     request_slots=cfg.serve.request_ring_slots,
                     tracing=(cfg.telemetry.enabled
                              and cfg.telemetry.tracing_enabled))
@@ -732,7 +732,7 @@ class PlayerStack:
                     "transport": "shm",
                     "request_ring": self._serve_transport.request_ring,
                     "action_dim": self.net.action_dim,
-                    "hidden_dim": cfg.network.hidden_dim,
+                    "hidden_dim": self.net.state_half,
                     "reply_slots": reply_slots,
                 }
             except Exception as e:

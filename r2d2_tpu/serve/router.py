@@ -308,7 +308,7 @@ class ServerFleet:
         sv = self.cfg.serve
         h, w, s = self.net.obs_hw
         return StateCache(self.per_shard_slots * len(owned), len(owned),
-                          (h, w), s, self.net.config.hidden_dim,
+                          (h, w), s, self.net.state_half,
                           lease_timeout_s=sv.lease_timeout_s,
                           action_dim=self.net.action_dim,
                           owned_shards=owned,

@@ -375,7 +375,7 @@ class PolicyServer:
         h, w, s = net.obs_hw
         self.cache = (cache if cache is not None
                       else StateCacheFromConfig(cfg, (h, w), s,
-                                                net.config.hidden_dim,
+                                                net.state_half,
                                                 net.action_dim))
         self.buckets = serve_buckets(self.max_batch)
         self._stop = threading.Event()
@@ -392,7 +392,7 @@ class PolicyServer:
         compile would park every connected client for its duration (the
         ingest stager learned this the hard way, PERF.md)."""
         h, w, s = obs_hw
-        hd = self.net.config.hidden_dim
+        hd = self.net.state_half
         for b in self.buckets:
             args = (self._params,
                     np.zeros((b, h, w, s), np.float32),

@@ -53,7 +53,7 @@ class StateCache:
     single-server layout byte-identical (owns every shard)."""
 
     def __init__(self, slots: int, shards: int, frame_hw: Tuple[int, int],
-                 frame_stack: int, hidden_dim: int,
+                 frame_stack: int, hidden_dim: int,   # the core's state_half
                  lease_timeout_s: float = 120.0, action_dim: int = 1,
                  owned_shards: Optional[Sequence[int]] = None,
                  total_shards: Optional[int] = None):

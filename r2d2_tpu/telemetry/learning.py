@@ -156,7 +156,7 @@ def delta_q_diag(net, spec, params, batch, replay_state, dq_batch: int):
                            out_height=spec.frame_height,
                            out_width=spec.frame_width)
     la_oh = jax.nn.one_hot(la_full, net.action_dim, dtype=jnp.float32)
-    zeros = jnp.zeros((m, 2, spec.hidden_dim), jnp.float32)
+    zeros = net.init_state(m)
     q_full, _ = net.module.apply(params, stacked, la_oh, zeros)  # (m, T', A)
 
     L = spec.learning
@@ -251,6 +251,60 @@ def fused_diagnostics(net, spec, diag: LearningDiag, new_step, params,
 
 # ---------------------------------------------------------------------------
 # Host-side aggregation + NaN forensics
+
+
+class MoeAggregator:
+    """The ``mla_moe`` core's routing counters between two flushes: each
+    dispatch's ``moe/`` outputs are summed on the device (a few hundred
+    integers a step; no sync on the step path), and ``flush`` fetches the
+    sums once and makes the record's ``moe`` block, per expert layer: the
+    histogram of chosen experts over all routed experts, the
+    (position, expert) pairs that fell on the experts held here, the
+    largest and the mean load among those, the router's entropy (nats, of
+    the scores normalised over the experts, mean over positions and steps)
+    and the pairs dropped (always 0: the core has no capacity limit)."""
+
+    def __init__(self, core):
+        self.held = slice(core.expert_offset,
+                          core.expert_offset + core.experts_held)
+        self._sums: Optional[Dict[str, Any]] = None
+        self._steps = 0
+
+    def on_dispatch(self, metrics: Dict[str, Any]) -> None:
+        import jax.numpy as jnp
+        moe = {k[len("moe/"):]: v for k, v in metrics.items()
+               if k.startswith("moe/")}
+        if not moe:
+            return
+        def total(v, tail):
+            # (L_moe, ...) a step, or (K, L_moe, ...) a multi-step dispatch
+            return jnp.reshape(v, (-1,) + v.shape[v.ndim - tail:]).sum(axis=0)
+
+        flat = {k: total(v, 2 if k == "chosen" else 1)
+                for k, v in moe.items()}
+        self._steps += moe["entropy"].size // moe["entropy"].shape[-1]
+        self._sums = flat if self._sums is None else {
+            k: self._sums[k] + flat[k] for k in flat}
+
+    def flush(self) -> Optional[dict]:
+        import jax
+        if self._sums is None:
+            return None
+        sums, steps = jax.device_get(self._sums), self._steps
+        self._sums, self._steps = None, 0
+        layers = []
+        for chosen, entropy, dropped in zip(
+                sums["chosen"], sums["entropy"], sums["dropped"]):
+            held = chosen[self.held]
+            layers.append({
+                "chosen_hist": [int(c) for c in chosen],
+                "pairs_held": int(held.sum()),
+                "held_load_max": int(held.max()),
+                "held_load_mean": float(held.mean()),
+                "router_entropy": float(entropy) / steps,
+                "dropped": int(dropped),
+            })
+        return {"steps": steps, "layers": layers}
 
 
 def _flatten_rows(values: List[np.ndarray], width: int) -> np.ndarray:

@@ -1477,7 +1477,7 @@ def _calibrate_forward_table(cfg, net, params, buckets,
     from r2d2_tpu.actor.policy import make_forward_fn
     fwd = make_forward_fn(net)
     h, w, s = net.obs_hw
-    hd = net.config.hidden_dim
+    hd = net.state_half
     table = {}
     for b in sorted(set(int(x) for x in buckets)):
         args = (params, np.zeros((b, h, w, s), np.float32),
@@ -1537,7 +1537,7 @@ def serve_fleet_probe(seconds: float, servers: int, clients: int,
     net = NetworkApply(6, cfg.network, cfg.env.frame_stack,
                        cfg.env.frame_height, cfg.env.frame_width)
     params = net.init(jax.random.PRNGKey(0))
-    hd = cfg.network.hidden_dim
+    hd = net.state_half
     fff = None
     if forward_table is not None:
         biggest = max(forward_table)
