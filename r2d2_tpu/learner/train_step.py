@@ -78,6 +78,25 @@ def create_train_state(key: jax.Array, net: NetworkApply, optim: OptimConfig
     )
 
 
+def sync_target(optim: OptimConfig, use_double: bool, new_step, params,
+                target_params):
+    """The hard target sync (ref worker.py:375-377) as ONE branch over the
+    whole tree: every ``optim.target_net_update_interval`` steps, counted
+    1-based like the reference's post-increment check, the target becomes a
+    copy of ``params``; on every other step it is passed through, which XLA
+    aliases in place (a per-leaf ``jnp.where`` read and rewrote every
+    target leaf on every step). Returns (target_params, fired) with
+    ``fired`` the step's 0/1 ``target_sync`` counter; with double-Q off
+    nothing reads the target and nothing fires. Shared by every step
+    factory so their schedules cannot diverge."""
+    if not use_double:
+        return target_params, jnp.zeros((), jnp.int32)
+    sync = (new_step % optim.target_net_update_interval) == 0
+    target_params = jax.lax.cond(
+        sync, lambda p, t: p, lambda p, t: t, params, target_params)
+    return target_params, sync.astype(jnp.int32)
+
+
 def _decode_inputs(net: NetworkApply, spec: ReplaySpec, batch: SampleBatch,
                    use_pallas: bool,
                    nhwc: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -236,7 +255,14 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
     staleness stamps, and (every ``diag.interval`` steps, under lax.cond
     so the steady-state path is untouched) target-parameter distance and
     the stored-state ΔQ check. None compiles the pre-diagnostics program
-    byte-for-byte — the telemetry.learning_enabled kill switch.
+    byte-for-byte — the telemetry.learning_enabled kill switch. The
+    diagnostics read the PRE-update params and target, so they are tied
+    before the optimizer by data dependency (an ``optimization_barrier``
+    that the old params and target pass together with the diagnostics'
+    outputs; Python order means nothing to XLA): untied, XLA:TPU could not
+    update the loop state in place and copied it on every step for a branch
+    that runs once in ``diag.interval`` (8.87 GB of ``copy`` a step in the
+    scan body of ``moonlight-core.learner-long``, 1.68 GB tied: PERF.md §5).
 
     ``rdiag`` (telemetry.ReplayDiag or None): the replay-observability
     pillar (ISSUE 10) fused the same way — the per-slot sample-count
@@ -263,15 +289,6 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
 
         (loss, aux), grads = grad_fn(
             train_state.params, train_state.target_params, batch)
-        with jax.named_scope("optimizer"):
-            updates, opt_state = tx.update(grads, train_state.opt_state,
-                                           train_state.params)
-            params = optax.apply_updates(train_state.params, updates)
-        if "moe" in aux:
-            # the mean the mla_moe core centred its routers' inputs on goes
-            # among the parameters, where acting reads it
-            from r2d2_tpu.models.cores.mla_moe import store_router_means
-            params = store_router_means(params, aux["moe"]["input_mean"])
 
         # priority write-back, atomic with the sample (no staleness window)
         tree = tree_update(
@@ -279,35 +296,48 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             aux["priorities"], batch.idxes)
         replay_state = replay_state.replace(tree=tree)
 
-        # hard target sync every target_net_update_interval (ref worker.py:375-377);
-        # 1-based counter like the reference's post-increment check
         new_step = train_state.step + 1
-        if use_double:
-            sync = (new_step % optim.target_net_update_interval) == 0
-            target_params = jax.tree_util.tree_map(
-                lambda p, t: jnp.where(sync, p, t), params,
-                train_state.target_params)
-        else:
-            target_params = train_state.target_params
+        with jax.named_scope("optimizer"):
+            # the norm the clip waits for (XLA keeps one of the two)
+            grad_norm = optax.global_norm(grads)
+        old_params, old_target = train_state.params, train_state.target_params
+        ld = {}
+        if diag is not None:
+            from r2d2_tpu.telemetry.learning import fused_diagnostics
+            # pre-update params: consistent with the batch just trained on
+            ld = fused_diagnostics(
+                net, spec, diag, new_step, old_params, old_target, batch,
+                aux, grads, loss, grad_norm, replay_state=replay_state)
+            # the tie (docstring): what the optimizer and the sync consume
+            # exists only once the diagnostics' reads are done
+            (old_params, old_target), ld = jax.lax.optimization_barrier(
+                ((old_params, old_target), ld))
 
-        grad_norm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, train_state.opt_state,
+                                           old_params)
+            params = optax.apply_updates(old_params, updates)
+        if "moe" in aux:
+            # the mean the mla_moe core centred its routers' inputs on goes
+            # among the parameters, where acting reads it
+            from r2d2_tpu.models.cores.mla_moe import store_router_means
+            params = store_router_means(params, aux["moe"]["input_mean"])
+
+        target_params, target_sync = sync_target(
+            optim, use_double, new_step, params, old_target)
+
         metrics = {
             "loss": loss,
             "mean_abs_td": aux["mean_abs_td"],
             "mean_q": aux["mean_q"],
             "grad_norm": grad_norm,
+            "target_sync": target_sync,
         }
         if "moe" in aux:
             # the mla_moe core's routing counters of this step
             metrics.update({f"moe/{k}": v for k, v in aux["moe"].items()
                             if k != "input_mean"})
-        if diag is not None:
-            from r2d2_tpu.telemetry.learning import fused_diagnostics
-            # pre-update params: consistent with the batch just trained on
-            metrics.update(fused_diagnostics(
-                net, spec, diag, new_step, train_state.params,
-                train_state.target_params, batch, aux, grads, loss,
-                grad_norm, replay_state=replay_state))
+        metrics.update(ld)
         if rdiag is not None:
             # replay-pathology pillar (ISSUE 10): sample-count ring +
             # lane bincount every step, tree-health snapshot on the
@@ -355,13 +385,8 @@ def make_external_batch_step(net: NetworkApply, spec: ReplaySpec,
             params = optax.apply_updates(train_state.params, updates)
 
         new_step = train_state.step + 1
-        if use_double:
-            sync = (new_step % optim.target_net_update_interval) == 0
-            target_params = jax.tree_util.tree_map(
-                lambda p, t: jnp.where(sync, p, t), params,
-                train_state.target_params)
-        else:
-            target_params = train_state.target_params
+        target_params, target_sync = sync_target(
+            optim, use_double, new_step, params, train_state.target_params)
 
         grad_norm = optax.global_norm(grads)
         metrics = {
@@ -370,6 +395,7 @@ def make_external_batch_step(net: NetworkApply, spec: ReplaySpec,
             "mean_abs_td": aux["mean_abs_td"],
             "mean_q": aux["mean_q"],
             "grad_norm": grad_norm,
+            "target_sync": target_sync,
         }
         if diag is not None and batch.weight_version is not None:
             # host placement: histograms / grad norms / staleness / the

@@ -32,7 +32,8 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from r2d2_tpu.config import OptimConfig
-from r2d2_tpu.learner.train_step import TrainState, make_loss_fn, make_optimizer
+from r2d2_tpu.learner.train_step import (
+    TrainState, make_loss_fn, make_optimizer, sync_target)
 from r2d2_tpu.models.network import NetworkApply
 from r2d2_tpu.ops.sum_tree import tree_update
 from r2d2_tpu.replay.device_replay import (
@@ -268,19 +269,15 @@ def _post_gradient_update(tx, optim: OptimConfig, use_double: bool,
     params = optax.apply_updates(train_state.params, updates)
 
     new_step = train_state.step + 1
-    if use_double:
-        sync = (new_step % optim.target_net_update_interval) == 0
-        target_params = jax.tree_util.tree_map(
-            lambda p, t: jnp.where(sync, p, t), params,
-            train_state.target_params)
-    else:
-        target_params = train_state.target_params
+    target_params, target_sync = sync_target(
+        optim, use_double, new_step, params, train_state.target_params)
 
     metrics = {
         "loss": loss,
         "mean_abs_td": mean_abs_td,
         "mean_q": mean_q,
         "grad_norm": optax.global_norm(grads),
+        "target_sync": target_sync,
     }
     train_state = train_state.replace(
         params=params, target_params=target_params,

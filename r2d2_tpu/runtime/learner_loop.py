@@ -281,6 +281,7 @@ class Learner:
         self._ratio_env_base = self.env_steps
         self._ratio_step_base = self._host_step
         self._pending_losses: list = []   # device scalars, flushed lazily
+        self._pending_syncs: list = []    # the steps' 0/1 target_sync
 
         # -- batched + pipelined ingestion (ISSUE 2) --
         # K > 1 (device placement only): a background stager thread drains
@@ -1125,6 +1126,7 @@ class Learner:
             step = self._host_step
             # scalar (k=1) or (k,) array
             self._pending_losses.append(m["loss"])
+            self._pending_syncs.append(m["target_sync"])
             if self._learning_agg is not None:
                 # hold the dispatch's ld/ outputs (device values, no
                 # sync); aggregated into the 'learning' record block at
@@ -1260,10 +1262,13 @@ class Learner:
         if self._pending_losses:
             with self.tele.stage("learner/device_sync",
                                  losses=len(self._pending_losses)):
-                arrays = jax.device_get(self._pending_losses)
+                arrays, syncs = jax.device_get(
+                    (self._pending_losses, self._pending_syncs))
             self._pending_losses.clear()
+            self._pending_syncs.clear()
             for loss in np.concatenate([np.atleast_1d(a) for a in arrays]):
                 self.metrics.on_train_step(float(loss))
+            self.metrics.on_target_syncs(sum(int(np.sum(a)) for a in syncs))
         # the aggregators fetch their own device values: a second and a
         # third transfer at every log boundary, after the sync above
         with self.tele.stage("learner/diag_flush"):

@@ -52,6 +52,7 @@ class TrainMetrics:
         self.episode_reward = 0.0
         self.training_steps = 0
         self.last_training_steps = 0
+        self.target_syncs = 0
         self.sum_loss = 0.0
         self.dropped_priority_updates = 0
         self._next_drop_warn = 1
@@ -194,6 +195,11 @@ class TrainMetrics:
         """Called per learner step (ref worker.py:211-212)."""
         self.training_steps += 1
         self.sum_loss += float(loss)
+
+    def on_target_syncs(self, fired: int) -> None:
+        """Hard target syncs among the flushed steps (the steps' 0/1
+        ``target_sync``, learner/train_step.py sync_target)."""
+        self.target_syncs += int(fired)
 
     def set_buffer_size(self, size: int) -> None:
         self.buffer_size = int(size)
@@ -397,6 +403,7 @@ class TrainMetrics:
             "avg_episode_return": avg_return,
             "training_steps": self.training_steps,
             "training_speed": train_speed,
+            "target_syncs": self.target_syncs,
             "loss": mean_loss,
             "dropped_priority_updates": self.dropped_priority_updates,
             # worker-health counters: cumulative, overlaid by the latest
