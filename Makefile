@@ -148,7 +148,7 @@
 #                (tools/chaos.py --promotion) rides the e2e bench's
 #                --promotion-ab evidence cell.
 #   make regress — the regression gate: tools/regress.py compares the
-#                tree's E2E_*/BENCH_* artifacts against BASELINE.json's
+#                tree's E2E_* artifacts against BASELINE.json's
 #                'bench' snapshot (per-metric noise tolerances) AND the
 #                freshly recomputed XLA cost table against its 'costs'
 #                snapshot (exact match — compute regressions fail even

@@ -39,7 +39,7 @@ def main(argv=None) -> None:
     if trace_dir is None:
         cfg = parse_overrides(Config(), config_overrides)
         if not any("replay.capacity" in str(o) for o in config_overrides):
-            # bench.py's trimmed-but-realistic default capacity; an
+            # a trimmed-but-realistic default capacity; an
             # explicit --replay.capacity override always wins
             cfg = cfg.replace(
                 **{"replay.capacity": min(cfg.replay.capacity, 25_600)})

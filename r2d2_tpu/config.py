@@ -152,31 +152,6 @@ class NetworkConfig:
     # trades compile time for fewer sequential loop boundaries on the
     # 55-step serial chain). Set from measurement — see PERF.md.
     scan_unroll: int = 1
-    # Rewrite the first conv as the EXACT conv over a 2x2 space-to-depth
-    # input (kernel/stride halved, channels x4): the frame stack's 4
-    # channels waste most of the MXU's input lanes otherwise. "on"/"off"
-    # ONLY — no "auto": the setting changes the parameter layout, so a
-    # backend-dependent resolution would build incompatible param trees on
-    # heterogeneous hosts (TPU learner vs CPU actors/eval). Checkpoints
-    # are per-setting. Default off pending TPU measurement — see PERF.md.
-    space_to_depth: str = "off"
-    # Run the LSTM time scan as ONE fused pallas kernel (ops/pallas_lstm.py):
-    # Wh resident in VMEM across all T steps, h/c carried in f32 scratch,
-    # custom-VJP backward kernel — attacks the per-iteration while-loop
-    # overhead on the serial recurrent chain (the profiled wall, PERF.md).
-    # Tri-state like the sibling pallas knobs; compute-only (no parameter
-    # layout change), tolerance-parity-tested vs the lax.scan path.
-    # Default "off" pending the TPU A/B (bench cell bf16_spd16_plstm).
-    pallas_lstm: str = "off"
-    # Timesteps per grid iteration of the fused LSTM kernel (must divide
-    # the unroll length; 55 -> 1, 5, 11). >1 amortizes per-iteration
-    # grid/DMA bookkeeping against bigger VMEM blocks — a chip
-    # measurement (bench.py sweeps the plstm cells).
-    pallas_lstm_block: int = 1
-    # Debug/dryrun only: run the fused-LSTM kernel in pallas interpret
-    # mode (slow) — how the multichip dryrun executes the kernel's exact
-    # semantics on the CPU mesh. Refused on a TPU backend (NetworkApply).
-    pallas_lstm_interpret: bool = False
     # -- quantized inference plane (ISSUE 14) --
     # Dtype of the ACTING/SERVING forward only (local scalar/vector
     # actors, the policy server's micro-batched dispatch, and the anakin
@@ -291,25 +266,12 @@ class OptimConfig:
     # Decode uint8 obs windows with the fused pallas kernel
     # (ops/pallas_kernels.py): "on", "off", or "auto" (pallas iff the
     # backend is TPU — the measured winner there, builders, round 3; the XLA
-    # gather path is the correct-everywhere fallback).
+    # gather path is the correct-everywhere fallback). Which kernel runs
+    # follows from the shapes (decode_route): where the batch tiles the 128
+    # lanes and storage is tile-padded (the exact gather's), the one that
+    # writes the first convolution's own layout; the planar (B,T,K,H,W)
+    # kernel + outer transpose for every other shape.
     pallas_obs_decode: str = "auto"
-    # Pallas decode output layout. "planar" (the default) is whatever the
-    # TPU path emits by shape (ops/pallas_kernels.py decode_route): where
-    # the batch tiles the 128 lanes and storage is tile-padded (the exact
-    # gather's), the kernel writes the first convolution's own layout,
-    # frame index in lanes and the K planes in the tile's sublanes, so XLA
-    # copies nothing between the kernel and the convolution (PR 26: the
-    # (B,T,K,H,W) planar array padded 84x84 -> 96x128 and the 1.7 ms
-    # layout copy behind it are gone); other shapes keep the planar
-    # (B,T,K,H,W) kernel + outer transpose. "nhwc" interleaves K into the
-    # lane dim in-kernel (measured a loss; kept for bench.py's cell).
-    pallas_decode_layout: str = "planar"
-    # Double-DQN only: run the online and target unrolls interleaved in ONE
-    # lax.scan instead of two sequential while-loops (which XLA cannot
-    # overlap) — models/network.py dual_sequence_q. "on"/"off"/"auto"
-    # (auto = TPU). Default off pending the TPU A/B (bench.py measures a
-    # double/double_fused cell pair each round).
-    fused_double_unroll: str = "off"
 
 
 @dataclass(frozen=True)
@@ -1867,7 +1829,8 @@ class Config:
             # sections absent from the dict take their defaults: configs
             # serialized before a section existed (checkpoint .config.json
             # files) must keep loading after the schema grows
-            sub = dict(d.get(f.name) or {})
+            sub = {key: value for key, value in (d.get(f.name) or {}).items()
+                   if not _retired(f.name, key, value)}
             for key, value in sub.items():
                 if isinstance(value, list):
                     sub[key] = tuple(
@@ -1893,6 +1856,46 @@ _SECTION_TYPES = {
 
 # a section's own nested dataclasses: (section, field) -> type
 _NESTED_TYPES = {("network", "core"): CoreConfig}
+
+# Options that no longer exist: (section, field) -> (the spellings under
+# which an old config meant what the code now always does, or None where
+# the field did nothing once the switch beside it was off; what took the
+# path away). A checkpoint's .config.json or a launch script may still
+# carry the key: at such a value it is dropped, at any other it is refused,
+# from a dict (Config.from_dict) and from the command line (parse_overrides)
+# alike. A PR that removes an option adds its row here.
+_MEANT_OFF = ("off", "false", "0", "no")   # as resolve_pallas_setting reads
+_RETIRED_FIELDS: Dict[Tuple[str, str],
+                      Tuple[Optional[Tuple[str, ...]], str]] = {
+    ("network", "space_to_depth"): (
+        _MEANT_OFF, "PR 29: measured -31%, PERF.md §6"),
+    ("network", "pallas_lstm"): (
+        _MEANT_OFF, "PR 29: never compiled for the chip since round 4, "
+        "PERF.md §6"),
+    ("network", "pallas_lstm_block"): (None, ""),
+    ("network", "pallas_lstm_interpret"): (None, ""),
+    ("optim", "pallas_decode_layout"): (
+        ("planar",), "PR 29: measured a loss, and the decode now follows the "
+        "shapes, PERF.md §6"),
+    ("optim", "fused_double_unroll"): (
+        _MEANT_OFF, "PR 29: measured neutral, PERF.md §6"),
+}
+
+
+def _retired(section: str, fname: str, value: Any) -> bool:
+    """Whether ``section.fname`` is a retired option at a value that may be
+    dropped; at a value that asked for the removed path, ``ValueError``."""
+    if (section, fname) not in _RETIRED_FIELDS:
+        return False
+    harmless, why = _RETIRED_FIELDS[(section, fname)]
+    spelling = (str(int(value)) if isinstance(value, bool)
+                else str(value)).lower()
+    if harmless is not None and spelling not in harmless:
+        raise ValueError(
+            f"`{section}.{fname}={value}` was removed in {why}; the option "
+            "no longer exists, drop it from the config")
+    return True
+
 
 # Field annotations are strings (PEP 563 via `from __future__ import
 # annotations`); only scalar fields are CLI-settable.
@@ -1959,6 +1962,8 @@ def parse_overrides(cfg: Config, argv: List[str]) -> Config:
         section, _, fname = key.partition(".")
         if section not in {f.name for f in dataclasses.fields(cfg)}:
             raise SystemExit(f"unknown config section {section!r}")
+        if _retired(section, fname, raw):
+            continue
         sub = getattr(cfg, section)
         group, _, leaf = fname.partition(".")
         if leaf and (section, group) in _NESTED_TYPES:

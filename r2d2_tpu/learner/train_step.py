@@ -98,8 +98,7 @@ def sync_target(optim: OptimConfig, use_double: bool, new_step, params,
 
 
 def _decode_inputs(net: NetworkApply, spec: ReplaySpec, batch: SampleBatch,
-                   use_pallas: bool,
-                   nhwc: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                   use_pallas: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """THE storage→network decode (one place for every unroll path): uint8
     frame rows → stacked normalized obs of logical shape (B,T,H,W,K), action
     indices → one-hot (-1 encodes the null action as zeros). Which decode
@@ -119,19 +118,19 @@ def _decode_inputs(net: NetworkApply, spec: ReplaySpec, batch: SampleBatch,
                                use_pallas=use_pallas,
                                out_dtype=net.module.compute_dtype,
                                out_height=spec.frame_height,
-                               out_width=spec.frame_width, nhwc=nhwc)
+                               out_width=spec.frame_width)
         last_action = jax.nn.one_hot(batch.last_action, net.action_dim,
                                      dtype=jnp.float32)
     return stacked, last_action
 
 
 def _unrolled_q(net: NetworkApply, spec: ReplaySpec, params,
-                batch: SampleBatch, use_pallas: bool = False,
-                nhwc: bool = False) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+                batch: SampleBatch, use_pallas: bool = False
+                ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Decode (see _decode_inputs) and unroll the full window from the
     stored hidden state. Returns (B, T, A) f32 Q-values and the counters
     the core sowed ({} for the LSTM)."""
-    stacked, last_action = _decode_inputs(net, spec, batch, use_pallas, nhwc)
+    stacked, last_action = _decode_inputs(net, spec, batch, use_pallas)
     q, _, counters = net.apply_learner(params, stacked, last_action,
                                        batch.hidden)
     return q, counters
@@ -142,40 +141,17 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
     """Returns loss(params, target_params, batch) -> (loss, aux). Pure —
     shared by the single-chip jit, the shard_map path, and the tests."""
 
-    from r2d2_tpu.ops.pallas_kernels import (
-        resolve_pallas_obs_decode, resolve_pallas_setting)
+    from r2d2_tpu.ops.pallas_kernels import resolve_pallas_obs_decode
     use_pallas = resolve_pallas_obs_decode(optim.pallas_obs_decode)
-    layout = str(optim.pallas_decode_layout).lower()
-    if layout not in ("planar", "nhwc"):
-        raise ValueError("optim.pallas_decode_layout must be 'planar' or "
-                         f"'nhwc'; got {optim.pallas_decode_layout!r}")
-    nhwc = layout == "nhwc"
-    # double-DQN only: interleave the two unrolls' recurrent chains in one
-    # scan (two sequential while-loops cannot overlap — see
-    # models/network.py dual_sequence_q); identical math, parity-tested
-    fused_dual = (use_double and net.config.core.kind == "lstm"
-                  and resolve_pallas_setting(optim.fused_double_unroll,
-                                             "optim.fused_double_unroll"))
 
     def loss_fn(params, target_params, batch: SampleBatch):
-        counters = {}
-        if fused_dual:
-            from r2d2_tpu.models.network import dual_sequence_q
-            stacked, last_action = _decode_inputs(net, spec, batch,
-                                                  use_pallas, nhwc)
-            q_online, q_target_all = dual_sequence_q(
-                net, params, target_params, stacked, last_action,
-                batch.hidden, batch.hidden)
-        else:
-            q_online, counters = _unrolled_q(net, spec, params, batch,
-                                             use_pallas, nhwc)
+        q_online, counters = _unrolled_q(net, spec, params, batch, use_pallas)
 
-        # the target unroll stays on the non-fused double path below, so
-        # it is computed BEFORE entering the loss scope — its ops keep
-        # their torso/lstm/head component scopes un-nested
-        if use_double and not fused_dual:
+        # the target unroll is computed BEFORE entering the loss scope —
+        # its ops keep their torso/lstm/head component scopes un-nested
+        if use_double:
             q_target_all, _ = _unrolled_q(net, spec, target_params, batch,
-                                          use_pallas, nhwc)
+                                          use_pallas)
 
         # "loss" component scope (ISSUE 9): everything below is gathers
         # + masked reductions over the unrolled Q — cheap, but

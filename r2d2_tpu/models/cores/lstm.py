@@ -36,14 +36,8 @@ class LSTMCore:
         nothing."""
         from r2d2_tpu.models.network import (HoistedLSTM, pack_hidden,
                                              unpack_hidden)
-        from r2d2_tpu.ops.pallas_kernels import resolve_pallas_setting
         cfg = self.config
         cell = HoistedLSTM(features=cfg.hidden_dim, dtype=self.dtype,
-                           unroll=cfg.scan_unroll,
-                           use_pallas=resolve_pallas_setting(
-                               cfg.pallas_lstm, "network.pallas_lstm"),
-                           pallas_block_t=cfg.pallas_lstm_block,
-                           pallas_interpret=cfg.pallas_lstm_interpret,
-                           name=self.scope)
+                           unroll=cfg.scan_unroll, name=self.scope)
         carry, outputs = cell(unpack_hidden(state.astype(self.dtype)), x_seq)
         return outputs, pack_hidden(carry).astype(jnp.float32)

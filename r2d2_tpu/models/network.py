@@ -56,46 +56,19 @@ def initial_hidden(batch_size: int, hidden_dim: int, dtype=jnp.float32) -> jnp.n
     return jnp.zeros((batch_size, 2, hidden_dim), dtype=dtype)
 
 
-def space_to_depth_2x2(x: jnp.ndarray) -> jnp.ndarray:
-    """(B, H, W, C) -> (B, H/2, W/2, 4C); channel index (dh*2 + dw)*C + c."""
-    b, h, w, c = x.shape
-    x = x.reshape(b, h // 2, 2, w // 2, 2, c)
-    return x.transpose(0, 1, 3, 2, 4, 5).reshape(b, h // 2, w // 2, 4 * c)
-
-
 class ConvTorso(nn.Module):
     """Nature-DQN feature extractor (ref model.py:22-31), NHWC.
 
     Input: (B, H, W, stack) normalized f32/bf16. Output: (B, cnn_out_dim).
-
-    ``space_to_depth``: rewrite the FIRST conv as the mathematically
-    identical conv over a 2x2 space-to-depth input — kernel/stride halved,
-    input channels x4 (stack 4 -> 16). The first conv's tiny channel count
-    otherwise wastes most of the MXU's 128 input lanes; the transform is
-    EXACT (same linear map, weights re-indexed — parity-tested), it only
-    changes the parameter layout, so checkpoints are specific to the
-    setting like any architecture field. Requires even H/W/kernel/stride
-    on layer 0 (validated by NetworkApply).
     """
 
     cnn_out_dim: int
     conv_layers: Sequence[Tuple[int, int, int]]
     dtype: jnp.dtype
-    space_to_depth: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        for i, (features, kernel, stride) in enumerate(self.conv_layers):
-            if i == 0 and self.space_to_depth:
-                if kernel % 2 or stride % 2:
-                    raise ValueError(
-                        f"space_to_depth needs an even first-conv "
-                        f"kernel/stride (got {kernel}/{stride}) — an odd "
-                        "value would silently change the architecture "
-                        "instead of being the exact rewrite")
-                x = space_to_depth_2x2(x)
-                kernel //= 2
-                stride //= 2
+        for features, kernel, stride in self.conv_layers:
             # VALID padding matches torch Conv2d's default zero-pad=0.
             x = nn.Conv(
                 features,
@@ -108,33 +81,6 @@ class ConvTorso(nn.Module):
         x = x.reshape(x.shape[0], -1)
         x = nn.Dense(self.cnn_out_dim, dtype=self.dtype)(x)
         return x
-
-
-def convert_params_space_to_depth(params, frame_stack: int):
-    """Migrate a standard-layout checkpoint to the space_to_depth layout:
-    re-index the first conv's kernel (2k, 2k, C, O) -> (k, k, 4C, O) with
-    w'[ph, pw, (dh*2+dw)*C + c, o] = w[2ph+dh, 2pw+dw, c, o] — the exact
-    transform ConvTorso applies to the input, so the converted checkpoint
-    computes identical outputs (parity-tested). Use when flipping
-    network.space_to_depth on for a warm start from an off-layout run."""
-    import flax
-    params = flax.core.unfreeze(params) if hasattr(params, "unfreeze") else \
-        jax.tree_util.tree_map(lambda x: x, params)
-    torso = params["params"]["torso"]
-    w = jnp.asarray(torso["Conv_0"]["kernel"])
-    kh, kw, c, o = w.shape
-    if c != frame_stack:
-        raise ValueError(
-            f"first conv kernel has {c} input channels; expected the "
-            f"standard layout's frame_stack={frame_stack} — already "
-            "converted?")
-    if kh % 2 or kw % 2:
-        raise ValueError(f"first conv kernel {kh}x{kw} must be even")
-    torso["Conv_0"]["kernel"] = (
-        w.reshape(kh // 2, 2, kw // 2, 2, c, o)
-         .transpose(0, 2, 1, 3, 4, 5)
-         .reshape(kh // 2, kw // 2, 4 * c, o))
-    return params
 
 
 class DuelingHead(nn.Module):
@@ -207,14 +153,6 @@ class HoistedLSTM(nn.Module):
     # lax.scan unroll factor: >1 trades compile time/code size for fewer
     # loop-iteration boundaries on the serial chain (NetworkConfig.scan_unroll)
     unroll: int = 1
-    # Fused pallas time-scan (ops/pallas_lstm.py) instead of lax.scan —
-    # NetworkConfig.pallas_lstm, resolved. Identical math (the kernel folds
-    # bias into the hoisted projection; tolerance-parity-tested).
-    use_pallas: bool = False
-    # timesteps per kernel grid iteration (NetworkConfig.pallas_lstm_block)
-    pallas_block_t: int = 1
-    # interpret-mode flag for the pallas path (CPU test mesh only)
-    pallas_interpret: bool = False
 
     @nn.compact
     def __call__(self, carry, xs):
@@ -227,17 +165,6 @@ class HoistedLSTM(nn.Module):
         bias = self.param("bias", nn.initializers.zeros, (4 * hidden,))
         w_rec = w_rec.astype(self.dtype)
         bias = bias.astype(self.dtype)
-
-        if self.use_pallas and xs.shape[1] > 1:
-            from r2d2_tpu.ops.pallas_lstm import lstm_scan_pallas
-            # T=1 (the actor's step) stays on the scan path: a one-step
-            # kernel dispatch has nothing to fuse.
-            xpb = (x_proj + bias).swapaxes(0, 1)              # (T, B, 4H)
-            hseq, (c_fin, h_fin) = lstm_scan_pallas(
-                xpb, w_rec, carry[0], carry[1],
-                interpret=self.pallas_interpret,
-                block_t=self.pallas_block_t)
-            return (c_fin, h_fin), hseq.swapaxes(0, 1)
 
         def step(carry, xp):                                  # xp: (B, 4H)
             new_c, new_h = lstm_cell_step(xp, carry[0], carry[1], w_rec, bias)
@@ -289,15 +216,6 @@ class R2D2Network(nn.Module):
         window_stats: bool = False,  # the learner's call (models/cores/)
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         cfg = self.config
-        if not isinstance(cfg.space_to_depth, bool):
-            # unresolved tri-state string: bool("off") is True — a silent
-            # architecture inversion. Direct R2D2Network constructions must
-            # go through NetworkApply (which resolves and validates) or
-            # pass a concrete bool.
-            raise ValueError(
-                "R2D2Network requires a resolved (bool) "
-                f"config.space_to_depth, got {cfg.space_to_depth!r} — "
-                "construct via NetworkApply, which resolves the tri-state")
         dtype = self.compute_dtype
         batch, seq = obs_seq.shape[0], obs_seq.shape[1]
 
@@ -310,7 +228,6 @@ class R2D2Network(nn.Module):
         # component (telemetry/traceparse.py keys on these exact tokens).
         flat, to_sequence = _torso_batch(obs_seq, dtype)
         latent = ConvTorso(cfg.cnn_out_dim, cfg.conv_layers, dtype,
-                           space_to_depth=cfg.space_to_depth,
                            name="torso")(flat)
         latent = to_sequence(latent)
 
@@ -330,86 +247,6 @@ class R2D2Network(nn.Module):
         )(outputs.reshape(batch * seq, core.out_dim))
         q = q.reshape(batch, seq, self.action_dim)
         return q, new_hidden
-
-
-def dual_sequence_q(net: "NetworkApply", params_a, params_b,
-                    obs_seq: jnp.ndarray, last_action_seq: jnp.ndarray,
-                    hidden_a: jnp.ndarray, hidden_b: jnp.ndarray
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Unroll TWO networks (online params_a, target params_b) over the same
-    observation sequence with their recurrent chains interleaved in ONE
-    ``lax.scan``.
-
-    Two separate ``net.apply`` calls lower to two sequential XLA while
-    loops, and XLA cannot overlap across while-loop boundaries — so a
-    double-DQN step pays 2x55 SERIAL recurrent matmuls even though the two
-    chains are independent. Each (B,512)x(512,2048) recurrent matmul is
-    latency-bound, not throughput-bound (PERF.md: batch scaling is flat),
-    so interleaving both chains in one scan body lets the scheduler hide
-    one chain's latency under the other's. Identical math to two applies —
-    the per-chain op sequence is unchanged (parity-tested exactly in
-    tests/test_network.py). Gated by ``optim.fused_double_unroll``; only
-    reachable when use_double is on.
-
-    Targets the serial-LSTM wall of ref worker.py:335-344's three-unroll
-    step (already reduced to two here; this removes the serialization
-    between the remaining two).
-    """
-    cfg = net.config
-    from r2d2_tpu.models.cores import require_lstm
-    require_lstm(cfg, "dual_sequence_q")
-    dtype = net.module.compute_dtype
-    batch, seq = obs_seq.shape[0], obs_seq.shape[1]
-
-    flat, to_sequence = _torso_batch(obs_seq, dtype)
-    torso = ConvTorso(cfg.cnn_out_dim, cfg.conv_layers, dtype,
-                      space_to_depth=cfg.space_to_depth)
-    # explicit component scopes: unlike the module path, these raw
-    # .apply calls carry no flax module names, so the trace→component
-    # mapping (telemetry/traceparse.py) would see the fused-dual
-    # program's ops as unattributed without them
-    with jax.named_scope("torso"):
-        lat_a = torso.apply({"params": params_a["params"]["torso"]}, flat)
-        lat_b = torso.apply({"params": params_b["params"]["torso"]}, flat)
-    la = last_action_seq.astype(dtype)
-
-    def rnn_in(lat):
-        return jnp.concatenate([to_sequence(lat), la], axis=-1)
-
-    def lstm_bits(p):
-        lp = p["params"]["lstm"]
-        return (jnp.asarray(lp["input_proj"]["kernel"]).astype(dtype),
-                jnp.asarray(lp["recurrent_kernel"]).astype(dtype),
-                jnp.asarray(lp["bias"]).astype(dtype))
-
-    wi_a, wr_a, b_a = lstm_bits(params_a)
-    wi_b, wr_b, b_b = lstm_bits(params_b)
-
-    def step(carry, xs):
-        ca, ha, cb, hb = carry
-        xpa, xpb = xs
-        ca, ha = lstm_cell_step(xpa, ca, ha, wr_a, b_a)
-        cb, hb = lstm_cell_step(xpb, cb, hb, wr_b, b_b)
-        return (ca, ha, cb, hb), (ha, hb)
-
-    with jax.named_scope("lstm"):
-        xp_a = (rnn_in(lat_a) @ wi_a).swapaxes(0, 1)    # (T, B, 4H)
-        xp_b = (rnn_in(lat_b) @ wi_b).swapaxes(0, 1)
-        ca, ha = unpack_hidden(hidden_a.astype(dtype))
-        cb, hb = unpack_hidden(hidden_b.astype(dtype))
-        _, (out_a, out_b) = jax.lax.scan(step, (ca, ha, cb, hb),
-                                         (xp_a, xp_b),
-                                         unroll=cfg.scan_unroll)
-
-    head = DuelingHead(net.action_dim, cfg.hidden_dim, cfg.use_dueling, dtype)
-
-    def head_q(params, outs):                            # outs: (T, B, H)
-        q = head.apply({"params": params["params"]["head"]},
-                       outs.swapaxes(0, 1).reshape(batch * seq, cfg.hidden_dim))
-        return q.reshape(batch, seq, net.action_dim)
-
-    with jax.named_scope("head"):
-        return head_q(params_a, out_a), head_q(params_b, out_b)
 
 
 # ---------------------------------------------------------------------------
@@ -544,7 +381,7 @@ def quantized_inference_apply(net: "NetworkApply", qparams,
                               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The quantized twin of ``R2D2Network.__call__``: same signature,
     same module components (ConvTorso / DuelingHead via raw .apply, the
-    shared ``lstm_cell_step`` — the dual_sequence_q pattern), but the
+    shared ``lstm_cell_step``), but the
     weights come dequantized per-channel from the published twin and the
     LSTM CARRY STAYS f32: the recurrent state crosses acting steps
     thousands of times, so carrying it (and the cell math) in f32 keeps
@@ -561,11 +398,10 @@ def quantized_inference_apply(net: "NetworkApply", qparams,
     batch, seq = obs_seq.shape[0], obs_seq.shape[1]
 
     flat = obs_seq.astype(dtype).reshape(batch * seq, *obs_seq.shape[2:])
-    torso = ConvTorso(cfg.cnn_out_dim, cfg.conv_layers, dtype,
-                      space_to_depth=cfg.space_to_depth)
-    # explicit component scopes, like dual_sequence_q: raw .apply calls
-    # carry no flax module names, and the trace→component mapping
-    # (telemetry/traceparse.py) keys on these exact tokens
+    torso = ConvTorso(cfg.cnn_out_dim, cfg.conv_layers, dtype)
+    # explicit component scopes: raw .apply calls carry no flax module
+    # names, and the trace→component mapping (telemetry/traceparse.py)
+    # keys on these exact tokens
     with jax.named_scope("torso"):
         latent = torso.apply({"params": dequantize_tree(qp["torso"], dtype)},
                              flat)
@@ -633,34 +469,8 @@ class NetworkApply:
         # bf16 is emulated and slower).
         from r2d2_tpu.ops.pallas_kernels import resolve_pallas_setting
         import dataclasses
-        if str(config.space_to_depth).lower() == "auto":
-            # unlike the compute-only tri-states, this knob changes the
-            # PARAMETER LAYOUT — a backend-dependent resolution would build
-            # incompatible param trees on heterogeneous hosts (TPU learner
-            # vs CPU-pinned actor processes / eval). Explicit only.
-            raise ValueError(
-                "network.space_to_depth must be 'on' or 'off' ('auto' is "
-                "not allowed: the setting changes the parameter layout, so "
-                "it must resolve identically on every host)")
         config = dataclasses.replace(
-            config, bf16=resolve_pallas_setting(config.bf16, "network.bf16"),
-            space_to_depth=resolve_pallas_setting(
-                config.space_to_depth, "network.space_to_depth"))
-        if config.pallas_lstm_interpret and jax.default_backend() == "tpu":
-            # interpret mode is the CPU mesh's way to run the kernel's
-            # semantics; a run that reports itself as TPU must execute the
-            # compiled kernel or nothing
-            raise ValueError(
-                "network.pallas_lstm_interpret is for CPU dry-runs only; "
-                "this process runs on a TPU — unset it (network.pallas_lstm="
-                "'on' then compiles the kernel through Mosaic)")
-        if config.space_to_depth:
-            _, k0, s0 = config.conv_layers[0]
-            if frame_height % 2 or frame_width % 2 or k0 % 2 or s0 % 2:
-                raise ValueError(
-                    "network.space_to_depth requires even frame dims and an "
-                    f"even first-conv kernel/stride; got {frame_height}x"
-                    f"{frame_width}, kernel {k0}, stride {s0}")
+            config, bf16=resolve_pallas_setting(config.bf16, "network.bf16"))
         self.action_dim = action_dim
         self.config = config
         self.obs_hw = (frame_height, frame_width, frame_stack)

@@ -12,18 +12,16 @@ This is the reference learner's obs_idx gather + /255
 op: normalised in f32, rounded once into the compute dtype. Which
 implementation runs follows from the input's shapes (``decode_route``).
 
-What the TPU path emits (PR 26): ``stack_frames_lanes`` writes the stacked
-observations in the layout the first convolution reads, and hands them over
-as ``LaneFrames`` — the torso's batch, already flattened. XLA runs the whole
-torso with the FRAME INDEX IN LANES: minor to major N, K, W, H, tile (4, 128)
-with two bf16 rows to a 32-bit word, so the K = 4 planes fill a tile's
-sublanes and 128 frames its lanes (bf16[7040,84,84,4]{0,3,2,1:T(4,128)(2,1)}
-at B128 x T55, 397 MB). The kernel's output (H, W, tile, K, 128) has those
-very bytes, XLA's transpose of it is a bitcast, and nothing is copied
-between the kernel and the convolution. Before, the planar kernel below
-wrote (B, T, K, H, W) with (84, 84) minor, padded to 96 x 128 (692 MB), and
-a ``network_glue`` copy read that and wrote the 397 MB: 3.15 ms a step for
-what now takes 0.6 (my chip runs, PR 26).
+On the TPU, where the input fits it, ``stack_frames_lanes`` writes the
+stacked observations in the layout the first convolution reads, and hands
+them over as ``LaneFrames`` — the torso's batch, already flattened. XLA runs
+the whole torso with the FRAME INDEX IN LANES: minor to major N, K, W, H,
+tile (4, 128) with two bf16 rows to a 32-bit word, so the K = 4 planes fill a
+tile's sublanes and 128 frames its lanes (bf16[7040,84,84,4]{0,3,2,1:T(4,128)
+(2,1)} at B128 x T55, 397 MB). The kernel's output (H, W, tile, K, 128) has
+those very bytes, XLA's transpose of it is a bitcast, and nothing is copied
+between the kernel and the convolution (`k_decode_roofline` 92.6%: ledger,
+PR 26).
 
 How: a lane tile is 128 frames of one time step — 128 sequences' step t
 where the batch fills the lanes, or step i of each of 128/B time segments
@@ -35,15 +33,14 @@ load puts a word row of all 128 frames on sublanes, a 128 x 128 transpose
 puts the frames on lanes, each byte is normalised and rounded (to bf16 by
 hand, in integer arithmetic: two planes share a word), kept in VMEM for the
 K - 1 tiles that still need it, and written with 32-bit strided stores.
-Mosaic took this route as written; what it had refused before (PERF.md §6:
-16-bit strided stores, lane-merging reshapes, a trailing K = 4) is not on
-it. The kernel's lowering is the same size for every window and batch: time,
-rows and segments loop in the grid or a ``fori_loop``.
+Mosaic refuses 16-bit strided stores, lane-merging reshapes and a trailing
+K = 4, so none is on this route. The kernel's lowering is the same size for
+every window and batch: time, rows and segments loop in the grid or a
+``fori_loop``.
 
-Layout note (measured, round 3), for the planar kernel
-``stack_frames_pallas``, which stays the Pallas path of shapes the lanes
-route does not take (a batch that does not tile 128 lanes, storage that is
-not tile-padded, an odd stack in bf16): it emits (B, T, K, H, W) — K
+The planar kernel ``stack_frames_pallas`` is the Pallas path of the shapes
+the lanes route does not take (a batch that does not tile 128 lanes, storage
+that is not tile-padded, an odd stack in bf16): it emits (B, T, K, H, W) — K
 *before* the spatial dims — and the wrapper transposes to the public
 (B, T, H, W, K) contract outside the kernel. Emitting K minor-most
 directly is catastrophic on TPU: the (8, 128) register tile pads the
@@ -113,76 +110,16 @@ def _stack_kernel(frame_stack: int, out_dtype, out_height: int,
         out_ref[0, 0, k] = (widened * inv).astype(out_dtype)
 
 
-def _decode_plane(in_ref, t, k, out_height: int, out_width: int):
-    """One frame plane, decoded to normalized f32 (H, W) in registers.
-    Mosaic can't cast uint8 -> f32 directly (round 2): widen via i32."""
-    from jax.experimental import pallas as pl
-
-    frame = in_ref[0, pl.dslice(t + k, 1)]                   # (1, H, W) u8
-    widened = frame[0, :out_height, :out_width].astype(
-        jnp.int32).astype(jnp.float32)
-    return widened * jnp.float32(1.0 / 255.0)
-
-
-def _stack_kernel_nhwc32(frame_stack: int, out_dtype, out_height: int,
-                         out_width: int, in_ref, out_ref):
-    # NHWC-emitting variant for 32-bit out_dtype: interleave K into the
-    # LANE dim (out lane index = w*K + k) with one strided store per
-    # plane, so the public (B, T, H, W, K) contract is a free reshape of
-    # the kernel output — no post-kernel transpose. The relayout happens
-    # in VMEM registers per timestep instead of as an HBM round-trip (the
-    # 1.6 ms/step layout copy in the round-3 profile). Strided stores are
-    # implemented for 32-bit data only (v5e Mosaic), hence the packed
-    # 16-bit variant below.
-    from jax.experimental import pallas as pl
-
-    t = pl.program_id(1)
-    for k in range(frame_stack):
-        val = _decode_plane(in_ref, t, k, out_height, out_width)
-        out_ref[0, 0, :, pl.Slice(k, out_width, frame_stack)] = (
-            val.astype(out_dtype))
-
-
-def _stack_kernel_nhwc16(frame_stack: int, out_dtype, out_height: int,
-                         out_width: int, in_ref, out_ref):
-    # NHWC-emitting variant for 16-bit out_dtype (the bf16 policy).
-    # Mosaic rejects every direct 16-bit relayout route on v5e: bf16
-    # minor-dim insertion ("32-bit only"), the (H,W,K)->(H,W*K)
-    # lane-merge reshape, and 16-bit strided stores. The working route
-    # is PAIR PACKING: bitcast each bf16 plane to u16, pack planes
-    # 2p/2p+1 into the low/high halves of one i32 vector, and emit with
-    # 32-bit strided stores into an i32 output at lane j = w*(K/2) + p.
-    # The wrapper's i32 -> out_dtype bitcast appends a trailing dim of 2
-    # indexing [low, high] bits (XLA narrowing convention), so final
-    # bf16 lane l = j*2 + e = w*K + 2p + e = w*K + k — exactly NHWC.
-    from jax.experimental import pallas as pl
-
-    t = pl.program_id(1)
-    pairs = frame_stack // 2
-    for p in range(pairs):
-        lo = jax.lax.bitcast_convert_type(
-            _decode_plane(in_ref, t, 2 * p, out_height, out_width)
-            .astype(out_dtype), jnp.uint16).astype(jnp.int32)
-        hi = jax.lax.bitcast_convert_type(
-            _decode_plane(in_ref, t, 2 * p + 1, out_height, out_width)
-            .astype(out_dtype), jnp.uint16).astype(jnp.int32)
-        packed = jax.lax.bitwise_or(lo, jax.lax.shift_left(hi, 16))
-        out_ref[0, 0, :, pl.Slice(p, out_width, pairs)] = packed
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
 def stack_frames_pallas(obs: jnp.ndarray, seq_window: int, frame_stack: int,
                         interpret: bool = False,
                         out_dtype=jnp.float32,
                         out_height=None,
-                        nhwc: bool = False,
                         out_width=None) -> jnp.ndarray:
     """Pallas implementation; ``interpret=True`` runs it on any backend
     (tests use it on the CPU mesh). ``out_height``/``out_width``: emit only
     the first out_height x out_width pixels of each (possibly tile-padded)
-    stored frame. ``nhwc``: emit the NHWC layout in-kernel (no post-kernel
-    transpose — see _stack_kernel_nhwc); optim.pallas_decode_layout
-    selects it."""
+    stored frame."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -191,32 +128,9 @@ def stack_frames_pallas(obs: jnp.ndarray, seq_window: int, frame_stack: int,
     out_height = height if out_height is None else out_height
     out_width = width if out_width is None else out_width
 
-    if nhwc:
-        itemsize = jnp.dtype(out_dtype).itemsize
-        if itemsize == 2 and frame_stack % 2 == 0:
-            # packed route (see _stack_kernel_nhwc16): i32 storage holding
-            # bf16 pairs; bitcast back outside the kernel (layout-free)
-            kernel = functools.partial(_stack_kernel_nhwc16, frame_stack,
-                                       out_dtype, out_height, out_width)
-            out_block = (1, 1, out_height, out_width * frame_stack // 2)
-            store_dtype = jnp.int32
-        elif itemsize == 4:
-            kernel = functools.partial(_stack_kernel_nhwc32, frame_stack,
-                                       out_dtype, out_height, out_width)
-            out_block = (1, 1, out_height, out_width * frame_stack)
-            store_dtype = out_dtype
-        else:
-            raise NotImplementedError(
-                f"nhwc decode needs a 32-bit out_dtype or a 16-bit one "
-                f"with even frame_stack; got {jnp.dtype(out_dtype).name} "
-                f"with frame_stack={frame_stack}")
-        out_map = lambda b, t: (b, t, 0, 0)
-    else:
-        kernel = functools.partial(_stack_kernel, frame_stack, out_dtype,
-                                   out_height, out_width)
-        out_block = (1, 1, frame_stack, out_height, out_width)
-        out_map = lambda b, t: (b, t, 0, 0, 0)
-        store_dtype = out_dtype
+    kernel = functools.partial(_stack_kernel, frame_stack, out_dtype,
+                               out_height, out_width)
+    out_block = (1, 1, frame_stack, out_height, out_width)
     out = pl.pallas_call(
         kernel,
         grid=(batch, seq_window),
@@ -225,32 +139,13 @@ def stack_frames_pallas(obs: jnp.ndarray, seq_window: int, frame_stack: int,
             lambda b, t: (b, 0, 0, 0),   # constant in t: one DMA per row
             memory_space=pltpu.VMEM,
         )],
-        out_specs=pl.BlockSpec(out_block, out_map,
+        out_specs=pl.BlockSpec(out_block, lambda b, t: (b, t, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(
-            (batch, seq_window) + out_block[2:], store_dtype),
+            (batch, seq_window) + out_block[2:], out_dtype),
         interpret=interpret,
     )(obs)
-    if nhwc:
-        if store_dtype != out_dtype:
-            # i32 -> (..., 2) out_dtype; index 0 = low 16 bits (XLA
-            # narrowing convention), matching the kernel's pack order
-            out = jax.lax.bitcast_convert_type(out, out_dtype)
-        # lane index = w*K + k, so this reshape is layout-free
-        return out.reshape(batch, seq_window, out_height, out_width,
-                           frame_stack)
     return out.transpose(0, 1, 3, 4, 2)                      # (B, T, H, W, K)
-
-
-def stack_frames_pallas_nhwc(obs: jnp.ndarray, seq_window: int,
-                             frame_stack: int, interpret: bool = False,
-                             out_dtype=jnp.float32,
-                             out_height=None,
-                             out_width=None) -> jnp.ndarray:
-    """NHWC-emitting decode (stack_frames_pallas with nhwc=True)."""
-    return stack_frames_pallas(obs, seq_window, frame_stack, interpret,
-                               out_dtype, out_height, nhwc=True,
-                               out_width=out_width)
 
 
 # ---------------------------------------------------------------------------
@@ -567,17 +462,13 @@ def resolve_pallas_obs_decode(setting) -> bool:
 
 
 def decode_route(obs_shape, seq_window: int, frame_stack: int,
-                 use_pallas: bool = False, out_dtype=jnp.float32,
-                 nhwc: bool = False) -> str:
+                 use_pallas: bool = False, out_dtype=jnp.float32) -> str:
     """The decode ``stack_frames`` takes for this input, by name:
-    "reference" (jnp), "nhwc" (optim.pallas_decode_layout asked for it),
-    "lanes" (the first convolution's own layout, where ``lanes_route``
-    admits the input) or "planar" (the Pallas kernel for every other
-    shape)."""
+    "reference" (jnp), "lanes" (the first convolution's own layout, where
+    ``lanes_route`` admits the input) or "planar" (the Pallas kernel for
+    every other shape)."""
     if not use_pallas:
         return "reference"
-    if nhwc:
-        return "nhwc"
     if lanes_route(obs_shape, seq_window, frame_stack, out_dtype):
         return "lanes"
     return "planar"
@@ -587,21 +478,20 @@ def stack_frames(obs: jnp.ndarray, seq_window: int, frame_stack: int,
                  use_pallas: bool = False,
                  out_dtype=jnp.float32,
                  out_height=None,
-                 nhwc: bool = False,
                  out_width=None):
     """Dispatch by ``decode_route``: pallas on TPU when requested, jnp
     otherwise. Returns the (B, T, H, W, K) array, or on the "lanes" route
     ``LaneFrames`` of that logical shape."""
     route = decode_route(obs.shape, seq_window, frame_stack, use_pallas,
-                         out_dtype, nhwc)
+                         out_dtype)
     if route == "lanes":
         return stack_frames_lanes(obs, seq_window, frame_stack,
                                   out_dtype=out_dtype, out_height=out_height,
                                   out_width=out_width)
-    if route != "reference":
+    if route == "planar":
         return stack_frames_pallas(obs, seq_window, frame_stack,
                                    out_dtype=out_dtype, out_height=out_height,
-                                   nhwc=nhwc, out_width=out_width)
+                                   out_width=out_width)
     return stack_frames_reference(obs, seq_window, frame_stack,
                                   out_dtype=out_dtype, out_height=out_height,
                                   out_width=out_width)
@@ -684,8 +574,9 @@ def gather_rows_exact_pallas(ring: jnp.ndarray, block_idx: jnp.ndarray,
     rejected round 4 (dim-3 tiling is 128), which is why this variant
     pairs with ``replay.pallas_exact_gather`` (storage padded 84x84 →
     96x128, the uint8 (32, 128) tile). Whether the padded copy
-    compiles/wins is a TPU measurement (bench.py's pad-gather cell);
-    interpret mode pins the semantics either way."""
+    compiles/wins is a TPU measurement (``k_gather_roofline`` of the
+    benchmark's learner cells); interpret mode pins the semantics either
+    way."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

@@ -189,35 +189,3 @@ def run_tiny_tp_step(mesh) -> float:
     loss = float(jax.device_get(metrics["loss"]))
     assert np.isfinite(loss), f"non-finite tp loss {loss}"
     return loss
-
-
-def run_tiny_plstm_step() -> float:
-    """One SINGLE-device fused learner step with the pallas LSTM time-scan
-    kernel (ops/pallas_lstm.py) in interpret mode: the driver's multichip
-    artifact then carries an execution of the kernel's exact semantics —
-    lean forward for the target unroll, residual-saving forward + custom-
-    VJP backward for the online unroll, inside the jitted step — on any
-    backend, even though Mosaic only compiles it on TPU. Returns the loss."""
-    import dataclasses
-
-    import jax
-
-    from r2d2_tpu.learner import create_train_state, make_learner_step
-    from r2d2_tpu.models import init_network
-    from r2d2_tpu.replay.device_replay import replay_add, replay_init
-
-    spec, opt, net = _tiny_setup()
-    ncfg = dataclasses.replace(net.config, pallas_lstm="on",
-                               pallas_lstm_interpret=True)
-    net_pl, _ = init_network(jax.random.PRNGKey(0), 4, ncfg, frame_stack=2,
-                             frame_height=20, frame_width=20)
-    ts = create_train_state(jax.random.PRNGKey(1), net_pl, opt)
-    rs = replay_init(spec)
-    rng = np.random.default_rng(0)
-    for _ in range(spec.num_blocks):
-        rs = replay_add(spec, rs, _synthetic_block(spec, rng))
-    step = make_learner_step(net_pl, spec, opt, use_double=True)
-    ts, rs, metrics = step(ts, rs)
-    loss = float(jax.device_get(metrics["loss"]))
-    assert np.isfinite(loss), f"non-finite plstm loss {loss}"
-    return loss

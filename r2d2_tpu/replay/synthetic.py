@@ -1,6 +1,7 @@
-"""Reference-shaped synthetic block builder — shared by bench.py and the
-production soak (tools/soak.py) so the two can never construct divergent
-data when the Block schema changes.
+"""Reference-shaped synthetic block builder — shared by the cost model
+(telemetry/costmodel.py), tools/roofline.py and the production soak
+(tools/soak.py) so they can never construct divergent data when the Block
+schema changes.
 
 The shapes mirror what LocalBuffer emits at the reference configuration
 (/root/reference/worker.py:86-91,492): a full block of S sequences with a

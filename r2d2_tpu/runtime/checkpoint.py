@@ -91,55 +91,15 @@ def restore_checkpoint(path: str, template: Optional[Dict[str, Any]] = None
             f"checkpoint at {path!r} does not match the current network's "
             "parameter tree — it was likely saved by an older architecture "
             "revision (parameter names/shapes changed). Re-train, or "
-            "restore with an explicitly matching template. If the only "
-            "change is flipping network.space_to_depth, migrate the params "
-            "with r2d2_tpu.models.network.convert_params_space_to_depth "
-            "(the runtime.pretrain warm-start path migrates "
-            "automatically).\n"
+            "restore with an explicitly matching template.\n"
             f"original error: {type(e).__name__}: {e}") from e
-
-
-def _maybe_migrate_space_to_depth(params, params_template):
-    """Auto-migrate a standard-layout checkpoint to the space_to_depth
-    layout when the template expects it (round-3 advisor: warm-starting
-    with network.space_to_depth=on from an off-layout run previously died
-    with the generic mismatch error, never mentioning the exact-rewrite
-    migration that exists). The reverse direction is refused loudly —
-    downgrading a layout silently would be surprising."""
-    try:
-        t_kernel = np.asarray(
-            params_template["params"]["torso"]["Conv_0"]["kernel"])
-        p_kernel = np.asarray(params["params"]["torso"]["Conv_0"]["kernel"])
-    except (KeyError, TypeError):
-        return params                     # unfamiliar tree: leave untouched
-    if t_kernel.shape == p_kernel.shape:
-        return params
-    tkh, tkw, tc, to = t_kernel.shape
-    pkh, pkw, pc, po = p_kernel.shape
-    if (tc, tkh, tkw) == (4 * pc, pkh // 2, pkw // 2) and to == po:
-        import logging
-        from r2d2_tpu.models.network import convert_params_space_to_depth
-        logging.getLogger(__name__).info(
-            "pretrain checkpoint uses the standard first-conv layout; "
-            "auto-migrating to space_to_depth (exact rewrite)")
-        return convert_params_space_to_depth(params, frame_stack=pc)
-    if (pc, pkh, pkw) == (4 * tc, tkh // 2, tkw // 2):
-        raise ValueError(
-            "pretrain checkpoint uses the space_to_depth first-conv layout "
-            "but the current network has network.space_to_depth=off — set "
-            "it to 'on' (the transform is exact; there is no automatic "
-            "downgrade)")
-    return params
 
 
 def load_pretrain(path: str, params_template):
     """Weights-only warm start (ref worker.py:260-261,511-512): restores just
-    ``params`` from a checkpoint directory, leaving optimizer/step fresh.
-    A standard-layout checkpoint loaded into a space_to_depth network is
-    migrated automatically (exact rewrite; see convert_params_space_to_depth)."""
+    ``params`` from a checkpoint directory, leaving optimizer/step fresh."""
     restored = restore_checkpoint(path)
     params = restored["params"] if isinstance(restored, dict) else restored
-    params = _maybe_migrate_space_to_depth(params, params_template)
 
     # conform dtypes to the template; shape mismatches fail HERE with the
     # param's path named instead of surfacing later inside apply

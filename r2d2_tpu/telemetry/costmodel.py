@@ -234,15 +234,8 @@ def analytic_component_costs(cfg, action_dim: int,
 
     total_flops = sum(c["flops"] for c in components.values())
     # the serial recurrent chain (PERF.md round-5 model): fwd + bwd
-    # always walk the chain; the target fwd adds a third walk under
-    # double-DQN unless the fused dual unroll interleaves it with the
-    # online chain in the same scan. Resolved EXACTLY like the real
-    # program (train_step.make_loss_fn) — "auto" is backend-dependent,
-    # and a hand-rolled string check would claim the wrong chain length
-    from r2d2_tpu.ops.pallas_kernels import resolve_pallas_setting
-    fused_dual = use_double and resolve_pallas_setting(
-        cfg.optim.fused_double_unroll, "optim.fused_double_unroll")
-    serial_walks = 2 + (1 if (use_double and not fused_dual) else 0)
+    # always walk the chain; the target fwd adds a third under double-DQN
+    serial_walks = 3 if use_double else 2
     serial_iters = T * serial_walks
     serial_flops = 2.0 * 4 * H * H * B * serial_iters
     return {

@@ -10,8 +10,8 @@ buckets per stage, and MERGEABILITY — counts from every actor process
 add elementwise, so one fleet-wide percentile falls out of summing rows
 of the shared-memory board (board.py). Resolution is the bucket growth
 factor (~33% here: 8 buckets per decade over 1 µs .. 100 s), plenty for
-"P99 queue wait jumped 10x", useless for microbenchmarks — bench.py
-keeps exact timing.
+"P99 queue wait jumped 10x", useless for microbenchmarks —
+benchmarks/run.py keeps exact timing.
 """
 
 import math

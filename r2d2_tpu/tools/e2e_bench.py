@@ -1,6 +1,6 @@
 """End-to-end actors→learner throughput benchmark.
 
-Everything measured before this tool was learner-only (bench.py: fused-step
+Everything measured before this tool was learner-only (fused-step
 seq-updates/s on synthetic batches; tools/soak.py: device-side ring
 behavior). This tool measures the SYSTEM: how fast experience is generated
 and how fast it is consumed, simultaneously — the reference's two logged
@@ -962,8 +962,6 @@ def quant_weight_bytes_table(overrides: Optional[dict] = None) -> dict:
     what the TPU projection is about), plus this bench's reduced shape
     for context. Pure eval_shape math, no compile; the int8 ratio is
     the >= 3x cut the costmodel gate also snapshots exactly."""
-    import dataclasses
-
     import jax
 
     from r2d2_tpu.config import Config, NetworkConfig
@@ -987,11 +985,10 @@ def quant_weight_bytes_table(overrides: Optional[dict] = None) -> dict:
     bench = _bench_config(dict(E2E_CPU_OVERRIDES, **(overrides or {})))
     return {
         "reference_shape": row(
-            dataclasses.replace(NetworkConfig(), space_to_depth="off"),
-            ref.env.frame_stack, ref.env.frame_height, ref.env.frame_width),
+            NetworkConfig(), ref.env.frame_stack, ref.env.frame_height,
+            ref.env.frame_width),
         "bench_shape": row(
-            dataclasses.replace(bench.network, space_to_depth="off"),
-            bench.env.frame_stack, bench.env.frame_height,
+            bench.network, bench.env.frame_stack, bench.env.frame_height,
             bench.env.frame_width),
     }
 
@@ -1933,10 +1930,6 @@ ANAKIN_AB_OVERRIDES = {
     "env.frame_stack": 2, "env.episode_len": 200,
     "network.hidden_dim": 16, "network.cnn_out_dim": 16,
     "network.conv_layers": ((4, 4, 4),),
-    # exact first-conv rewrite (models/network.py, parity-tested): on this
-    # CPU the 2-input-channel conv is the fused scan's hottest op and the
-    # s2d layout runs it ~25% faster; identical math in BOTH arms
-    "network.space_to_depth": True,
     "sequence.burn_in_steps": 8, "sequence.learning_steps": 5,
     "sequence.forward_steps": 3,
     # capacity = anakin lanes x block_length: the ring must hold one full

@@ -32,7 +32,7 @@ def capture_step_trace(cfg: Config, steps: int, out_dir: str,
     kernels, bf16, steps_per_dispatch) under a profiler trace; returns
     ``out_dir``. Replay is filled with synthetic blocks at the configured
     shapes, so no actors/envs are involved — this profiles the learner
-    alone, like bench.py."""
+    alone, like the benchmark's learner cells."""
     import jax
     import numpy as np
 

@@ -1,11 +1,10 @@
 """Noise-aware bench regression gate (ISSUE 7): compare fresh
-``E2E_*``/``BENCH_*`` artifacts against the ``bench`` section of
+``E2E_*`` artifacts against the ``bench`` section of
 ``BASELINE.json``.
 
-Every perf round leaves a JSON artifact (tools/e2e_bench.py A/Bs,
-bench.py's learner matrix), but nothing ever COMPARED two rounds — a 20%
-throughput regression would merge silently as long as tests stayed
-green. This gate closes that hole:
+Every perf round leaves a JSON artifact (tools/e2e_bench.py A/Bs), but
+nothing ever COMPARED two rounds — a 20% throughput regression would
+merge silently as long as tests stayed green. This gate closes that hole:
 
   * ``--update`` snapshots the throughput metrics of every artifact in
     ``--dir`` into ``BASELINE.json["bench"]`` (one dotted-path → value
@@ -56,10 +55,9 @@ DEFAULT_TOLERANCES = (
     ("speedup", 0.15),         # derived from two single-run cells
     ("vs_baseline", 0.15),
     ("_per_sec", 0.15),        # raw single-run cells (±10% host noise)
-    ("value", 0.15),           # bench.py headline
 )
 _WATCH = tuple(k for k, _ in DEFAULT_TOLERANCES)
-DEFAULT_GLOBS = ("E2E_*.json", "BENCH_*.json")
+DEFAULT_GLOBS = ("E2E_*.json",)
 
 
 def metric_tolerance(path: str, override: Optional[float] = None) -> float:
@@ -172,7 +170,7 @@ def main(argv=None) -> int:
                    help="directory holding the fresh artifacts")
     p.add_argument("--artifacts", nargs="*", default=None,
                    help="explicit artifact filenames (default: the "
-                        "E2E_*/BENCH_* globs)")
+                        "E2E_* glob)")
     p.add_argument("--tolerance", type=float, default=None,
                    help="override the per-metric tolerance table with one "
                         "relative-drop bound for everything")

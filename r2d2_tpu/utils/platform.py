@@ -129,8 +129,7 @@ def _decode_facts(cfg, bf16: bool, use_pallas: bool):
          spec.stored_frame_height, spec.stored_frame_width),
         spec.seq_window, spec.frame_stack,
         use_pallas=use_pallas,
-        out_dtype=jnp.bfloat16 if bf16 else jnp.float32,
-        nhwc=str(cfg.optim.pallas_decode_layout).lower() == "nhwc")
+        out_dtype=jnp.bfloat16 if bf16 else jnp.float32)
     if route != "lanes":
         return route, None
     return route, round(lane_fill(spec.batch_size, spec.seq_window), 4)
@@ -177,8 +176,6 @@ def runtime_report(cfg) -> dict:
                                        "replay.pallas_sample_gather"),
             "pallas_exact_gather": on(cfg.replay.pallas_exact_gather,
                                       "replay.pallas_exact_gather"),
-            "pallas_lstm": on(cfg.network.pallas_lstm,
-                              "network.pallas_lstm"),
             "steps_per_dispatch": cfg.runtime.resolved_steps_per_dispatch(),
             "ingest_batch_blocks":
                 cfg.replay.resolved_ingest_batch_blocks(),

@@ -121,14 +121,6 @@ def test_named_scopes_in_learner_hlo():
         assert token in hlo, f"component scope {token!r} missing from HLO"
 
 
-def test_named_scopes_in_fused_dual_hlo():
-    # the fused double unroll bypasses the named flax modules — its
-    # explicit scopes must keep the program attributable
-    hlo = _learner_step_hlo(gate_cfg(**{"optim.fused_double_unroll": "on"}))
-    for token in ("jvp(torso)", "jvp(lstm)", "jvp(head)"):
-        assert token in hlo, f"fused-dual scope {token!r} missing"
-
-
 def test_named_scopes_in_anakin_hlo():
     from r2d2_tpu.actor.anakin import init_act_carry, make_anakin_act
     cfg = gate_cfg()
@@ -184,26 +176,29 @@ def test_anakin_unroll_twin_bit_identical():
 
 def test_flops_parity_with_xla_cost_model():
     # the ISSUE 9 acceptance bar: the unroll twin's XLA flops and
-    # bench.model_flops_per_step within 5% (XLA counts a while body
+    # costmodel.model_flops_per_step within 5% (XLA counts a while body
     # once, hence the twin; see the costmodel module docstring)
-    import bench
     cfg = gate_cfg()
     table = costmodel.collect_cost_table(cfg, variants=("learner_step",),
                                          unroll_scans=True)
     xla_flops = table["programs"]["learner_step"]["flops"]
     action_dim = table["action_dim"]
-    analytic = bench.model_flops_per_step(cfg, action_dim,
-                                          cfg.network.use_double)
+    analytic = costmodel.model_flops_per_step(cfg, action_dim,
+                                              cfg.network.use_double)
     ratio = xla_flops / analytic
     assert 0.95 <= ratio <= 1.05, f"parity drifted: {ratio:.4f}"
 
 
 def test_model_flops_single_source():
-    # bench.py delegates to the costmodel count — the two can't drift
-    import bench
+    # the component model quotes the same count, and walks the serial
+    # chain once more (the target's forward) under double-DQN
     cfg = gate_cfg()
-    assert bench.model_flops_per_step(cfg, 6, True) == \
-        costmodel.model_flops_per_step(cfg, 6, True)
+    for use_double, walks in ((False, 2), (True, 3)):
+        analytic = costmodel.analytic_component_costs(cfg, 6, use_double)
+        assert analytic["model_flops_per_step"] == \
+            costmodel.model_flops_per_step(cfg, 6, use_double)
+        assert analytic["serial_chain"]["iterations"] == \
+            cfg.sequence.seq_len * walks
     # double-DQN adds exactly one extra unroll of every matmul
     single = costmodel.model_flops_per_step(cfg, 6, False)
     double = costmodel.model_flops_per_step(cfg, 6, True)

@@ -63,27 +63,6 @@ def test_unroll_matches_stepwise(small_net):
     np.testing.assert_allclose(np.asarray(h_full), np.asarray(h), atol=1e-5)
 
 
-def test_dual_sequence_q_matches_two_applies(small_net):
-    """The fused double-DQN unroll (one scan interleaving both recurrent
-    chains — models/network.py dual_sequence_q) must match two separate
-    net.apply calls EXACTLY: the per-chain op sequence is unchanged, only
-    the loop structure differs."""
-    from r2d2_tpu.models.network import dual_sequence_q
-
-    spec, params_a = small_net
-    params_b = spec.init(jax.random.PRNGKey(9))       # a distinct target net
-    obs, la = _rand_inputs(jax.random.PRNGKey(3), 3, 7)
-    hid_a = initial_hidden(3, spec.config.hidden_dim)
-    hid_b = jnp.ones_like(hid_a) * 0.1
-
-    q_a_ref, _ = spec.apply(params_a, obs, la, hid_a)
-    q_b_ref, _ = spec.apply(params_b, obs, la, hid_b)
-    q_a, q_b = dual_sequence_q(spec, params_a, params_b, obs, la,
-                               hid_a, hid_b)
-    np.testing.assert_array_equal(np.asarray(q_a), np.asarray(q_a_ref))
-    np.testing.assert_array_equal(np.asarray(q_b), np.asarray(q_b_ref))
-
-
 def test_padding_suffix_does_not_affect_prefix(small_net):
     """Causality: garbage past a sequence's true end leaves the valid prefix
     bit-identical — this is what licenses fixed-window unrolls over ragged
@@ -239,70 +218,6 @@ def test_online_positions_and_mask():
     mask = learning_step_mask(jnp.array([3, 10]), 10)
     assert mask[0].sum() == 3 and mask[1].sum() == 10
     assert mask[0, 2] == 1.0 and mask[0, 3] == 0.0
-
-
-@pytest.mark.slow
-def test_space_to_depth_is_exact(rng):
-    """network.space_to_depth rewrites the first conv as the SAME linear
-    map over a 2x2 space-to-depth input: with the standard conv's weights
-    re-indexed into the transformed layout, outputs must match to float
-    tolerance (same sums, different association order)."""
-    from r2d2_tpu.models.network import ConvTorso
-
-    B, H, W, C = 4, 84, 84, 4
-    layers = ((32, 8, 4), (64, 4, 2), (64, 3, 1))
-    x = jnp.asarray(rng.uniform(0, 1, (B, H, W, C)), jnp.float32)
-
-    std = ConvTorso(64, layers, jnp.float32)
-    p_std = std.init(jax.random.PRNGKey(0), x)
-    want = std.apply(p_std, x)
-
-    # remap conv1: w2[ph, pw, (dh*2+dw)*C + c, o] = w[2ph+dh, 2pw+dw, c, o]
-    w = p_std["params"]["Conv_0"]["kernel"]            # (8, 8, C, 32)
-    w2 = (w.reshape(4, 2, 4, 2, C, 32)
-           .transpose(0, 2, 1, 3, 4, 5)
-           .reshape(4, 4, 4 * C, 32))
-    p_s2d = jax.tree_util.tree_map(lambda v: v, p_std)
-    p_s2d["params"]["Conv_0"]["kernel"] = w2
-
-    s2d = ConvTorso(64, layers, jnp.float32, space_to_depth=True)
-    got = s2d.apply(p_s2d, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-    # shape contract: param layout differs, output does not
-    init_shapes = jax.tree_util.tree_map(
-        lambda v: v.shape, s2d.init(jax.random.PRNGKey(1), x))
-    assert init_shapes["params"]["Conv_0"]["kernel"] == (4, 4, 16, 32)
-    assert got.shape == want.shape
-
-    # full-network parity through the config knob: a standard-layout
-    # checkpoint migrated by convert_params_space_to_depth must produce
-    # identical Q-values from the s2d network
-    from r2d2_tpu.models.network import (
-        NetworkApply, convert_params_space_to_depth)
-    base_cfg = NetworkConfig(hidden_dim=16, cnn_out_dim=32)
-    net_off = NetworkApply(4, base_cfg, 4, 84, 84)
-    params_off = net_off.init(jax.random.PRNGKey(2))
-    obs = jnp.asarray(rng.uniform(0, 1, (2, 3, 84, 84, 4)), jnp.float32)
-    la = jnp.zeros((2, 3, 4), jnp.float32)
-    from r2d2_tpu.models import initial_hidden
-    q_off, _ = net_off.apply(params_off, obs, la, initial_hidden(2, 16))
-
-    cfg = NetworkConfig(hidden_dim=16, cnn_out_dim=32, space_to_depth="on")
-    net = NetworkApply(4, cfg, 4, 84, 84)
-    params_on = convert_params_space_to_depth(params_off, frame_stack=4)
-    q_on, _ = net.apply(params_on, obs, la, initial_hidden(2, 16))
-    np.testing.assert_allclose(np.asarray(q_on), np.asarray(q_off),
-                               rtol=1e-5, atol=1e-5)
-    with pytest.raises(ValueError, match="already converted"):
-        convert_params_space_to_depth(params_on, frame_stack=4)
-    with pytest.raises(ValueError, match="space_to_depth"):
-        NetworkApply(4, cfg, 4, 83, 84)
-    # "auto" is rejected: a layout-changing knob must resolve identically
-    # on every host (review finding — heterogeneous-backend param trees)
-    with pytest.raises(ValueError, match="auto"):
-        NetworkApply(4, NetworkConfig(space_to_depth="auto"), 4, 84, 84)
 
 
 def test_actor_policy_forces_f32_under_bf16(rng):

@@ -63,10 +63,8 @@ def test_stack_frames_out_height_strips_padding(rng):
 
 def test_stack_frames_out_width_strips_padding(rng):
     """out_width (exact-gather lane-tile padding, 84x84 -> 96x128 at
-    reference scale) strips the lane pad in BOTH pallas kernels (planar
-    and nhwc) and the reference twin, matching an unpadded decode
-    exactly."""
-    from r2d2_tpu.ops.pallas_kernels import stack_frames_pallas_nhwc
+    reference scale) strips the lane pad in the planar pallas kernel and
+    the reference twin, matching an unpadded decode exactly."""
     B, T, K, H, W = 2, 5, 3, 12, 12
     obs = jnp.asarray(rng.integers(0, 255, (B, T + K - 1, H, W)), jnp.uint8)
     obs_pad = jnp.pad(obs, ((0, 0), (0, 0), (0, 4), (0, 6)))  # -> (16, 18)
@@ -75,37 +73,9 @@ def test_stack_frames_out_width_strips_padding(rng):
                                                 out_height=H, out_width=W))
     got_pl = np.asarray(stack_frames_pallas(obs_pad, T, K, True,
                                             out_height=H, out_width=W))
-    got_nhwc = np.asarray(stack_frames_pallas_nhwc(obs_pad, T, K, True,
-                                                   out_height=H, out_width=W))
     np.testing.assert_array_equal(got_ref, want)
     np.testing.assert_allclose(got_pl, want, rtol=2e-7)
-    np.testing.assert_allclose(got_nhwc, want, rtol=2e-7)
-    assert got_pl.shape == got_nhwc.shape == (B, T, H, W, K)
-
-
-def test_stack_frames_nhwc_matches_reference(rng):
-    """The NHWC-emitting decode (K interleaved into the lane dim in-kernel,
-    no post-kernel transpose) matches the reference twin — including with
-    a padded storage height and bf16 output."""
-    from r2d2_tpu.ops.pallas_kernels import stack_frames_pallas_nhwc
-    B, T, K, H, W = 3, 6, 4, 12, 16
-    obs = jnp.asarray(rng.integers(0, 255, (B, T + K - 1 + 2, H, W)),
-                      jnp.uint8)
-    want = np.asarray(stack_frames_reference(obs, T, K))
-    got = np.asarray(stack_frames_pallas_nhwc(obs, T, K, True))
-    assert got.shape == (B, T, H, W, K)
-    np.testing.assert_allclose(got, want, rtol=2e-7)
-
-    obs_pad = jnp.pad(obs, ((0, 0), (0, 0), (0, 4), (0, 0)))
-    got_pad = np.asarray(stack_frames_pallas_nhwc(obs_pad, T, K, True,
-                                                  out_height=H))
-    np.testing.assert_allclose(got_pad, want, rtol=2e-7)
-
-    want_bf16 = np.asarray(stack_frames_reference(obs, T, K,
-                                                  out_dtype=jnp.bfloat16))
-    got_bf16 = np.asarray(stack_frames_pallas_nhwc(obs, T, K, True,
-                                                   out_dtype=jnp.bfloat16))
-    np.testing.assert_array_equal(got_bf16, want_bf16)
+    assert got_pl.shape == (B, T, H, W, K)
 
 
 def test_stack_frames_bf16_output(rng):
@@ -215,18 +185,17 @@ def test_lane_order_is_a_permutation_of_the_window(batch, window):
     (dict(stack=3), "planar"),                       # bf16 planes pair up
     (dict(stack=3, dtype=jnp.float32), "lanes"),
     (dict(dtype=jnp.float16), "planar"),
-    (dict(nhwc=True), "nhwc"),
     (dict(use_pallas=False), "reference"),
 ])
 def test_decode_route_follows_the_shapes(kwargs, want):
     """One path chosen by what the input shows, no knob."""
     from r2d2_tpu.ops.pallas_kernels import decode_route
     k = dict(batch=128, window=55, stack=4, stored=(96, 128),
-             dtype=jnp.bfloat16, nhwc=False, use_pallas=True)
+             dtype=jnp.bfloat16, use_pallas=True)
     k.update(kwargs)
     route = decode_route(
         (k["batch"], k["window"] + k["stack"] - 1) + k["stored"],
-        k["window"], k["stack"], k["use_pallas"], k["dtype"], k["nhwc"])
+        k["window"], k["stack"], k["use_pallas"], k["dtype"])
     assert route == want
 
 
@@ -298,15 +267,15 @@ def test_decode_inputs_builds_one_pallas_call(batch, window):
     assert _count_eqns(jaxpr, "pallas_call") == 1
 
 
-@pytest.mark.parametrize("batch,use_double,fused", [
-    (8, False, "off"), (128, False, "off"), (8, True, "off"),
-    (8, True, "on")], ids=["b8", "b128", "b8-double", "b8-double-fused"])
+@pytest.mark.parametrize("batch,use_double", [
+    (8, False), (128, False), (8, True)], ids=["b8", "b128", "b8-double"])
 def test_loss_through_lanes_decode_matches_reference_path(
-        rng, monkeypatch, batch, use_double, fused):
+        rng, monkeypatch, batch, use_double):
     """``make_loss_fn`` with the lanes kernel forced on (interpret mode)
     against the same loss on the jnp path: loss, priorities and gradients
     equal to f32 round-off. Pins the frame order that goes into the torso
-    and comes back to the LSTM, on the plain and the fused-double unroll."""
+    and comes back to the LSTM; under double-Q the target unroll decodes
+    the window a second time."""
     import dataclasses
 
     import r2d2_tpu.ops.pallas_kernels as pk
@@ -336,12 +305,11 @@ def test_loss_through_lanes_decode_matches_reference_path(
     monkeypatch.setattr(pk, "stack_frames_lanes", interpreted)
     out = {}
     for decode in ("off", "on"):
-        opt = dataclasses.replace(OPT, pallas_obs_decode=decode,
-                                  fused_double_unroll=fused)
+        opt = dataclasses.replace(OPT, pallas_obs_decode=decode)
         loss_fn = make_loss_fn(net, spec, opt, use_double=use_double)
         out[decode] = jax.value_and_grad(loss_fn, has_aux=True)(
             ts.params, target, sample)
-    assert len(calls) == (2 if use_double and fused == "off" else 1)
+    assert len(calls) == (2 if use_double else 1)
     (loss_a, aux_a), grads_a = out["off"]
     (loss_b, aux_b), grads_b = out["on"]
     np.testing.assert_allclose(float(loss_b), float(loss_a), rtol=1e-5)
@@ -446,193 +414,3 @@ def test_stack_frames_pallas_compiled_on_tpu():
     assert proc.returncode == 0, (
         f"compiled pallas check failed (rc={proc.returncode}):\n{proc.stderr[-4000:]}")
     assert out and out[-1] == "OK"
-
-
-# ---------------------------------------------------------------------------
-# Fused LSTM time-scan (ops/pallas_lstm.py)
-
-
-def _lstm_inputs(rng, T=7, B=8, H=128, dtype=jnp.float32):
-    xpb = jnp.asarray(rng.standard_normal((T, B, 4 * H)), dtype)
-    wh = jnp.asarray(rng.standard_normal((H, 4 * H)) * 0.1, dtype)
-    c0 = jnp.asarray(rng.standard_normal((B, H)), dtype)
-    h0 = jnp.asarray(rng.standard_normal((B, H)), dtype)
-    return xpb, wh, c0, h0
-
-
-def test_lstm_scan_pallas_forward_matches_reference(rng):
-    """f32 interpret-mode forward is bit-exact vs the lax.scan twin (the
-    kernel's f32 carry + f32 gate math reproduce the scan exactly when
-    nothing is rounded)."""
-    from r2d2_tpu.ops.pallas_lstm import (lstm_scan_pallas,
-                                          lstm_scan_reference)
-    args = _lstm_inputs(rng)
-    hs_r, (cf_r, hf_r) = lstm_scan_reference(*args)
-    hs_p, (cf_p, hf_p) = lstm_scan_pallas(*args, interpret=True)
-    np.testing.assert_array_equal(np.asarray(hs_p), np.asarray(hs_r))
-    np.testing.assert_array_equal(np.asarray(cf_p), np.asarray(cf_r))
-    np.testing.assert_array_equal(np.asarray(hf_p), np.asarray(hf_r))
-
-
-def test_lstm_scan_pallas_grads_match_reference(rng):
-    """custom-VJP backward kernel vs jax.grad of the scan twin, for every
-    input — including the final-carry cotangents (the loss reads c_fin and
-    h_fin so dcfin/dhfin are non-zero)."""
-    from r2d2_tpu.ops.pallas_lstm import (lstm_scan_pallas,
-                                          lstm_scan_reference)
-    args = _lstm_inputs(rng)
-    T, B, H = args[0].shape[0], args[0].shape[1], args[1].shape[0]
-    w = jnp.asarray(rng.standard_normal((T, B, H)), jnp.float32)
-
-    def loss(fn, args):
-        hs, (c, h) = fn(*args)
-        return jnp.sum(hs * w) + jnp.sum(c * 1.3) + jnp.sum(h * 0.7)
-
-    g_ref = jax.grad(lambda a: loss(lstm_scan_reference, a))(args)
-    g_pal = jax.grad(lambda a: loss(
-        lambda *a: lstm_scan_pallas(*a, interpret=True), a))(args)
-    for name, a, b in zip(("dxpb", "dwh", "dc0", "dh0"), g_ref, g_pal):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-6, rtol=2e-6, err_msg=name)
-
-
-def test_lstm_scan_pallas_unused_carry_grads(rng):
-    """When the loss ignores the final carry JAX feeds zero cotangents for
-    it; the kernel must still produce the right dxpb/dwh."""
-    from r2d2_tpu.ops.pallas_lstm import (lstm_scan_pallas,
-                                          lstm_scan_reference)
-    args = _lstm_inputs(rng, T=4, B=8, H=128)
-
-    def loss(fn, args):
-        hs, _ = fn(*args)
-        return jnp.sum(hs ** 2)
-
-    g_ref = jax.grad(lambda a: loss(lstm_scan_reference, a))(args)
-    g_pal = jax.grad(lambda a: loss(
-        lambda *a: lstm_scan_pallas(*a, interpret=True), a))(args)
-    for name, a, b in zip(("dxpb", "dwh", "dc0", "dh0"), g_ref, g_pal):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-6, rtol=2e-6, err_msg=name)
-
-
-def test_hoisted_lstm_pallas_path_matches_scan(rng):
-    """HoistedLSTM(use_pallas=True) plumbing — bias folding, axis swaps,
-    carry order — against the default scan path, same params. The bias
-    fold changes one f32 addition order, hence allclose not array_equal."""
-    from r2d2_tpu.models.network import HoistedLSTM
-    B, T, D, H = 4, 6, 48, 128
-    xs = jnp.asarray(rng.standard_normal((B, T, D)), jnp.float32)
-    carry = (jnp.asarray(rng.standard_normal((B, H)), jnp.float32),
-             jnp.asarray(rng.standard_normal((B, H)), jnp.float32))
-    scan_cell = HoistedLSTM(features=H)
-    params = scan_cell.init(jax.random.PRNGKey(0), carry, xs)
-    # make the bias nonzero so the fold is actually exercised
-    params = jax.tree_util.tree_map(lambda x: x, params)
-    params["params"]["bias"] = jnp.asarray(
-        rng.standard_normal((4 * H,)) * 0.1, jnp.float32)
-    (c_s, h_s), out_s = scan_cell.apply(params, carry, xs)
-    pallas_cell = HoistedLSTM(features=H, use_pallas=True,
-                              pallas_interpret=True)
-    (c_p, h_p), out_p = pallas_cell.apply(params, carry, xs)
-    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_s),
-                               atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(c_p), np.asarray(c_s),
-                               atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(h_p), np.asarray(h_s),
-                               atol=1e-5, rtol=1e-5)
-
-
-def test_hoisted_lstm_pallas_single_step_falls_back(rng):
-    """T=1 (the actor's step shape) must stay on the scan path — the
-    pallas kernel is a sequence fusion, not a step dispatch."""
-    from r2d2_tpu.models.network import HoistedLSTM
-    B, D, H = 4, 48, 128
-    xs = jnp.asarray(rng.standard_normal((B, 1, D)), jnp.float32)
-    carry = (jnp.zeros((B, H)), jnp.zeros((B, H)))
-    cell = HoistedLSTM(features=H, use_pallas=True, pallas_interpret=False)
-    params = cell.init(jax.random.PRNGKey(0), carry, xs)
-    # pallas_interpret=False would fail to compile on CPU if the kernel
-    # were (wrongly) taken; succeeding proves the fallback
-    (_, _), out = cell.apply(params, carry, xs)
-    assert out.shape == (B, 1, H)
-
-
-def test_lstm_scan_pallas_bf16_tracks_reference(rng):
-    """bf16 interpret-mode pass of both kernels (the dtype the chip runs
-    under the shipped policy): forward within bf16 tolerance of the f32
-    reference, and the custom-VJP pipeline produces finite, same-scale
-    grads for every input. Catches dtype-specific kernel bugs (bad casts,
-    f32-only ops) before the on-chip A/B."""
-    from r2d2_tpu.ops.pallas_lstm import (lstm_scan_pallas,
-                                          lstm_scan_reference)
-    f32args = _lstm_inputs(rng, T=5, B=8, H=128)
-    args = tuple(a.astype(jnp.bfloat16) for a in f32args)
-    hs_r, (cf_r, hf_r) = lstm_scan_reference(*f32args)
-    hs_p, (cf_p, hf_p) = lstm_scan_pallas(*args, interpret=True)
-    assert hs_p.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(hs_p, np.float32),
-                               np.asarray(hs_r), atol=0.03, rtol=0.03)
-    np.testing.assert_allclose(np.asarray(cf_p, np.float32),
-                               np.asarray(cf_r), atol=0.05, rtol=0.05)
-
-    def loss(a):
-        hs, (c, h) = lstm_scan_pallas(*a, interpret=True)
-        return (jnp.sum(hs.astype(jnp.float32) ** 2)
-                + jnp.sum(c.astype(jnp.float32))
-                + jnp.sum(h.astype(jnp.float32)))
-
-    g_pal = jax.grad(loss)(args)
-
-    def loss_ref(a):
-        hs, (c, h) = lstm_scan_reference(*a)
-        return jnp.sum(hs ** 2) + jnp.sum(c) + jnp.sum(h)
-
-    g_ref = jax.grad(loss_ref)(f32args)
-    for name, a, b in zip(("dxpb", "dwh", "dc0", "dh0"), g_pal, g_ref):
-        a = np.asarray(a, np.float32)
-        b = np.asarray(b)
-        assert np.isfinite(a).all(), name
-        assert a.dtype == np.float32 and a.shape == b.shape
-        # same magnitude ballpark (bf16 rounding both in the kernel and in
-        # the bf16 reference chain rules out elementwise equality)
-        denom = max(np.abs(b).max(), 1e-3)
-        assert np.abs(a - b).max() / denom < 0.25, name
-
-
-@pytest.mark.slow
-def test_lstm_scan_pallas_block_t_matches_reference(rng):
-    """block_t > 1 (several timesteps per grid iteration) must be exactly
-    the same computation: bit-exact f32 forward across block boundaries,
-    grads to f32 epsilon — including the in-block h_prev recomputation
-    (o*tanh(c)) and the block-boundary carry handoff."""
-    from r2d2_tpu.ops.pallas_lstm import (lstm_scan_pallas,
-                                          lstm_scan_reference)
-    args = _lstm_inputs(rng, T=10, B=8, H=128)
-    hs_r, (cf_r, hf_r) = lstm_scan_reference(*args)
-    w = jnp.asarray(rng.standard_normal(hs_r.shape), jnp.float32)
-
-    def loss(fn, a):
-        hs, (c, h) = fn(*a)
-        return jnp.sum(hs * w) + jnp.sum(c * 1.3) + jnp.sum(h * 0.7)
-
-    g_ref = jax.grad(lambda a: loss(lstm_scan_reference, a))(args)
-    for bt in (2, 5, 10):
-        hs_p, (cf_p, hf_p) = lstm_scan_pallas(*args, interpret=True,
-                                              block_t=bt)
-        np.testing.assert_array_equal(np.asarray(hs_p), np.asarray(hs_r),
-                                      err_msg=f"block_t={bt}")
-        np.testing.assert_array_equal(np.asarray(cf_p), np.asarray(cf_r))
-        g_pal = jax.grad(lambda a: loss(
-            lambda *x: lstm_scan_pallas(*x, interpret=True, block_t=bt),
-            a))(args)
-        for name, a, b in zip(("dxpb", "dwh", "dc0", "dh0"), g_ref, g_pal):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=3e-6, rtol=3e-6,
-                                       err_msg=f"{name} block_t={bt}")
-
-
-def test_lstm_scan_pallas_block_t_must_divide(rng):
-    from r2d2_tpu.ops.pallas_lstm import lstm_scan_pallas
-    args = _lstm_inputs(rng, T=7, B=8, H=128)
-    with pytest.raises(ValueError, match="divide"):
-        lstm_scan_pallas(*args, interpret=True, block_t=3)

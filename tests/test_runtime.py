@@ -374,48 +374,21 @@ def test_thread_actor_envs_closed_on_stop(tmp_path, monkeypatch):
     assert len(closed) == n
 
 
-@pytest.mark.slow
-def test_pretrain_auto_migrates_space_to_depth(tmp_path):
-    """Round-3 advisor: warm-starting a space_to_depth network from a
-    standard-layout checkpoint must auto-migrate (exact rewrite) instead of
-    dying with the generic mismatch error; the reverse direction refuses
-    loudly."""
-    import jax.numpy as jnp
-    from r2d2_tpu.config import NetworkConfig
-    from r2d2_tpu.models import initial_hidden
-    from r2d2_tpu.models.network import NetworkApply
-
-    base_cfg = NetworkConfig(hidden_dim=16, cnn_out_dim=32)
-    net_off = NetworkApply(4, base_cfg, 4, 84, 84)
-    params_off = net_off.init(jax.random.PRNGKey(2))
-    path = save_checkpoint(str(tmp_path), "Fake", 1, 0, params_off,
-                           {"dummy": np.zeros(1)}, params_off, 0, 0)
-
-    s2d_cfg = NetworkConfig(hidden_dim=16, cnn_out_dim=32,
-                            space_to_depth="on")
-    net_on = NetworkApply(4, s2d_cfg, 4, 84, 84)
-    template_on = net_on.init(jax.random.PRNGKey(3))
-    migrated = load_pretrain(path, template_on)
-
-    rng = np.random.default_rng(0)
-    obs = jnp.asarray(rng.uniform(0, 1, (2, 3, 84, 84, 4)), jnp.float32)
-    la = jnp.zeros((2, 3, 4), jnp.float32)
-    q_off, _ = net_off.apply(params_off, obs, la, initial_hidden(2, 16))
-    q_on, _ = net_on.apply(migrated, obs, la, initial_hidden(2, 16))
-    np.testing.assert_allclose(np.asarray(q_on), np.asarray(q_off),
-                               rtol=1e-5, atol=1e-5)
-
-    # reverse direction (s2d checkpoint -> standard net): loud refusal
-    params_on = net_on.init(jax.random.PRNGKey(4))
-    path_on = save_checkpoint(str(tmp_path), "FakeS2d", 1, 0, params_on,
-                              {"dummy": np.zeros(1)}, params_on, 0, 0)
-    with pytest.raises(ValueError, match="space_to_depth=off"):
-        load_pretrain(path_on, net_off.init(jax.random.PRNGKey(5)))
-
-    # unrelated shape mismatch: named param in the error, no migration
-    wide = NetworkApply(4, NetworkConfig(hidden_dim=32, cnn_out_dim=32), 4, 84, 84)
-    with pytest.raises(ValueError, match="architecture mismatch"):
-        load_pretrain(path, wide.init(jax.random.PRNGKey(6)))
+def test_pretrain_names_a_first_conv_of_another_shape(tmp_path,
+                                                       small_params):
+    """A checkpoint whose first-conv kernel has another shape than the
+    network's (another stack, another kernel; what a checkpoint of the
+    removed space-to-depth layout looks like: kernel halved, channels x4)
+    is refused by name; nothing is rewritten on the way in."""
+    path = save_checkpoint(str(tmp_path), "Fake", 1, 0, small_params,
+                           {"dummy": np.zeros(1)}, small_params, 0, 0)
+    template = jax.tree_util.tree_map(np.asarray, small_params)
+    kh, kw, c, o = template["params"]["torso"]["Conv_0"]["kernel"].shape
+    template["params"]["torso"]["Conv_0"]["kernel"] = np.zeros(
+        (kh * 2, kw * 2, c // 2, o), np.float32)
+    with pytest.raises(ValueError, match=r"torso/Conv_0/kernel.*\(3, 3, 2, 4\)"
+                                         r".*architecture mismatch"):
+        load_pretrain(path, template)
 
 
 @pytest.mark.slow

@@ -41,39 +41,6 @@ def _filled_replay(spec, rng, n_blocks=3):
     return state
 
 
-@pytest.mark.slow
-def test_fused_double_unroll_matches_sequential(rng):
-    """optim.fused_double_unroll=on (one scan interleaving the online and
-    target chains) must reproduce the sequential two-unroll double-DQN
-    loss, gradients, and priorities exactly — only the loop structure
-    changes (VERDICT r3 #3 forcing mechanism)."""
-    import dataclasses
-
-    spec = make_spec(batch_size=6)
-    net, _ = _net(spec, use_double=True)
-    ts = create_train_state(jax.random.PRNGKey(2), net, OPT)
-    # distinct target params so the target chain is actually exercised
-    target = net.init(jax.random.PRNGKey(77))
-    rs = _filled_replay(spec, rng)
-    batch = replay_sample(spec, rs, jax.random.PRNGKey(5))
-
-    losses, grads_all, prios = [], [], []
-    for fused in ("off", "on"):
-        opt = dataclasses.replace(OPT, fused_double_unroll=fused)
-        loss_fn = make_loss_fn(net, spec, opt, use_double=True)
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            ts.params, target, batch)
-        losses.append(float(loss))
-        grads_all.append(grads)
-        prios.append(np.asarray(aux["priorities"]))
-
-    assert losses[0] == losses[1]
-    np.testing.assert_array_equal(prios[0], prios[1])
-    for a, b in zip(jax.tree_util.tree_leaves(grads_all[0]),
-                    jax.tree_util.tree_leaves(grads_all[1])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 def test_learner_step_runs_and_updates(rng):
     spec = make_spec(batch_size=8)
     net, params = _net(spec)
@@ -286,42 +253,6 @@ def test_bf16_and_double_compile(rng):
     step = make_learner_step(net16, spec, OPT, use_double=True)
     ts, rs, m = step(ts, rs)
     assert np.isfinite(float(m["loss"]))
-
-
-@pytest.mark.slow
-def test_pallas_lstm_loss_parity_with_scan(rng):
-    """network.pallas_lstm numeric-safety gate (same contract as the bf16
-    gate above): from identical params and data, the fused-kernel LSTM
-    path's losses must track the lax.scan trajectory within tolerance
-    across parameter updates. Runs the kernel in interpret mode on the
-    CPU mesh via the debug flag (network.pallas_lstm_interpret)."""
-    spec = make_spec(batch_size=8)
-
-    def build(plstm: str):
-        cfg = NetworkConfig(hidden_dim=spec.hidden_dim, cnn_out_dim=16,
-                            pallas_lstm=plstm, pallas_lstm_interpret=True,
-                            conv_layers=((8, 4, 2), (16, 3, 1)))
-        return init_network(jax.random.PRNGKey(0), A, cfg,
-                            frame_stack=spec.frame_stack,
-                            frame_height=spec.frame_height,
-                            frame_width=spec.frame_width)[0]
-
-    losses = {}
-    for plstm in ("off", "on"):
-        net = build(plstm)
-        ts = create_train_state(jax.random.PRNGKey(1), net, OPT)
-        rs = _filled_replay(spec, np.random.default_rng(0))
-        step = make_learner_step(net, spec, OPT, use_double=False)
-        run = []
-        for _ in range(10):
-            ts, rs, m = step(ts, rs)
-            run.append(float(m["loss"]))
-        losses[plstm] = run
-
-    # f32 config: only the bias-fold addition order and matmul accumulation
-    # differ — the first step must agree tightly, the trajectory closely
-    assert losses["on"][0] == pytest.approx(losses["off"][0], rel=1e-4)
-    np.testing.assert_allclose(losses["on"], losses["off"], rtol=1e-2)
 
 
 def test_exact_gather_train_step_loss_parity(rng):
