@@ -40,7 +40,10 @@ leaves out what the others would add. No pair is dropped and there is no
 capacity factor: all pairs are sorted by expert (pairs on absent experts
 last), and the held ones go through grouped matrix products a chunk of
 rows at a time, as many chunks as there are held pairs
-(``held_experts_ffn``).
+(``held_experts_ffn``). The way back follows the same pairs: each chunk adds
+its rows to their positions' float32 sums as it goes (``add_rows``), forward
+and backward, so no array has a row for every pair and a chip that holds an
+eighth of the experts moves an eighth of the rows.
 
 Precision: matrix products take ``dtype`` operands (bf16 on a TPU) and
 accumulate in float32; the residual stream, the norms, the router's scores,
@@ -84,6 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from r2d2_tpu.config import CoreConfig
+from r2d2_tpu.ops.pallas_kernels import add_rows
 
 # the source family's ``initializer_range``; the projections that write
 # into the residual stream (o_proj, every down_proj) are drawn narrower by
@@ -199,58 +203,6 @@ class SwiGLU(nn.Module):
         return _matmul("nf,fd->nd", a, down, self.dtype, _F32)
 
 
-@jax.custom_vjp
-def _permuted(x, index, back):
-    """``x[index]`` where ``index`` and ``back`` are a permutation and its
-    inverse (either may carry padding past the other's length, which reads
-    row 0 and gives no gradient): the gradient is the gather ``g[back]``,
-    not a scatter."""
-    return x[jnp.minimum(index, x.shape[0] - 1)]
-
-
-def _permuted_bwd(res, g):
-    (back,) = res
-    rows = g[jnp.minimum(back, g.shape[0] - 1)]
-    keep = (back < g.shape[0]).reshape((-1,) + (1,) * (g.ndim - 1))
-    return jnp.where(keep, rows, 0), None, None
-
-
-_permuted.defvjp(lambda x, index, back: (_permuted(x, index, back), (back,)),
-                 _permuted_bwd)
-
-
-def _sum_over_choices(sorted_rows, inverse, top_k: int):
-    """(N, d) float32: each position's ``top_k`` pairs' rows, summed. The
-    pairs are numbered choice-major (pair = choice * N + position), so the
-    rows brought back in pair order split into ``top_k`` slabs of N without
-    a copy."""
-    back = sorted_rows[inverse]
-    return back.reshape(top_k, -1, back.shape[-1]).astype(_F32).sum(axis=0)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(sorted_rows, order, inverse, top_k: int):
-    """``_sum_over_choices`` with a gather for a gradient: a sorted row's
-    is its position's (``order % N``), zero for ``order``'s padding; no
-    array of all pairs' gradients on the way."""
-    return _sum_over_choices(sorted_rows, inverse, top_k)
-
-
-def _combine_fwd(sorted_rows, order, inverse, top_k):
-    return (_sum_over_choices(sorted_rows, inverse, top_k),
-            (order, jnp.zeros((0,), sorted_rows.dtype)))
-
-
-def _combine_bwd(top_k, res, g):
-    order, like = res
-    rows = g[order % g.shape[0]].astype(like.dtype)
-    pairs = g.shape[0] * top_k
-    return jnp.where((order < pairs)[:, None], rows, 0), None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
-
-
 def route(scores, bias, core: CoreConfig):
     """(chosen (N, k) int32, weights (N, k) float32) from the router's
     sigmoid scores (N, routed): the top-k of scores + bias, weighted by
@@ -298,95 +250,112 @@ def _chunk_ffn(x, weight, gate_up, down, sizes, live):
     return jnp.where(live, out * weight[:, None], 0).astype(x.dtype)
 
 
-def _chunk_of(i, chunk: int, h, order, weight, group_sizes):
-    """Chunk ``i`` of the sorted pairs: (its positions' rows of ``h``, its
-    pairs' weights, the groups' sizes inside it, its live rows)."""
+def _chunk_of(i, chunk: int, h, order, pair_weight, group_sizes):
+    """Chunk ``i`` of the sorted pairs: (its pairs, the position each
+    row's sum goes to, ``len(h)`` where the row stands for no pair on a
+    held expert; its positions' rows of ``h``, its pairs' weights, the
+    groups' sizes inside it, its live rows)."""
     lo = i * chunk
     pairs = jax.lax.dynamic_slice_in_dim(order, lo, chunk)
     ends = jnp.cumsum(group_sizes)
     sizes = (jnp.clip(ends, lo, lo + chunk)
              - jnp.clip(ends - group_sizes, lo, lo + chunk))
-    live = (lo + jnp.arange(chunk) < ends[-1])[:, None]
+    live = lo + jnp.arange(chunk) < ends[-1]
+    n = h.shape[0]
+    at = pairs % n
     with jax.named_scope("moe_dispatch"):
-        x = h[pairs % h.shape[0]]
-    return (x, jax.lax.dynamic_slice_in_dim(weight, lo, chunk), sizes, live)
+        x = h[at]
+        w = pair_weight[jnp.minimum(pairs, pair_weight.shape[0] - 1)]
+    return pairs, jnp.where(live, at, n), x, w, sizes, live[:, None]
 
 
 def _live_chunks(group_sizes, chunk: int):
     return (jnp.sum(group_sizes) + chunk - 1) // chunk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def held_experts_ffn(h, order, inverse, weight, gate_up, down, group_sizes,
-                     top_k: int, chunk: int):
-    """The held experts' weighted SwiGLU for all (position, expert) pairs.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def held_experts_ffn(h, order, pair_weight, gate_up, down, group_sizes,
+                     chunk: int):
+    """The held experts' weighted SwiGLU, summed to the positions.
 
     ``h`` (N, d) the positions; ``order`` (M,) the pairs sorted by expert,
-    the pairs on absent experts last, padded to whole chunks (pair =
-    choice * N + position); ``inverse`` (N * top_k,) each pair's sorted
-    row; ``weight`` (M,) the sorted pairs' routing weights; gate_up
+    the pairs on absent experts last, padded to whole chunks with numbers
+    past the pairs' (pair = choice * N + position); ``pair_weight``
+    (N * top_k,) the pairs' routing weights in pair order; gate_up
     (G, d, 2f), down (G, f, d); ``group_sizes`` (G,) the pairs on each held
-    expert. Returns the sorted rows (M, d), zero past the groups, and the
-    number of rows the chunks it walked took in as pairs.
+    expert. Returns each position's sum over its pairs on held experts
+    (N, d) float32, and the number of rows the chunks it walked took in as
+    pairs.
 
     The sorted pairs are taken ``chunk`` at a time, and only as many chunks
     as hold a pair on a held expert (a loop with a dynamic trip count): a
-    chunk gathers its positions' rows and runs the two grouped products. So
-    the work follows the pairs that are here, whatever the router's skew,
-    with static shapes and no pair dropped. The backward is its own: it
-    walks the same chunks, recomputes a chunk's activation, adds the
-    weights' gradients into one float32 accumulator in place, and brings
-    the rows' gradients back to the positions with a gather."""
+    chunk gathers its positions' rows and its pairs' weights, runs the two
+    grouped products and adds its rows to their positions' sums
+    (``add_rows``). So the work follows the pairs that are here, there and
+    back, whatever the router's skew, with static shapes and no pair
+    dropped: no array has a row for every pair. The backward is its own: it
+    walks the same chunks, gathers a chunk's positions' rows of the sum's
+    gradient, recomputes the chunk's activation, adds the weights'
+    gradients into one float32 accumulator in place and the rows'
+    gradients to their positions as the forward does."""
     # the weights come as the parameters are kept (float32) and are cast
     # here, once a call, so that their gradient goes back uncast
     gate_up, down = gate_up.astype(h.dtype), down.astype(h.dtype)
+    # the second product takes float32 operands: cast once here; left to
+    # the chunk's own cast, XLA makes it again for every chunk (0.17 ms
+    # each at the cell's sizes; PERF.md, Findings, PR 30)
+    down = down.astype(_F32)
 
     def one(i, carry):
-        out, covered = carry
-        x, w, sizes, live = _chunk_of(i, chunk, h, order, weight, group_sizes)
-        return (jax.lax.dynamic_update_slice_in_dim(
-            out, _chunk_ffn(x, w, gate_up, down, sizes, live), i * chunk, 0),
-                covered + jnp.sum(live, dtype=jnp.int32))
+        total, covered = carry
+        _, pos, x, w, sizes, live = _chunk_of(i, chunk, h, order,
+                                              pair_weight, group_sizes)
+        rows = _chunk_ffn(x, w, gate_up, down, sizes, live)
+        with jax.named_scope("moe_combine"):
+            total = add_rows(total, rows, pos)
+        return total, covered + jnp.sum(live, dtype=jnp.int32)
 
     return jax.lax.fori_loop(
         0, _live_chunks(group_sizes, chunk), one,
-        (jnp.zeros((order.shape[0], h.shape[1]), h.dtype),
-         jnp.zeros((), jnp.int32)))
+        (jnp.zeros(h.shape, _F32), jnp.zeros((), jnp.int32)))
 
 
-def _held_experts_fwd(h, order, inverse, weight, gate_up, down, group_sizes,
-                      top_k, chunk):
-    return (held_experts_ffn(h, order, inverse, weight, gate_up, down,
-                             group_sizes, top_k, chunk),
-            (h, order, inverse, weight, gate_up, down, group_sizes))
+def _held_experts_fwd(h, order, pair_weight, gate_up, down, group_sizes,
+                      chunk):
+    return (held_experts_ffn(h, order, pair_weight, gate_up, down,
+                             group_sizes, chunk),
+            (h, order, pair_weight, gate_up, down, group_sizes))
 
 
-def _held_experts_bwd(top_k, chunk, res, g):
+def _held_experts_bwd(chunk, res, g):
     g, _ = g                                # the count carries no gradient
-    h, order, inverse, weight, gate_up, down, group_sizes = res
+    h, order, pair_weight, gate_up, down, group_sizes = res
     kept = gate_up.dtype, down.dtype
     gate_up, down = gate_up.astype(h.dtype), down.astype(h.dtype)
 
     def one(i, carry):
-        dx, dw, dw1, dw2 = carry
-        x, w, sizes, live = _chunk_of(i, chunk, h, order, weight, group_sizes)
+        dh, dw, dw1, dw2 = carry
+        pairs, pos, x, w, sizes, live = _chunk_of(i, chunk, h, order,
+                                                  pair_weight, group_sizes)
+        with jax.named_scope("moe_combine"):
+            # a row's gradient is its position's
+            gi = g[pairs % g.shape[0]].astype(h.dtype)
         _, vjp = jax.vjp(
             lambda x, w, w1, w2: _chunk_ffn(x, w, w1, w2, sizes, live),
             x, w, gate_up, down)
-        dxi, dwi, dw1i, dw2i = vjp(
-            jax.lax.dynamic_slice_in_dim(g, i * chunk, chunk))
-        return (jax.lax.dynamic_update_slice_in_dim(dx, dxi, i * chunk, 0),
-                jax.lax.dynamic_update_slice_in_dim(dw, dwi, i * chunk, 0),
-                dw1 + dw1i.astype(_F32), dw2 + dw2i.astype(_F32))
+        dxi, dwi, dw1i, dw2i = vjp(gi)
+        with jax.named_scope("moe_dispatch"):
+            dh = add_rows(dh, dxi, pos)
+            # a permutation's numbers and, past them, the padding's
+            dw = dw.at[pairs].set(dwi, mode="drop", unique_indices=True)
+        return dh, dw, dw1 + dw1i.astype(_F32), dw2 + dw2i.astype(_F32)
 
-    dx, dw, dw1, dw2 = jax.lax.fori_loop(
+    dh, dw, dw1, dw2 = jax.lax.fori_loop(
         0, _live_chunks(group_sizes, chunk), one,
-        (jnp.zeros_like(g), jnp.zeros_like(weight),
+        (jnp.zeros(h.shape, _F32), jnp.zeros_like(pair_weight),
          jnp.zeros(gate_up.shape, _F32), jnp.zeros(down.shape, _F32)))
-    with jax.named_scope("moe_dispatch"):
-        dh = _sum_over_choices(dx, inverse, top_k).astype(h.dtype)
-    return (dh, None, None, dw, dw1.astype(kept[0]), dw2.astype(kept[1]),
-            None)
+    return (dh.astype(h.dtype), None, dw, dw1.astype(kept[0]),
+            dw2.astype(kept[1]), None)
 
 
 held_experts_ffn.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -395,8 +364,11 @@ held_experts_ffn.defvjp(_held_experts_fwd, _held_experts_bwd)
 class HeldExperts(nn.Module):
     """The routed experts this chip holds, for the pairs that fall on them:
     all N*k (position, expert) pairs are sorted by expert, the pairs on
-    absent experts last; the held ones go through ``held_experts_ffn`` and
-    each position's come back summed."""
+    absent experts last; the held ones go through ``held_experts_ffn``,
+    which gives each position's sum. Beside it the layer's counters:
+    ``dropped``, the pairs the router put on held experts less the rows the
+    chunks took in (none: there is no capacity to run out of), and
+    ``rows_walked``, the sorted rows of the chunks that were walked."""
     core: CoreConfig
     dtype: Any
 
@@ -417,23 +389,19 @@ class HeldExperts(nn.Module):
             on_held = (local >= 0) & (local < held)
             key = jnp.where(on_held, local, held)
             order = jnp.argsort(key, stable=True)
-            inverse = jnp.argsort(order)
             # padding up to whole chunks: rows that stand for no pair
-            order = jnp.concatenate([order, jnp.full(
-                (padded - n * k,), n * k, order.dtype)])
+            order = jnp.concatenate([order, jnp.arange(
+                n * k, padded, dtype=order.dtype)])
             group_sizes = jnp.sum(
                 key[:, None] == jnp.arange(held)[None, :], axis=0,
                 dtype=jnp.int32)
             pair_weight = jnp.where(on_held, weights.T.reshape(-1), 0.0)
-            weight = _permuted(pair_weight, order, inverse)
-        out, covered = held_experts_ffn(h.astype(dt), order, inverse, weight,
-                                        gate_up, down, group_sizes, k, chunk)
-        with jax.named_scope("moe_combine"):
-            routed = _combine(out, order, inverse, k)
-        # pairs the router put on held experts less the rows the chunks
-        # took in: none, there is no capacity to run out of
-        dropped = jnp.sum(on_held, dtype=jnp.int32) - covered
-        return routed, dropped
+        routed, covered = held_experts_ffn(h.astype(dt), order, pair_weight,
+                                           gate_up, down, group_sizes, chunk)
+        return routed, {
+            "dropped": jnp.sum(on_held, dtype=jnp.int32) - covered,
+            "rows_walked": (_live_chunks(group_sizes, chunk)
+                            * chunk).astype(jnp.int32)}
 
 
 class MoE(nn.Module):
@@ -461,7 +429,7 @@ class MoE(nn.Module):
                 "nd,de->ne", flat - mean, w_r,
                 precision=jax.lax.Precision.HIGHEST))
             chosen, weights = route(scores, bias, c)
-        routed, dropped = HeldExperts(c, self.dtype, name="experts")(
+        routed, walk = HeldExperts(c, self.dtype, name="experts")(
             flat, chosen, weights)
         with jax.named_scope("moe_shared"):
             shared = SwiGLU(c.moe_intermediate_size * c.n_shared_experts,
@@ -475,8 +443,8 @@ class MoE(nn.Module):
                     chosen, c.n_routed_experts, dtype=jnp.int32), axis=(0, 1)),
                 "entropy": -jnp.mean(jnp.sum(share * jnp.log(share + 1e-30),
                                              axis=-1)),
-                "dropped": dropped,
                 "input_mean": mean,
+                **walk,
             }
         return out, stats
 
@@ -584,8 +552,8 @@ class MlaMoeCore:
 def moe_counters(mutated: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
     """The stack's sown counters out of ``apply(..., mutable=['moe'])``'s
     second result: {chosen (L_moe, routed), entropy (L_moe,), dropped
-    (L_moe,), input_mean (L_moe, hidden)}, or {} for a stack without expert
-    layers."""
+    (L_moe,), rows_walked (L_moe,), input_mean (L_moe, hidden)}, or {} for
+    a stack without expert layers."""
     found = jax.tree_util.tree_leaves(
         mutated.get("moe", {}), is_leaf=lambda x: isinstance(x, tuple))
     return found[0][0] if found else {}

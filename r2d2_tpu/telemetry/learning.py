@@ -261,8 +261,12 @@ class MoeAggregator:
     histogram of chosen experts over all routed experts, the
     (position, expert) pairs that fell on the experts held here, the
     largest and the mean load among those, the router's entropy (nats, of
-    the scores normalised over the experts, mean over positions and steps)
-    and the pairs dropped (always 0: the core has no capacity limit)."""
+    the scores normalised over the experts, mean over positions and steps),
+    the pairs dropped (always 0: the core has no capacity limit) and
+    ``rows_walked``, the sorted rows of the chunks the held experts walked
+    (live chunks x chunk, summed over the steps): ``pairs_held`` over it is
+    how full the walk was, and it over steps x all pairs the share of the
+    pairs' rows that the experts' way there and back touched."""
 
     def __init__(self, core):
         self.held = slice(core.expert_offset,
@@ -293,8 +297,9 @@ class MoeAggregator:
         sums, steps = jax.device_get(self._sums), self._steps
         self._sums, self._steps = None, 0
         layers = []
-        for chosen, entropy, dropped in zip(
-                sums["chosen"], sums["entropy"], sums["dropped"]):
+        for chosen, entropy, dropped, walked in zip(
+                sums["chosen"], sums["entropy"], sums["dropped"],
+                sums["rows_walked"]):
             held = chosen[self.held]
             layers.append({
                 "chosen_hist": [int(c) for c in chosen],
@@ -303,6 +308,7 @@ class MoeAggregator:
                 "held_load_mean": float(held.mean()),
                 "router_entropy": float(entropy) / steps,
                 "dropped": int(dropped),
+                "rows_walked": int(walked),
             })
         return {"steps": steps, "layers": layers}
 

@@ -146,6 +146,32 @@ def run_chip_checks(only: str = "") -> int:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     add("exact_gather", exact_gather)
 
+    # --- rows summed to their positions (the mla_moe core's held experts) -
+    # at the moonlight-core cell's shapes (8,000 positions, chunks of 2,560
+    # bf16 rows), at acting's (T = 1: 64 lanes, one chunk of 64 x 6 pairs)
+    # and at sizes that fill no tile
+    def add_rows(positions, rows, dtype):
+        def check():
+            rng = fresh_rng()
+            from r2d2_tpu.ops.pallas_kernels import (add_rows_pallas,
+                                                     add_rows_reference)
+            # a third of the rows stand for no pair
+            pos = jnp.asarray(np.where(
+                rng.random(rows) < 0.33, positions,
+                rng.integers(0, positions, rows)), jnp.int32)
+            x = jnp.asarray(rng.standard_normal((rows, 2048)), dtype)
+            acc = jnp.asarray(rng.standard_normal((positions, 2048)),
+                              jnp.float32)
+            want = add_rows_reference(acc, x, pos)
+            got = add_rows_pallas(acc, x, pos)
+            # float32 sums of at most a few rows, in another order
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-6, atol=1e-6)
+        return check
+    add("add_rows_n8000_r2560", add_rows(8000, 2560, jnp.bfloat16))
+    add("add_rows_n64_r384", add_rows(64, 384, jnp.bfloat16))
+    add("add_rows_n13_r78_f32", add_rows(13, 78, jnp.float32))
+
     # --- quantized acting forward (ISSUE 14): compile + parity ----------
     def quant_forward():
         rng = fresh_rng()
