@@ -2,9 +2,15 @@
 train step and the bytes each kernel's call needs, all as functions of shapes.
 
 Copied from ``r2d2_tpu/telemetry/costmodel.py`` (``PEAK_SPECS``,
-``model_flops_per_step``), whose counts are reconciled with XLA's
-``cost_analysis`` in ``tests/test_costmodel.py``. The copy is deliberate: a
-later PR may change the program's table, not the one it is measured with.
+``model_flops_per_step``, here ``step_flops``), whose counts are reconciled
+with XLA's ``cost_analysis`` in ``tests/test_costmodel.py``. The copy is
+deliberate: a later PR may change the program's table, not the one it is
+measured with.
+
+A configuration names the module that counts its model work under ``costs``
+(``harness.costs_of``); this one counts the LSTM network, ``costs_mla_moe.py``
+the network with the ``mla_moe`` core. Each gives ``step_flops(cfg,
+action_dim)``, which ``mfu_bf16`` reads.
 """
 
 from typing import Dict, Sequence, Tuple
@@ -45,7 +51,7 @@ def _macs_per_frame(conv_layers: Sequence[Tuple[int, int, int]], height: int,
     return float(sum(conv) + fc + lstm + head), float(conv[0] if conv else 0)
 
 
-def model_flops_per_step(cfg, action_dim: int) -> float:
+def step_flops(cfg, action_dim: int) -> float:
     """Model FLOPs of one train step on one chip's batch: forward and
     backward (2x forward) of the online net, plus the target net's forward
     under double-Q, over batch x window frames at 2 FLOPs a MAC. The first

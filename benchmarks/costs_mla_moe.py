@@ -8,17 +8,17 @@ multiply-add. Not counted: norms, the rotation, the softmax, the sort and
 gather of the routed pairs, Adam, and anything computed twice (the layers are
 rematerialised in the backward pass; that is not model work).
 
-The held experts are counted at their expected share: a position's
-``num_experts_per_tok`` choices fall on one of the ``experts_held`` of
-``n_routed_experts`` with probability held / routed each, so a position
-brings ``top_k * held / routed`` pairs (0.75 at 6 x 8 / 64). The core's
-router, which reads its input less its mean over positions, gives that count
-within a few per cent on a seeded batch of 8,000 positions (PERF.md,
-Findings, PR 27 has the chip's counts; the record's ``moe`` block has the
-count itself, which no reader is handed: ``runners/learner.py`` would have
-to put it into ``values``). The held experts are a ninth of the step's
-count, so a few per cent of them are a few tenths of a per cent of
-``step_mfu_bf16``.
+In ``step_flops`` the held experts are counted at their expected share: a
+position's ``num_experts_per_tok`` choices fall on one of the
+``experts_held`` of ``n_routed_experts`` with probability held / routed each,
+so a position brings ``top_k * held / routed`` pairs (0.75 at 6 x 8 / 64).
+The core's router, which reads its input less its mean over positions, gives
+that count within a few per cent on a seeded batch of 8,000 positions
+(PERF.md, Findings, PR 27 has the chip's counts). The held experts are a
+ninth of the step's count, so a few per cent of them are a few tenths of a
+per cent of ``mfu_bf16``. ``experts_flops`` counts them from the pairs that
+did fall on them (the program's ``moe`` counter ``pairs_held``, which
+``runners/learner.py`` hands the readers), for ``k_experts_roofline``.
 """
 
 from typing import Dict
@@ -87,3 +87,18 @@ def step_flops(cfg, action_dim: int) -> float:
         cfg.network.core, cfg.network.cnn_out_dim + action_dim,
         cfg.sequence.seq_len).values())
     return 2.0 * positions * ((outer + core) * passes(cfg) - first_conv)
+
+
+def experts_flops(cfg, pairs_held: float) -> float:
+    """Model FLOPs the held experts' grouped products owe for ``pairs_held``
+    (position, expert) pairs that the online net's routers put on them,
+    summed over the expert layers: a pair is one row through the three
+    products of its expert's SwiGLU (3 x hidden x width multiply-adds a
+    forward pass), in every pass a step makes (``passes``; the target net's
+    routers are taken to put as many pairs there as the online net's). The
+    rows a chunked walk pads its groups with are no model work, and neither
+    is the forward pass recomputed inside the backward: a walk that pads
+    more, or recomputes more, takes longer for the same count."""
+    core = cfg.network.core
+    return (2.0 * 3 * core.hidden_size * core.moe_intermediate_size
+            * passes(cfg) * pairs_held)

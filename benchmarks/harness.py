@@ -126,11 +126,25 @@ def build_config(overrides: Dict[str, Any], save_dir: str, seed: int):
 
 def load_named(kind: str, name: str):
     """Module ``benchmarks/<kind>/<name>.py`` (a runner, a plain reference, a
-    per-layer metric's reader), found by the name a data file gives it."""
+    per-layer metric's reader; with ``kind`` "" a module of ``benchmarks/``
+    itself), found by the name a data file gives it."""
     path = os.path.join(BENCH_DIR, kind, f"{name}.py")
     if not os.path.exists(path):
-        raise BenchError(f"{kind} has no {name!r}: no file {path}")
-    return importlib.import_module(f"benchmarks.{kind}.{name}")
+        raise BenchError(f"{kind or 'benchmarks'} has no {name!r}: no file "
+                         f"{path}")
+    return importlib.import_module(
+        ".".join(part for part in ("benchmarks", kind, name) if part))
+
+
+def costs_of(config: dict):
+    """The count of a configuration's model work: the module
+    ``benchmarks/<name>.py`` that the configuration names under ``costs``, as
+    it names its ``reference`` and its ``scopes``. Every such module gives
+    ``step_flops(cfg, action_dim)``, the model FLOPs of one train step; one
+    may give counts of single layers beside it (``experts_flops``). None for
+    a configuration that names no count: its step has no share of a peak."""
+    name = config.get("costs")
+    return None if name is None else load_named("", name)
 
 
 def reader_of(metric_name: str):

@@ -126,7 +126,8 @@ def compare(program: Dict[str, Any], reference: Dict[str, Any],
               "loss_reduction": 1e-5, "priority_reduction": 1e-5}
     return {
         "ok": bool(finite and all(errors[k] <= limits[k] for k in errors)),
-        "errors": errors, "tolerance": tolerance, "finite": bool(finite),
+        "errors": errors, "limits": limits, "tolerance": tolerance,
+        "finite": bool(finite),
         "q_scale": q_scale, "td_scale": float(np.max(td_ref)),
         "sequences": int(valid.shape[0]),
         "stable_steps": int(stable.sum()), "valid_steps": int(valid.sum()),

@@ -61,6 +61,7 @@ class MetricContext:
     facts: Dict[str, Any]          # counts the runner resolved
     trace: Optional[Any]           # trace.reduce.TraceSummary, or None
     device_kind: str
+    config: Optional[Dict[str, Any]] = None   # the configuration's file
 
 
 def main(argv=None) -> int:
@@ -154,7 +155,7 @@ def main(argv=None) -> int:
                   f"{json.dumps(summary.self_by_scope())}", flush=True)
         ctx = MetricContext(cfg=cfg, values=result["values"],
                             facts=result["facts"], trace=summary,
-                            device_kind=device["kind"])
+                            device_kind=device["kind"], config=config)
         for m in declared:
             value = harness.reader_of(m["name"]).read(ctx)
             if value is not None:
@@ -170,6 +171,14 @@ def main(argv=None) -> int:
             line["metrics"][m["name"]] = {
                 "value": float(result["values"][m["name"]]),
                 "unit": m["unit"]}
+    # each number the reference check compared, beside its limit: the last
+    # key of the line and the last lines on standard error
+    reference = result["reference"]
+    line["compared"] = {name: [error, reference["limits"][name]]
+                        for name, error in reference["errors"].items()}
+    for name, (error, limit) in line["compared"].items():
+        print(f"compared {name}: {error:.6g} (limit {limit:g})",
+              file=sys.stderr)
     print(json.dumps(line))
     return 0
 

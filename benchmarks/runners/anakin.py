@@ -9,17 +9,19 @@ from ``log_fn``, which the loop calls on its own thread every
 ``runtime.log_interval`` seconds, right after ``Learner.flush_metrics`` has
 fetched the pending losses: every record therefore closes on finished device
 work, and the counters in it are exact. Warm-up lasts until the ring has
-wrapped once; then the window opens; when it has run its length (in a traced
-run ``traced_window_seconds``, enough for ``dispatch_host_ms``, and then the
-traced intervals) ``log_fn`` raises ``_Stop``, and the loop's own ``finally``
-closes it. A stop hook in the loop would be cleaner (PERF.md, Open questions).
+wrapped once; then the window opens; when it has run its length and holds
+``MIN_INTERVALS`` intervals (in a traced run ``traced_window_seconds``, enough
+for ``dispatch_host_ms``, and then the traced intervals) ``log_fn`` raises
+``_Stop``, and the loop's own ``finally`` closes it. A stop hook in the loop would be cleaner (PERF.md, Open questions).
 
 The loop dates a record before it flushes and calls ``log_fn``, so whatever
-``log_fn`` spends (the reference check, starting the profiler) counts towards
-the next interval: the record after a slow ``log_fn`` comes one iteration
-later. The window's first interval and a traced interval are therefore one
-iteration long; the rate is the median interval's, which the short one does
-not move.
+the flush (which waits for the iterations the host is ahead) and ``log_fn``
+spend (the reference check, starting the profiler) counts towards the next
+interval: the record after a slow one comes one iteration later. The window's
+first interval and a traced interval are therefore one iteration long, and on
+the chip so is every second one (0.207 s, then 4.07 s of twenty iterations;
+PERF.md, Open questions). The rate is the median interval's: of six, the mean
+of the slowest long one and the fastest short one.
 
 The loop builds its ``Learner`` itself; the runner needs it for the reference
 check and the device-side counters, and takes it by standing a recording
@@ -36,6 +38,15 @@ import numpy as np
 
 from benchmarks import harness
 from benchmarks.reference import check
+
+
+# fewest log intervals a timed window holds (a whole one has six: the loop's
+# records come in turn one iteration and some twenty apart, ``interval_s`` in
+# ``facts``; the flush's wait for what the host has dispatched ahead counts
+# towards the next record, which seems to be why). A window that a
+# stall of the host fell into closes on five: the median is then a sound
+# interval, of the one-iteration kind, which reads 1% under the long kind
+MIN_INTERVALS = 5
 
 
 class _Stop(Exception):
@@ -102,7 +113,13 @@ def run(ctx) -> Dict[str, Any]:
             state["phase"] = "window"
         elif state["phase"] == "window":
             state["window"].append(row)
-            if now - state["window"][0][0] < window_s:
+            # the window closes on time and on work: a host that stood still
+            # for some of the seconds delays the close (the median interval
+            # is then one it did not fall into) and does not decide
+            # ``correct``, which is for answers
+            if (now - state["window"][0][0] < window_s
+                    or (ctx.trace is None
+                        and len(state["window"]) - 1 < MIN_INTERVALS)):
                 return
             state["builds1"] = ctx.compiles.builds
             if ctx.trace is None:
@@ -149,8 +166,6 @@ def run(ctx) -> Dict[str, Any]:
                           and int(learner.train_state.step)
                           == learner.training_steps),
         "ring_full": learner.ring.buffer_steps == cfg.replay.capacity * dp,
-        "intervals": (len(rates) >= 4 or ctx.rehearse
-                      or ctx.trace is not None),
     }
     # host time inside the program's calls, per iteration, from its own
     # stage spans (what the loop's thread did between device dispatches)
@@ -179,5 +194,7 @@ def run(ctx) -> Dict[str, Any]:
                   "lanes": cfg.actor.anakin_lanes,
                   "scan_steps": cfg.replay.block_length,
                   "intervals": len(rates),
+                  "interval_s": [round(b[0] - a[0], 4) for a, b in pairs],
+                  "interval_rates": [round(r, 1) for r in rates],
                   "act_bytes": 2 if learner.net.config.bf16 else 4},
     }

@@ -15,18 +15,29 @@ median would jump between the two. ``Learner.flush_metrics`` (the losses and
 the diagnostics to the host, tens of ms during which the device waits) runs
 as the trainer's loop runs it, every ``runtime.log_interval`` seconds. The
 rate reported is the median sub-window's, so neither a flush nor a stall
-moves it. A traced run times one sub-window (for ``dispatch_host_ms``) and
-then traces.
+moves it; the window lasts its seconds and at least ``MIN_SUBWINDOWS``
+sub-windows, so a host that stood still for some of them delays the close and
+does not decide ``correct``, which is for answers. A traced run times one
+sub-window (for ``dispatch_host_ms``) and then traces; where the program counts what its experts were routed (the
+record's ``moe`` block, a core with experts), the counts of the traced
+dispatches alone go into ``facts`` (``moe_traced``), read from the learner's
+metrics after the flush that follows the capture: nothing is added to a
+window, and a program without the counter carries none.
 """
 
 import statistics
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from benchmarks import harness, traffic
 from benchmarks.reference import check
+
+
+# fewest sub-windows a timed window holds: with three, the median is a
+# sub-window that no single stall of the host fell into
+MIN_SUBWINDOWS = 3
 
 
 def _replicas_equal(params) -> bool:
@@ -47,10 +58,15 @@ class _Loop:
         self.dispatched = 0
         self.last_flush = time.perf_counter()
 
-    def flush(self) -> None:
+    def flush(self) -> Optional[Dict[str, Any]]:
+        """The program's flush; returns the ``moe`` block it made of the
+        dispatches since the one before (what a training run's record would
+        carry), None where the program counts no routing or had nothing new
+        to count."""
         with self.spans.span("metrics_flush"):
             self.learner.flush_metrics()
         self.last_flush = time.perf_counter()
+        return getattr(self.learner.metrics, "_moe", None)
 
     def dispatches(self, n: int) -> List[Any]:
         """``n`` dispatches, blocked on; then the flush if its interval has
@@ -67,6 +83,18 @@ class _Loop:
                 >= self.learner.cfg.runtime.log_interval):
             self.flush()
         return jax.device_get(losses)
+
+
+def _moe_totals(block: Dict[str, Any]) -> Dict[str, int]:
+    """Train steps, and over them and the expert layers the pairs that fell
+    on held experts and the sorted rows the experts' walk took in, of one of
+    the program's ``moe`` blocks (``telemetry/learning.py MoeAggregator``;
+    ``rows_walked`` is 0 in a checkout from before that counter)."""
+    layers = block["layers"]
+    return {"steps": block["steps"],
+            "pairs_held": sum(layer["pairs_held"] for layer in layers),
+            "rows_walked": sum(layer.get("rows_walked", 0)
+                               for layer in layers)}
 
 
 def load_program() -> None:
@@ -135,7 +163,10 @@ def run(ctx) -> Dict[str, Any]:
             rates.append(n_sub * k / (te - ts))
             attempted += n_sub
             bad += sum(not np.isfinite(np.asarray(x)).all() for x in losses)
-            if te >= deadline:
+            # a window closes on time and on work: a stall of the host that
+            # eats the seconds still leaves the median sub-windows to stand on
+            if te >= deadline and (len(rates) >= MIN_SUBWINDOWS
+                                   or ctx.trace is not None):
                 break
         window_end = time.perf_counter()
         builds_in_window = ctx.compiles.builds - builds0
@@ -148,7 +179,11 @@ def run(ctx) -> Dict[str, Any]:
             attempted += len(losses)
             bad += sum(not np.isfinite(np.asarray(x)).all() for x in losses)
 
-        loop.flush()
+        moe = loop.flush()
+        # in a traced run the dispatches since the flush before are the
+        # traced ones: their routing counters go to the readers
+        counted = ({"moe_traced": _moe_totals(moe)}
+                   if ctx.trace is not None and moe else {})
         steps = learner.training_steps
         dispatched = loop.dispatched * k
         checks = {
@@ -159,8 +194,6 @@ def run(ctx) -> Dict[str, Any]:
                               and int(learner.train_state.step) == dispatched),
             "ring_full": (learner.ring.buffer_steps
                           == cfg.replay.capacity * dp),
-            "subwindows": (len(rates) >= 3 or ctx.rehearse
-                           or ctx.trace is not None),
         }
         if learner.mesh is not None:
             checks["replicas_bit_equal"] = _replicas_equal(
@@ -181,7 +214,7 @@ def run(ctx) -> Dict[str, Any]:
                       "dp": dp, "subwindows": len(rates),
                       "dispatches_per_subwindow": n_sub,
                       "resolved": resolved,
-                      "act_bytes": 2 if net.config.bf16 else 4},
+                      "act_bytes": 2 if net.config.bf16 else 4, **counted},
         }
     finally:
         learner.stop_background()

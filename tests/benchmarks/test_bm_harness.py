@@ -5,115 +5,45 @@ without an edit to any file that is there."""
 
 import json
 import os
-import re
 import shutil
 import subprocess
 import sys
 
 import pytest
 
+import bm_structure
 from benchmarks import harness, run
 
 BENCH = harness.load_benchmark()
 CELLS = [c["name"] for c in BENCH["workloads"]]
-NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
-PLAIN_PATH = re.compile(r"^[A-Za-z0-9_./-]+$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
 def test_benchmark_json_meets_the_contract():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+    bm_structure.contract(BENCH)
     assert os.path.getsize(os.path.join(harness.ROOT, "BENCHMARK.json")) \
         <= 64 * 1024
-    assert 1 <= len(BENCH["paths"]) <= 16
-    for path in BENCH["paths"]:
-        assert PLAIN_PATH.match(path) and len(path) <= 200
-        assert not path.startswith("/") and ".." not in path.split("/")
-        assert os.path.isdir(os.path.join(harness.ROOT, path))
-    assert len(BENCH["command"]) <= 32
-    for arg in BENCH["command"]:
-        if os.path.exists(os.path.join(harness.ROOT, arg)):
-            assert any(arg.startswith(p + "/") for p in BENCH["paths"])
-    assert isinstance(BENCH["run_seconds"], int)
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert 1 <= len(BENCH["configs"]) <= 24
-    assert 2 <= len(BENCH["workloads"]) <= 24
-    assert 1 <= len(BENCH["end_to_end"]) <= 16
-    assert 1 <= len(BENCH["per_layer"]) <= 128
-    names = ([c["name"] for c in BENCH["configs"]] + CELLS
-             + [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]])
-    assert len(names) == len(set(names)), "a name is used once"
-    assert all(NAME.match(n) for n in names)
-    for entry in BENCH["configs"] + BENCH["workloads"]:
-        assert len(entry["why"]) <= 200, entry["name"]
-    four = [c for c in BENCH["workloads"] if c["chips"] == 4]
-    assert all(c["chips"] in (1, 4) for c in BENCH["workloads"])
-    assert len(four) <= max(1, len(CELLS) // 4)
-    pairs = [(c["config"], c["traffic"]) for c in BENCH["workloads"]]
-    assert len(pairs) == len(set(pairs))
     # files under paths have plain names
     for path in BENCH["paths"]:
         for root, _, files in os.walk(os.path.join(harness.ROOT, path)):
             if "__pycache__" in root:
                 continue
             for f in files:
-                assert PLAIN_PATH.match(os.path.join(root, f)), (root, f)
+                assert bm_structure.PLAIN_PATH.match(os.path.join(root, f)), \
+                    (root, f)
 
 
 def test_metrics_are_declared_once_with_unit_direction_and_bound():
-    sources = {"device_trace", "program_span", "program_counter",
-               "host_clock"}
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    for m in BENCH["end_to_end"]:
-        assert m["unit"] and m["better"] in ("higher", "lower")
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.1
-    assert e2e["setup_s"]["bound"] == 0.1
-    for m in BENCH["per_layer"]:
-        assert m["unit"] and m["better"] in ("higher", "lower")
-        assert m["source"] in sources and m["layer"]
-        assert m["moves"] in e2e and "bound" not in m
-        assert m["unit"] == "%" or not m["name"].endswith("_roofline")
-        assert callable(harness.reader_of(m["name"]).read)
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
-        assert UNIT.match(m["unit"]), (m["name"], m["unit"])
+    bm_structure.metrics_declared_once(BENCH)
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_cell_resolves_to_its_files(cell_name):
-    cell = harness.find_cell(BENCH, cell_name)
-    entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
-    assert any(entry["file"].startswith(p + "/") for p in BENCH["paths"])
-    config = harness.config_doc(BENCH, cell["config"])
-    traffic = harness.traffic_doc(cell["traffic"])
-    runner = harness.load_named("runners", traffic["runner"])
-    assert callable(runner.run) and callable(runner.load_program)
-    # the configuration's plain reference and its rows of busy time
-    assert callable(harness.load_named("reference",
-                                       config["reference"]).from_config)
-    table = harness.scope_table(config)
-    assert table and all(token and scope for token, scope in table)
-    assert config["source"] == entry["source"]
-    assert config["reduced"] == entry["reduced"]
-    # the full-size Config builds (sizes are consistent; nothing is allocated)
-    cfg = harness.build_config(harness.program_overrides(config, traffic),
-                               "unused", 0)
-    assert cfg.mesh.dp == cell["chips"]
-    # what the cell reports: set-up, another end-to-end metric, a layer's
-    e2e = [m["name"] for m in harness.cell_metrics(BENCH, cell_name,
-                                                   "end_to_end")]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    assert harness.cell_metrics(BENCH, cell_name, "per_layer")
+    bm_structure.cell_resolves(BENCH, cell_name)
 
 
 def test_every_config_has_a_cell_and_a_file_of_its_own():
-    used = {c["config"] for c in BENCH["workloads"]}
-    files = [c["file"] for c in BENCH["configs"]]
-    assert used == {c["name"] for c in BENCH["configs"]}
-    assert len(files) == len(set(files))
+    bm_structure.every_config_has_a_cell_and_a_file(BENCH)
 
 
 def test_traffic_may_not_override_a_size():
@@ -175,18 +105,58 @@ def test_runner_rehearses_on_cpu_down_to_the_json_line(
         monkeypatch.setattr(harness, "load_benchmark", lambda: bench)
     rc = run.main(["--workload", cell_name, "--seed", "3", "--seconds", "1.5",
                    "--trace", "1", "--rehearse", "1"])
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     line = _last_json(out)
     if cell_name == PLANNED["name"]:
         assert '"replicas_bit_equal": true' in out
     assert rc == 0
-    assert set(line) == LINE_KEYS | {"rehearsal"}
+    assert set(line) == LINE_KEYS | {"rehearsal", "compared"}
+    # each number compared beside its limit: the line's last key, and the
+    # last lines on standard error
+    assert list(line)[-1] == "compared" and len(line["compared"]) == 6
+    assert all(0 <= got <= limit for got, limit in line["compared"].values())
+    assert [text.split(":")[0] for text in err.strip().splitlines()[-6:]] \
+        == [f"compared {name}" for name in line["compared"]]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert line["metrics"] == {}, "a CPU run prints no metric"
     assert set(line["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     assert line["device"]["platform"] == "cpu"
+    # a CPU's capture holds nothing for the device-trace readers; the routing
+    # counters of the traced dispatch reach them where the core has experts
+    assert "readers that found something:" in out
+    (facts,) = [json.loads(text[len("facts: "):]) for text in out.splitlines()
+                if text.startswith("facts: ")]
+    if cell_name == bm_structure.MOONLIGHT:
+        counted = facts["moe_traced"]
+        assert counted["steps"] == facts["steps_per_dispatch"] == 2
+        assert 0 < counted["pairs_held"] <= counted["rows_walked"]
+    else:
+        assert "moe_traced" not in facts
+
+
+@pytest.mark.parametrize("cell_name, counted, fewest", [
+    ("r2d2-ref.learner", "subwindows", 3), ("r2d2-ref.anakin", "intervals", 5)])
+def test_a_window_whose_seconds_a_stall_ate_closes_on_work_and_is_correct(
+        cell_name, counted, fewest, tmp_path, monkeypatch, capsys):
+    """A host that stands still eats a window's seconds (the driver's run of
+    ``r2d2-paper.learner`` that PR 31 was refused for held 2 sub-windows of
+    4). ``correct`` is for answers: the window closes on its seconds and on
+    the fewest sub-windows a median stands on, and no check counts them.
+    Here the seconds are gone before the first sub-window ends."""
+    monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path))
+    rc = run.main(["--workload", cell_name, "--seed", "11", "--seconds",
+                   "0.001", "--trace", "0", "--rehearse", "1"])
+    out, _ = capsys.readouterr()
+    line = _last_json(out)
+    (facts,) = [json.loads(text[len("facts: "):]) for text in out.splitlines()
+                if text.startswith("facts: ")]
+    (checks,) = [json.loads(text[len("checks: "):])
+                 for text in out.splitlines() if text.startswith("checks: ")]
+    assert rc == 0 and line["correct"] is True
+    assert facts[counted] == fewest
+    assert counted not in checks and all(checks.values())
 
 
 def test_no_chip_no_number(tmp_path, monkeypatch, capsys):

@@ -15,17 +15,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import costs_mla_moe, harness, run, traffic
+import bm_structure
+from benchmarks import costs, costs_mla_moe, harness, run, traffic
 from benchmarks.reference import check, r2d2_mla_moe
 from benchmarks.runners import learner_long
 
 BENCH = harness.load_benchmark()
-CELL = "moonlight-core.learner-long"
+CELL = bm_structure.MOONLIGHT
 CONFIG = harness.config_doc(BENCH, "moonlight-core")
 POOL = {"pool_blocks": 4, "priority_range": [0.1, 2.0], "reward_scale": 1.0}
 ACTION_DIM = 6
-NEW_READERS = ["step_mfu_bf16", "core_self_share", "mla_self_share",
-               "moe_self_share", "moe_dispatch_self_share"]
+NEW_READERS = list(bm_structure.MOONLIGHT_READERS)
 
 
 def _tiny_learner(tmp_path, seed=0, **extra):
@@ -289,11 +289,7 @@ def test_configuration_file_is_the_catalogs_row_cut_as_it_says():
     differs = {k for k, v in source.items() if CONFIG.get(k, "absent") != v}
     assert differs == {"num_hidden_layers"}
     assert CONFIG["num_hidden_layers"] == 5
-    entry = next(c for c in BENCH["configs"] if c["name"] == "moonlight-core")
-    assert entry["reduced"] == CONFIG["reduced"]
-    assert set(CONFIG["reduced"]) == {
-        "network.core.num_hidden_layers", "network.core.experts_held",
-        "replay.capacity", "num_hidden_layers"}
+    bm_structure.moonlight_config_entry(BENCH)
     overrides = CONFIG["overrides"]
     for key, value in overrides.items():
         name = key.split(".")[-1]
@@ -303,31 +299,10 @@ def test_configuration_file_is_the_catalogs_row_cut_as_it_says():
     assert overrides["network.core.num_hidden_layers"] == 5
     assert overrides["network.core.experts_held"] == CONFIG["experts_held"] == 8
     assert "eight chips share each layer" in CONFIG["deployment"]
-    # the window and batch of r2d2-paper: the two cells differ in the core
-    paper = harness.config_doc(BENCH, "r2d2-paper")["overrides"]
-    same = {k: v for k, v in overrides.items()
-            if k in paper and k != "replay.capacity"}
-    assert same == {k: v for k, v in paper.items() if k != "replay.capacity"}
 
 
 def test_cell_joins_the_accepted_metrics():
-    def cells(name):
-        return next(m for m in BENCH["end_to_end"] + BENCH["per_layer"]
-                    if m["name"] == name).get("workloads")
-    for name in ("seq_updates_per_s", "dispatch_host_ms", "train_step_ms",
-                 "torso_self_share", "k_decode_roofline", "k_gather_roofline",
-                 "device_idle_share"):
-        assert cells(name)[-1] == CELL, name
-    for name in ("lstm_self_share", "mfu_bf16"):
-        assert CELL not in cells(name)
-    # The new readers have files and no entry yet. The contract appends new
-    # entries to ``per_layer``; test_bm_span_readers.py (PR 24) holds its
-    # three to the end of the list; neither may be edited outside a
-    # ``benchmark`` PR, which is what declares these (PERF.md, section 7).
-    declared = {m["name"] for m in BENCH["per_layer"]}
-    assert not declared & set(NEW_READERS)
-    assert [m["name"] for m in BENCH["per_layer"]][-1] == \
-        "anakin.accounting_host_ms"
+    bm_structure.moonlight_cell_joins(BENCH)
     table = harness.scope_table(CONFIG)
     tokens = [token for token, _ in table]
     assert tokens.index("moe_experts") < tokens.index("mem_core") \
@@ -339,25 +314,218 @@ def test_cell_joins_the_accepted_metrics():
         assert not any(a != b and a in b for b in tokens), a
 
 
-@pytest.mark.parametrize("reader", NEW_READERS)
-def test_new_readers_find_nothing_in_a_program_without_the_core(reader):
-    """The parent's program has no ``mem_core`` scope: on its capture (the
-    recorded r2d2-ref.learner fixture) a new reader returns nothing and does
-    not raise, with or without a trace."""
+def _fixture_summary(config):
     from benchmarks.trace import reduce, xspace_text
     (path,) = glob.glob(os.path.join(harness.BENCH_DIR, "trace", "fixtures",
                                      "*.txt.gz"))
-    summary = reduce.summarize_data(xspace_text.load(path),
-                                    scopes=harness.scope_table(CONFIG))
+    return reduce.summarize_data(xspace_text.load(path),
+                                 scopes=harness.scope_table(config))
+
+
+def _cfg(config_name, mix):
+    return harness.build_config(harness.program_overrides(
+        harness.config_doc(BENCH, config_name), harness.traffic_doc(mix)),
+        "unused", 0)
+
+
+@pytest.mark.parametrize("reader", NEW_READERS)
+def test_new_readers_find_nothing_in_a_program_without_the_core(reader):
+    """The parent's program has no ``mem_core`` scope and counts no routed
+    pairs: on its capture (the recorded r2d2-ref.learner fixture) a new
+    reader returns nothing and does not raise, with or without a trace, and
+    whether or not a count of pairs comes with it."""
+    summary = _fixture_summary(CONFIG)
     assert summary.busy_s() > 0
-    cfg = harness.build_config(harness.program_overrides(
-        harness.config_doc(BENCH, "r2d2-ref"),
-        harness.traffic_doc("learner")), "unused", 0)
+    cfg = _cfg("r2d2-ref", "learner")
+    counted = {"steps": 16, "pairs_held": 96000, "rows_walked": 122880}
     for trace in (summary, None):
-        ctx = run.MetricContext(
-            cfg=cfg, values={}, trace=trace, device_kind="TPU v5 lite",
-            facts={"steps_per_dispatch": 16, "action_dim": ACTION_DIM})
-        assert harness.reader_of(reader).read(ctx) is None
+        for extra in ({}, {"moe_traced": counted}):
+            ctx = run.MetricContext(
+                cfg=cfg, values={}, trace=trace, device_kind="TPU v5 lite",
+                config=CONFIG, facts={"steps_per_dispatch": 16,
+                                      "action_dim": ACTION_DIM, **extra})
+            assert harness.reader_of(reader).read(ctx) is None
+
+
+COUNTS = {"r2d2-ref": costs, "r2d2-paper": costs,
+          "moonlight-core": costs_mla_moe}
+
+
+@pytest.mark.parametrize("config_name, mix", [
+    ("r2d2-ref", "learner"), ("r2d2-paper", "learner"),
+    ("moonlight-core", "learner-long")])
+def test_mfu_bf16_reads_the_count_its_configuration_names(config_name, mix):
+    """One reader for the step's share of the chip: the configuration's file
+    names the module that counts its model work (``costs``), and the reading
+    is that count over the step's device time and the peak. Here on the
+    recorded capture (one program of 16 steps), whose step time all three
+    are held to."""
+    config = harness.config_doc(BENCH, config_name)
+    assert harness.costs_of(config) is COUNTS[config_name]
+    summary, cfg = _fixture_summary(config), _cfg(config_name, mix)
+    ctx = run.MetricContext(
+        cfg=cfg, values={}, trace=summary, device_kind="TPU v5 lite",
+        config=config, facts={"steps_per_dispatch": 16,
+                              "action_dim": ACTION_DIM})
+    (program,) = summary.module_runs("loss")
+    step_s = program.dur / 1e9 / 16
+    flops = COUNTS[config_name].step_flops(cfg, ACTION_DIM)
+    got = harness.reader_of("mfu_bf16").read(ctx)
+    assert got == pytest.approx(100 * flops / step_s / 197e12, rel=1e-12)
+    if config_name == "r2d2-ref":
+        # the fixture is this cell's step as PR 22 recorded it (13.1 ms)
+        assert 20 < got < 25
+    if config_name == "moonlight-core":
+        # 16.26 TFLOP of model work a step (PERF.md, section 5)
+        assert flops == pytest.approx(16.26e12, rel=2e-3)
+
+
+@pytest.mark.parametrize("config", [None, {}, {"costs": None}],
+                         ids=["no_file", "no_key", "null"])
+def test_mfu_bf16_finds_nothing_where_no_count_is_named(config):
+    summary = _fixture_summary(CONFIG)
+    ctx = run.MetricContext(
+        cfg=_cfg("r2d2-ref", "learner"), values={}, trace=summary,
+        device_kind="TPU v5 lite", config=config,
+        facts={"steps_per_dispatch": 16, "action_dim": ACTION_DIM})
+    assert harness.reader_of("mfu_bf16").read(ctx) is None
+    assert harness.reader_of("k_experts_roofline").read(ctx) is None
+
+
+def test_a_count_that_is_named_and_has_no_file_is_an_error():
+    with pytest.raises(harness.BenchError, match="no_such_count"):
+        harness.costs_of({"costs": "no_such_count"})
+
+
+STEP = "jit(multi_step)/jit(main)/while/body/"
+CORE = STEP + "R2D2Network/mem_core/layers_1/mlp/"
+
+
+def _experts_capture(products_ns=3000, steps=2):
+    """``steps`` train steps in one program: in each the held experts'
+    activation under ``moe_experts`` (1,000 ns), their grouped products as
+    the chip's capture names them (an ``op_name`` that is the call's own
+    name and no scope, ``products_ns``), the sort of the pairs, a fusion of
+    the attention, the loss."""
+    from jax.profiler import ProfileData
+
+    from benchmarks.trace import reduce, xspace_text
+
+    def op(name, start, dur, path):
+        return (f"%{name} = f32[8]{{0}} op(f32[8]{{0}} %p)", start, dur,
+                {"op_name": path})
+    ops, width = [], products_ns + 4000
+    for i in range(steps):
+        at = 1000 + i * width
+        ops += [
+            op(f"fusion.{i}1", at, 1000, CORE + "moe_experts/mul"),
+            op(f"ragged-dot-none.{i}2", at + 1000, products_ns,
+               "ragged-dot-none"),
+            op(f"sort.{i}3", at + 1000 + products_ns, 500,
+               CORE + "moe_dispatch/sort"),
+            op(f"fusion.{i}4", at + 1500 + products_ns, 2000,
+               STEP + "R2D2Network/mem_core/layers_1/mla_attn/dot_general"),
+            op(f"fusion.{i}5", at + 3500 + products_ns, 500,
+               STEP + "loss/sub"),
+        ]
+    capture = {"/device:TPU:0": {
+        "XLA Modules": [("jit_multi_step(1)", 1000, steps * width, {})],
+        "XLA Ops": ops}}
+    return reduce.summarize_data(
+        ProfileData.from_text_proto(xspace_text.to_text(capture)),
+        harness.scope_table(CONFIG))
+
+
+def _experts_ctx(summary, counted, steps_per_dispatch=2):
+    facts = {"steps_per_dispatch": steps_per_dispatch,
+             "action_dim": ACTION_DIM}
+    if counted is not None:
+        facts["moe_traced"] = counted
+    return run.MetricContext(
+        cfg=_cfg("moonlight-core", "learner-long"), values={}, facts=facts,
+        trace=summary, device_kind="TPU v5 lite", config=CONFIG)
+
+
+# one pair: 3 products of 2048 x 1408 at 2 FLOPs a multiply-add, in four
+# passes (online forward, target forward, a backward of two)
+PAIR_FLOPS = 6 * 2048 * 1408 * 4
+
+
+def test_experts_roofline_counts_pairs_and_never_the_rows_walked():
+    read = harness.reader_of("k_experts_roofline").read
+    summary = _experts_capture()
+    # the rows under moe_experts and the scope-less grouped products
+    assert summary.self_by_scope()["moe_experts"] == pytest.approx(8000e-9)
+    counted = {"steps": 2, "pairs_held": 8, "rows_walked": 10}
+    base = read(_experts_ctx(summary, counted))
+    assert base == pytest.approx(
+        100 * 8 * PAIR_FLOPS / 8000e-9 / 197e12)
+    assert 0 < base < 100
+    # twice the padding at the same pairs: the numerator does not move
+    assert read(_experts_ctx(summary, {**counted, "rows_walked": 20})) == base
+    # ... and what the padding costs shows as time: the reading falls with it
+    slower = _experts_capture(products_ns=7000)
+    assert read(_experts_ctx(slower, counted)) == pytest.approx(base / 2)
+    # the count is taken a step: a block of counters that spans other
+    # dispatches than the traced ones reads the same
+    assert read(_experts_ctx(summary, {"steps": 6, "pairs_held": 24,
+                                       "rows_walked": 30})) \
+        == pytest.approx(base)
+    assert costs_mla_moe.experts_flops(
+        _cfg("moonlight-core", "learner-long"), 1) == PAIR_FLOPS
+
+
+@pytest.mark.parametrize("counted", [
+    None, {}, {"steps": 2, "pairs_held": 0, "rows_walked": 5120},
+    {"steps": 0, "pairs_held": 0, "rows_walked": 0},
+    {"steps": 2, "rows_walked": 5120}],
+    ids=["absent", "empty", "no_pair_held", "no_step", "no_such_counter"])
+def test_experts_roofline_finds_nothing_without_a_count_of_pairs(counted):
+    """A program that counts no pairs, a step whose router put none on a
+    held expert, a checkout from before the counter: None, and no 0 for a
+    share of a peak."""
+    read = harness.reader_of("k_experts_roofline").read
+    assert read(_experts_ctx(_experts_capture(), counted)) is None
+    assert read(_experts_ctx(None, counted)) is None
+
+
+def test_the_other_readers_of_the_core_read_the_made_up_rows():
+    summary = _experts_capture()
+    ctx = _experts_ctx(summary, None)
+    busy = summary.busy_s()
+    assert busy == pytest.approx(14000e-9)
+    # the core's share holds the grouped products, which carry no scope
+    for reader, ns in (("core_self_share", 13000), ("mla_self_share", 4000),
+                       ("moe_self_share", 9000),
+                       ("moe_dispatch_self_share", 1000)):
+        assert harness.reader_of(reader).read(ctx) == pytest.approx(
+            100 * ns * 1e-9 / busy), reader
+
+
+def test_runner_hands_on_the_counters_of_the_last_flush():
+    from benchmarks.runners import learner
+    layer = {"chosen_hist": [], "pairs_held": 5, "held_load_max": 3,
+             "held_load_mean": 1.0, "router_entropy": 1.0, "dropped": 0}
+    block = {"steps": 8, "layers": [dict(layer, rows_walked=8),
+                                    dict(layer, pairs_held=7)]}
+    assert learner._moe_totals(block) == {
+        "steps": 8, "pairs_held": 12, "rows_walked": 8}
+
+    class Metrics:
+        _moe = None
+
+    class Learner:
+        metrics = Metrics()
+
+        def flush_metrics(self):
+            self.metrics._moe, self.pending = self.pending, None
+
+    loop = learner._Loop(Learner(), harness.HostSpans())
+    loop.learner.pending = block
+    assert loop.flush() is block
+    assert loop.flush() is None             # nothing new was dispatched
+    del Metrics._moe                        # a program without the counter
+    assert loop.flush() is None
 
 
 def test_runner_gives_the_learners_loop_a_subwindow_in_dispatches():

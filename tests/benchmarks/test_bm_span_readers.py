@@ -9,6 +9,7 @@ import os
 
 import pytest
 
+import bm_structure
 from benchmarks import harness, run
 
 BENCH = harness.load_benchmark()
@@ -48,24 +49,8 @@ def test_reader_finds_nothing_where_the_program_has_no_such_span(metric):
 
 
 def test_the_three_metrics_are_declared_for_the_fused_loop_alone():
-    declared = {m["name"]: m for m in BENCH["per_layer"]}
-    layers = {"anakin.log_stall_ms": "orchestration",
-              "anakin.ring_write_host_ms": "ingest",
-              "anakin.accounting_host_ms": "orchestration"}
-    for name, layer in layers.items():
-        assert declared[name] == {
-            "name": name, "unit": "ms", "better": "lower",
-            "source": "program_span", "layer": layer,
-            "moves": "env_steps_per_s", "workloads": [CELL]}
-    # appended: what the benchmark had keeps its place
-    assert [m["name"] for m in BENCH["per_layer"]][-3:] == list(layers)
-    in_cell = [m["name"] for m in harness.cell_metrics(BENCH, CELL,
-                                                       "per_layer")]
-    assert set(layers) <= set(in_cell) and len(in_cell) == 8
-    for cell in BENCH["workloads"]:
-        if cell["name"] != CELL:
-            assert not set(layers) & {m["name"] for m in harness.cell_metrics(
-                BENCH, cell["name"], "per_layer")}
+    assert list(bm_structure.ANAKIN_SPAN_READERS) == list(READERS)
+    bm_structure.anakin_span_readers(BENCH)
 
 
 def test_readers_find_the_loops_spans_in_the_rehearsal(

@@ -9,7 +9,7 @@ import os
 import pytest
 from jax.profiler import ProfileData
 
-from benchmarks import costs, harness
+from benchmarks import harness
 from benchmarks.trace import reduce, xspace_text
 
 SCOPE = "jit(multi_step)/jit(main)/while/body/"
@@ -169,7 +169,7 @@ def test_every_reader_of_a_cell_reads_a_capture(cell_name):
                                     harness.scope_table(config),
                                     host_names=["dispatch", "block_wait"])
     ctx = run.MetricContext(
-        cfg=cfg, trace=summary, device_kind="TPU v5 lite",
+        cfg=cfg, trace=summary, device_kind="TPU v5 lite", config=config,
         values={"dispatch_host_s": [1e-3, 2e-3],
                 "program_span_s": {"actor/act_scan": [1e-3],
                                    "learner/train_dispatch": [2e-3]}},
@@ -187,7 +187,7 @@ def test_every_reader_of_a_cell_reads_a_capture(cell_name):
     assert read["train_step_ms"] == pytest.approx(10000 / 1e6 / 16)
     assert read["dispatch_host_ms"] in (pytest.approx(1.5), pytest.approx(3.0))
     if "mfu_bf16" in read:
-        flops = costs.model_flops_per_step(cfg, 6)
+        flops = harness.costs_of(config).step_flops(cfg, 6)
         assert read["mfu_bf16"] == pytest.approx(
             100 * flops / (10000e-9 / 16) / 197e12)
 
