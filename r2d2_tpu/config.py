@@ -65,21 +65,44 @@ class EnvConfig:
 class CoreConfig:
     """The memory core between the torso and the dueling head
     (``models/cores/``). ``kind="lstm"`` is the R2D2 LSTM of width
-    ``network.hidden_dim`` and reads none of the other keys. ``"mla_moe"`` is
-    a stack of DeepSeek-V3-form layers (latent attention, then a dense or a
-    mixture-of-experts SwiGLU) whose keys are spelt as the source model's
-    ``config.json`` spells them; the defaults are Moonlight-16B-A3B's
+    ``network.hidden_dim`` and reads none of the other keys. The other two
+    are stacks of a language model's layers whose keys are spelt as the
+    source model's ``config.json`` spells them; a key that means the same in
+    both sources is one key here.
+
+    ``"mla_moe"``: DeepSeek-V3-form layers (latent attention, then a dense
+    or a mixture-of-experts SwiGLU); the defaults are Moonlight-16B-A3B's
     (https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json).
     The source's keys that choose between forms (``q_lora_rank``,
     ``scoring_func``, ``topk_method``, ``n_group``, ``topk_group``,
     ``norm_topk_prob``, ``moe_layer_freq``) are not here: the core implements
     the one form that config names (models/cores/mla_moe.py).
+
+    ``"conv_attn_moe"``: LFM2-MoE-form layers
+    (https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json,
+    ``model_type: lfm2_moe``), each a gated short convolution or
+    grouped-query attention by ``layer_types`` ("conv" / "full_attention",
+    one name a layer), then a dense or a mixture-of-experts SwiGLU
+    (models/cores/conv_attn_moe.py). It reads ``layer_types``,
+    ``conv_L_cache`` (the convolution's length L: a conv layer stores L - 1
+    positions) and ``num_key_value_heads`` (heads are ``hidden_size /
+    num_attention_heads`` wide) beside the shared keys; the source spells
+    three of those otherwise: ``n_routed_experts`` is its ``num_experts``,
+    ``first_k_dense_replace`` its ``num_dense_layers``, ``rms_norm_eps`` its
+    ``norm_eps``. Its forms that are not options (sigmoid scores, the
+    expert bias in the choice, normalised weights, no shared expert, no
+    biases) are not keys; it reads none of the latent attention's keys
+    (``kv_lora_rank``, ``qk_*_head_dim``, ``v_head_dim``) nor
+    ``n_shared_experts``, and it has no defaults of its own: a
+    configuration spells every size (benchmarks/configs/lfm2-core.json).
+
     Three keys are this program's own: ``experts_held`` / ``expert_offset``
     (the router scores all ``n_routed_experts`` and picks
     ``num_experts_per_tok`` of them; this chip computes the experts
     ``expert_offset .. expert_offset + experts_held - 1`` and leaves out what
-    the others would add) and ``memory_len`` (positions of latent cache the
-    recurrent state carries)."""
+    the others would add) and ``memory_len`` (positions of latent cache, or
+    of keys and values, that the recurrent state carries for an attention
+    layer)."""
 
     kind: str = "lstm"
     hidden_size: int = 2048
@@ -101,13 +124,18 @@ class CoreConfig:
     experts_held: int = 64
     expert_offset: int = 0
     memory_len: int = 40
+    layer_types: Tuple[str, ...] = ()
+    conv_L_cache: int = 3
+    num_key_value_heads: int = 8
 
     def __post_init__(self):
-        if self.kind not in ("lstm", "mla_moe"):
+        # a JSON list (a checkpoint's .config.json) hashes as a tuple
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.kind not in ("lstm", "mla_moe", "conv_attn_moe"):
             raise ValueError(
-                f"network.core.kind ({self.kind!r}) must be 'lstm' or "
-                "'mla_moe'")
-        if self.kind != "mla_moe":
+                f"network.core.kind ({self.kind!r}) must be 'lstm', "
+                "'mla_moe' or 'conv_attn_moe'")
+        if self.kind == "lstm":
             return
         if not (0 <= self.expert_offset and self.experts_held >= 1
                 and self.expert_offset + self.experts_held
@@ -119,11 +147,34 @@ class CoreConfig:
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError(
                 "network.core.num_experts_per_tok exceeds n_routed_experts")
-        if self.qk_rope_head_dim % 2 or self.memory_len < 1 \
-                or self.num_hidden_layers < 1:
+        if self.memory_len < 1 or self.num_hidden_layers < 1:
             raise ValueError(
-                "network.core: qk_rope_head_dim must be even, memory_len "
-                "and num_hidden_layers at least 1")
+                "network.core: memory_len and num_hidden_layers must be at "
+                "least 1")
+        if self.kind == "mla_moe":
+            if self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "network.core.qk_rope_head_dim must be even")
+            return
+        if len(self.layer_types) != self.num_hidden_layers or any(
+                kind not in ("conv", "full_attention")
+                for kind in self.layer_types):
+            raise ValueError(
+                f"network.core.layer_types ({self.layer_types!r}) must name "
+                f"each of the {self.num_hidden_layers} layers 'conv' or "
+                "'full_attention'")
+        heads, groups = self.num_attention_heads, self.num_key_value_heads
+        if (heads < 1 or groups < 1 or heads % groups
+                or self.hidden_size % heads
+                or (self.hidden_size // heads) % 2):
+            raise ValueError(
+                "network.core: num_key_value_heads must divide "
+                "num_attention_heads, which must divide hidden_size into "
+                "heads of an even width")
+        if self.conv_L_cache < 2:
+            raise ValueError(
+                "network.core.conv_L_cache must be at least 2 (a conv layer "
+                "stores conv_L_cache - 1 positions)")
 
 
 @dataclass(frozen=True)
@@ -1918,6 +1969,9 @@ def _coerce(key: str, value: str, annotation: str) -> Any:
                 "';'-separated out_channels,kernel,stride triples, e.g. "
                 "8,4,2;16,3,1")
         return layers
+    if "Tuple[str, ...]" in str(annotation):
+        # names joined by ',' (--network.core.layer_types=conv,full_attention)
+        return tuple(name for name in value.split(",") if name)
     if str(annotation) == "Any":
         # union knob (actor.anakin_priority: a float stamp or "td") —
         # numeric strings become floats, anything else stays a string

@@ -208,7 +208,7 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             "q_chosen": q_chosen,
         }
         if counters:
-            # the mla_moe core's routing counters of the online forward
+            # the routing counters of the online forward (a core with experts)
             aux["moe"] = counters
         return loss, aux
 
@@ -294,9 +294,9 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
                                            old_params)
             params = optax.apply_updates(old_params, updates)
         if "moe" in aux:
-            # the mean the mla_moe core centred its routers' inputs on goes
-            # among the parameters, where acting reads it
-            from r2d2_tpu.models.cores.mla_moe import store_router_means
+            # the mean the core centred its routers' inputs on goes among
+            # the parameters, where acting reads it
+            from r2d2_tpu.models.cores.experts import store_router_means
             params = store_router_means(params, aux["moe"]["input_mean"])
 
         target_params, target_sync = sync_target(
@@ -310,7 +310,7 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             "target_sync": target_sync,
         }
         if "moe" in aux:
-            # the mla_moe core's routing counters of this step
+            # the experts' routing counters of this step
             metrics.update({f"moe/{k}": v for k, v in aux["moe"].items()
                             if k != "input_mean"})
         metrics.update(ld)

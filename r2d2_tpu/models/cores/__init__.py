@@ -20,10 +20,28 @@ it is talking to:
     core takes over positions comes from the call's own batch of windows,
     where acting reads what the train step stored among the parameters.
 
+A core also says what the learner has to know of it, so that nothing asks
+for a core by name: ``routes_experts`` (its layers route positions to
+experts: the forward pass sows routing counters, the train step stores the
+routers' input means among the parameters and the record carries a ``moe``
+block; ``experts.py``) and ``state_parts()``, the state row by kind of part
+as (kind, layers that keep such a part, floats in all of them), which the
+record's ``core`` block carries (``state_block``).
+
 ``lstm``: R2D2's LSTM (``HoistedLSTM``), the row is (h, c).
-``mla_moe``: latent attention over a stored latent cache and a mixture of
-experts of which this chip holds a share (``mla_moe.py``).
+``mla_moe``: DeepSeek-V3-form layers: latent attention over a stored latent
+cache and a mixture of experts of which this chip holds a share
+(``mla_moe.py``).
+``conv_attn_moe``: LFM2-MoE-form layers: gated short convolutions beside
+grouped-query attention by ``layer_types``, each kind with its own stored
+state (the convolution's last inputs, a window of keys and values) packed
+layer by layer into the one row, and the same held experts with no shared
+expert (``conv_attn_moe.py``). Its departures from the source stand in its
+docstring and in ``benchmarks/configs/lfm2-core.json``.
+The two stacks share ``experts.py``: router, held experts, counters.
 """
+
+from typing import Any, Dict
 
 from r2d2_tpu.config import NetworkConfig
 
@@ -33,8 +51,24 @@ def make_core(config: NetworkConfig, dtype):
     if config.core.kind == "lstm":
         from r2d2_tpu.models.cores.lstm import LSTMCore
         return LSTMCore(config, dtype)
+    if config.core.kind == "conv_attn_moe":
+        from r2d2_tpu.models.cores.conv_attn_moe import ConvAttnMoeCore
+        return ConvAttnMoeCore(config.core, dtype)
     from r2d2_tpu.models.cores.mla_moe import MlaMoeCore
     return MlaMoeCore(config.core, dtype)
+
+
+def state_block(config: NetworkConfig) -> Dict[str, Any]:
+    """The record's ``core`` block: the core's kind and its state row by
+    kind of part (``state_parts``), so that a reader can tell what a
+    sequence's stored row holds and how much of it."""
+    import jax.numpy as jnp
+    core = make_core(config, jnp.float32)
+    return {"kind": config.core.kind, "state_half": core.state_half,
+            "row_bytes": 8 * core.state_half,
+            "parts": [{"kind": kind, "layers": layers, "floats": floats,
+                       "bytes": 4 * floats}
+                      for kind, layers, floats in core.state_parts()]}
 
 
 def require_lstm(config: NetworkConfig, what: str) -> None:

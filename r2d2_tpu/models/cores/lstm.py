@@ -15,10 +15,14 @@ class LSTMCore:
     config: NetworkConfig
     dtype: Any
     scope: str = "lstm"
+    routes_experts = False
 
     @property
     def state_half(self) -> int:
         return self.config.hidden_dim
+
+    def state_parts(self):
+        return [("lstm_h_c", 1, 2 * self.config.hidden_dim)]
 
     @property
     def out_dim(self) -> int:

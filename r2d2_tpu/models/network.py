@@ -523,9 +523,9 @@ class NetworkApply:
     def apply_learner(self, params, obs_seq, last_action_seq, hidden):
         """The learner's forward pass over a batch of windows: ``apply``
         with the core's ``window_stats`` on, and the counters the core sowed
-        into the ``moe`` collection while it ran (the ``mla_moe`` core's
-        routing: models/cores/mla_moe.py; {} for a core that sows none)."""
-        from r2d2_tpu.models.cores.mla_moe import moe_counters
+        into the ``moe`` collection while it ran (the routing of a core with
+        experts: models/cores/experts.py; {} for a core that sows none)."""
+        from r2d2_tpu.models.cores.experts import moe_counters
         (q, new_hidden), sown = self.module.apply(
             params, obs_seq, last_action_seq, hidden, True, mutable=["moe"])
         return q, new_hidden, moe_counters(sown)

@@ -621,9 +621,9 @@ def gather_rows(ring: jnp.ndarray, block_idx: jnp.ndarray, start: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Rows summed to their positions (the ``mla_moe`` core's held experts: a
+# Rows summed to their positions (the held experts of the cores that route: a
 # chunk of (position, expert) pairs' rows goes back to the positions' sum,
-# models/cores/mla_moe.py ``held_experts_ffn``).
+# models/cores/experts.py ``held_experts_ffn``).
 
 # Positions a grid step of ``add_rows_pallas`` accumulates. My chip runs,
 # PR 30, the kernel alone at the cell's sizes, three chunks: 0.716 / 0.707 /

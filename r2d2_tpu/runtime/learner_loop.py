@@ -66,10 +66,10 @@ class Learner:
         self._learning_agg = (LearningAggregator(
             player_idx, cfg.runtime.save_dir, cfg.telemetry.nan_policy,
             cfg.optim.lr) if self._diag is not None else None)
-        # the mla_moe core's routing counters (record block 'moe')
+        # the routing counters of a core with experts (record block 'moe')
         from r2d2_tpu.telemetry.learning import MoeAggregator
         self._moe_agg = (MoeAggregator(cfg.network.core)
-                         if cfg.network.core.kind == "mla_moe" else None)
+                         if net.core.routes_experts else None)
         # replay & data-pathology pillar (ISSUE 10): same spec/aggregator
         # pattern — a ReplayDiag fuses sum-tree health, sample-lifetime
         # accounting and lane composition into the step; None (the
@@ -240,6 +240,10 @@ class Learner:
 
         self.metrics = metrics or TrainMetrics(player_idx, cfg.runtime.save_dir,
                                                resume=bool(cfg.runtime.resume))
+        # what a sequence's stored state row holds (record block 'core', on
+        # the run's first record)
+        from r2d2_tpu.models.cores import state_block
+        self.metrics.set_core(state_block(cfg.network))
         if self._exp_trace is not None:
             # experience lineage (ISSUE 19): the record's 'trace' block
             self.metrics.set_tracing(self._exp_trace.interval_block)

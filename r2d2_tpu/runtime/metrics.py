@@ -115,6 +115,7 @@ class TrainMetrics:
         # telemetry.costmodel_enabled kill switch (schema byte-identical
         # to pre-PR9, stability-tested)
         self._costs = None
+        self._core = None
 
         # serving plane (ISSUE 13): a serving-block provider
         # (ServingStats.interval_block, attached by the orchestrating
@@ -275,6 +276,14 @@ class TrainMetrics:
         configured step (telemetry/costmodel.analytic_component_costs).
         Emitted on exactly one record then cleared; None = no block."""
         self._costs = block
+
+    def set_core(self, block: Optional[dict]) -> None:
+        """Attach the one-shot memory-core block: the core's kind and its
+        state row by kind of part (kind, layers, floats, bytes —
+        models/cores/__init__.py state_block), so that a reader can tell an
+        LSTM's (h, c) from a latent cache, and a convolution's state from a
+        window of keys and values. Emitted on exactly one record."""
+        self._core = block
 
     def set_serving(self, provider) -> None:
         """Attach the serving-block provider (ISSUE 13): a callable
@@ -471,6 +480,9 @@ class TrainMetrics:
             # carries them and the stream stays lean
             record["costs"] = self._costs
             self._costs = None
+        if self._core is not None:
+            record["core"] = self._core
+            self._core = None
         if self.telemetry.enabled:
             # ONE aggregated block per interval covering the whole fleet:
             # learner-local stage timers merged with the actor board's

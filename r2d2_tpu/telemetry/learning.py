@@ -254,11 +254,13 @@ def fused_diagnostics(net, spec, diag: LearningDiag, new_step, params,
 
 
 class MoeAggregator:
-    """The ``mla_moe`` core's routing counters between two flushes: each
-    dispatch's ``moe/`` outputs are summed on the device (a few hundred
-    integers a step; no sync on the step path), and ``flush`` fetches the
+    """The routing counters of a core with experts (``models/cores/
+    experts.py``: the ``mla_moe`` and ``conv_attn_moe`` cores) between two
+    flushes: each dispatch's ``moe/`` outputs are summed on the device (a
+    few hundred integers a step; no sync on the step path), and ``flush``
+    fetches the
     sums once and makes the record's ``moe`` block, per expert layer: the
-    histogram of chosen experts over all routed experts, the
+    histogram of chosen experts over all ``n_routed_experts``, the
     (position, expert) pairs that fell on the experts held here, the
     largest and the mean load among those, the router's entropy (nats, of
     the scores normalised over the experts, mean over positions and steps),

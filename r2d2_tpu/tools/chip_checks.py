@@ -146,10 +146,11 @@ def run_chip_checks(only: str = "") -> int:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     add("exact_gather", exact_gather)
 
-    # --- rows summed to their positions (the mla_moe core's held experts) -
-    # at the moonlight-core cell's shapes (8,000 positions, chunks of 2,560
-    # bf16 rows), at acting's (T = 1: 64 lanes, one chunk of 64 x 6 pairs)
-    # and at sizes that fill no tile
+    # --- rows summed to their positions (the held experts of the cores
+    # that route, models/cores/experts.py) at the moonlight-core cell's
+    # shapes (8,000 positions, chunks of 2,560 bf16 rows) and the lfm2-core
+    # cell's (chunks of 3,072), at acting's (T = 1: 64 lanes, one chunk of
+    # 64 x 6 pairs) and at sizes that fill no tile
     def add_rows(positions, rows, dtype):
         def check():
             rng = fresh_rng()
@@ -169,6 +170,7 @@ def run_chip_checks(only: str = "") -> int:
                                        rtol=1e-6, atol=1e-6)
         return check
     add("add_rows_n8000_r2560", add_rows(8000, 2560, jnp.bfloat16))
+    add("add_rows_n8000_r3072", add_rows(8000, 3072, jnp.bfloat16))
     add("add_rows_n64_r384", add_rows(64, 384, jnp.bfloat16))
     add("add_rows_n13_r78_f32", add_rows(13, 78, jnp.float32))
 

@@ -162,7 +162,13 @@ def test_parents_config_json_loads(tmp_path):
     # every value the file holds besides them arrived (JSON has no tuples;
     # a field added since is not the file's to hold)
     loaded = json.loads(cfg.to_json())
+
+    def held(stored, loaded):
+        """``loaded`` at the keys ``stored`` has, nested groups alike."""
+        return {k: held(v, loaded[k]) if isinstance(v, dict) else loaded[k]
+                for k, v in stored.items()}
+
     for section, fields in stored.items():
-        assert {k: loaded[section][k] for k in fields} == fields
+        assert held(fields, loaded[section]) == fields
     shutil.copy(path, tmp_path / "ckpt_7.config.json")
     assert load_checkpoint_config(str(tmp_path / "ckpt_7")) == cfg

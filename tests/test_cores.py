@@ -1,8 +1,12 @@
 """The memory-core interface (``r2d2_tpu/models/cores/``): the LSTM behind it
 is the parent's network bit for bit; the ``mla_moe`` core's state, acting
-step, routing and balance rule; and the places that store a state row (ring,
-``LocalBuffer``, anakin carry, state cache, snapshot) take its width from the
-core. All at tiny sizes on the CPU, seeded random weights."""
+step, routing and balance rule; what holds of either core with experts
+(``mla_moe``, ``conv_attn_moe``: the cases parametrised over ``kind``); and
+the places that store a state row (ring, ``LocalBuffer``, anakin carry,
+state cache, snapshot) take its width from the core. The ``conv_attn_moe``
+core's own cases (two kinds of part in one row) are in
+``test_core_conv_attn_moe.py``. All at tiny sizes on the CPU, seeded random
+weights."""
 
 import dataclasses
 
@@ -28,13 +32,27 @@ TINY_CORE = {"kind": "mla_moe", "hidden_size": 32, "num_attention_heads": 2,
              "moe_intermediate_size": 16, "n_routed_experts": 8,
              "num_experts_per_tok": 2, "experts_held": 4,
              "num_hidden_layers": 2, "memory_len": 4}
+# 3 layers: (2 x 32) + 4 positions x (2 key/value heads x 8 x 2) + (2 x 32)
+# = 256 values = (2, 128)
+TINY_CONV_CORE = {"kind": "conv_attn_moe", "hidden_size": 32,
+                  "num_attention_heads": 4, "num_key_value_heads": 2,
+                  "conv_L_cache": 3, "intermediate_size": 64,
+                  "moe_intermediate_size": 16, "n_routed_experts": 8,
+                  "num_experts_per_tok": 2, "experts_held": 4,
+                  "num_hidden_layers": 3,
+                  "layer_types": ("conv", "full_attention", "conv"),
+                  "memory_len": 4, "rope_theta": 1e6,
+                  "routed_scaling_factor": 1.0}
+CORES = {"mla_moe": TINY_CORE, "conv_attn_moe": TINY_CONV_CORE}
+HALF = {"mla_moe": 80, "conv_attn_moe": 128}      # state_half of each
+both_cores = pytest.mark.parametrize("kind", list(CORES))
 TINY_REPLAY = {"sequence.burn_in_steps": 4, "sequence.learning_steps": 5,
                "sequence.forward_steps": 3, "replay.block_length": 20,
                "replay.capacity": 160, "replay.batch_size": 8}
 
 
-def tiny_config(**extra) -> Config:
-    core = {f"network.core.{k}": v for k, v in TINY_CORE.items()}
+def tiny_config(kind="mla_moe", **extra) -> Config:
+    core = {f"network.core.{k}": v for k, v in CORES[kind].items()}
     return Config().replace(**{**TINY_ENV, **TINY_REPLAY, **core, **extra})
 
 
@@ -68,10 +86,12 @@ def test_replace_reaches_the_core_and_keeps_the_rest():
     hash(cfg.network)        # flax hashes the section
 
 
-def test_config_with_a_core_round_trips_through_json():
-    cfg = tiny_config()
+@both_cores
+def test_config_with_a_core_round_trips_through_json(kind):
+    cfg = tiny_config(kind)
     again = Config.from_json(cfg.to_json())
     assert again == cfg and isinstance(again.network.core, CoreConfig)
+    hash(again.network)      # a JSON list came back as a tuple
 
 
 def test_command_line_reaches_the_core():
@@ -161,11 +181,13 @@ def test_state_row_is_the_latent_cache():
     assert state_half(full.network) == 57_600
 
 
-def test_unroll_equals_acting_step_by_step():
+@both_cores
+def test_unroll_equals_acting_step_by_step(kind):
     """An episode's first memory_len + 1 steps: the learner's unroll of the
     block from the empty state gives the Q and the final state of the same
-    block acted one step at a time through the rolling cache."""
-    cfg = tiny_config()
+    block acted one step at a time through the rolling cache (and, in the
+    ``conv_attn_moe`` core, the convolutions' shifting inputs)."""
+    cfg = tiny_config(kind)
     net = tiny_net(cfg)
     params = net.init(jax.random.PRNGKey(0))
     steps = cfg.network.core.memory_len + 1
@@ -212,8 +234,9 @@ def test_an_empty_slot_is_not_attended_to():
     assert np.isfinite(np.asarray(from_empty)).all()
 
 
-def test_the_stored_cache_gets_no_gradient():
-    cfg = tiny_config()
+@both_cores
+def test_the_stored_cache_gets_no_gradient(kind):
+    cfg = tiny_config(kind)
     net = tiny_net(cfg)
     params = net.init(jax.random.PRNGKey(0))
     obs, action, state = inputs(jax.random.PRNGKey(4), 2, 6, net)
@@ -275,23 +298,30 @@ def test_the_router_reads_what_varies_between_positions(seed):
     assert (left == 0).sum() >= 6                 # and many experts none
 
 
-def test_acting_subtracts_the_mean_the_train_step_stored():
+@both_cores
+def test_acting_subtracts_the_mean_the_train_step_stored(kind):
     """``apply_learner`` hands back the mean it centred each router on;
     with that stored among the parameters, acting on the same positions
-    routes and answers as the learner did."""
-    cfg = tiny_config()
+    routes and answers as the learner did. The expert layers are found by
+    their routers, whichever core holds them."""
+    cfg = tiny_config(kind)
+    core = cfg.network.core
+    routers = core.num_hidden_layers - core.first_k_dense_replace
     net = tiny_net(cfg)
     params = net.init(jax.random.PRNGKey(0))
     obs, action, state = inputs(jax.random.PRNGKey(8), 4, 6, net)
     q, final, counters = net.apply_learner(params, obs, action, state)
     means = counters["input_mean"]
-    assert means.shape == (1, cfg.network.core.hidden_size)
+    assert means.shape == (routers, core.hidden_size)
     assert float(jnp.abs(means).max()) > 0
     q_unstored, _ = net.apply(params, obs, action, state)
     assert float(jnp.abs(q_unstored - q).max()) > 0
     stored = mla_moe.store_router_means(params, means)
-    leaf = stored["params"]["mem_core"]["layers_1"]["mlp"]["router_input_mean"]
-    np.testing.assert_array_equal(leaf, means[0])
+    for i in range(routers):
+        leaf = stored["params"]["mem_core"][
+            f"layers_{core.first_k_dense_replace + i}"]["mlp"][
+                "router_input_mean"]
+        np.testing.assert_array_equal(leaf, means[i])
     assert jax.tree_util.tree_structure(stored) == \
         jax.tree_util.tree_structure(params)
     q_acted, final_acted = net.apply(stored, obs, action, state)
@@ -299,8 +329,9 @@ def test_acting_subtracts_the_mean_the_train_step_stored():
     np.testing.assert_allclose(final_acted, final, atol=2e-6)
 
 
-def test_the_bias_gets_no_gradient_and_the_router_does():
-    cfg = tiny_config()
+@both_cores
+def test_the_bias_gets_no_gradient_and_the_router_does(kind):
+    cfg = tiny_config(kind)
     net = tiny_net(cfg)
     params = net.init(jax.random.PRNGKey(0))
     obs, action, state = inputs(jax.random.PRNGKey(6), 2, 6, net)
@@ -313,25 +344,28 @@ def test_the_bias_gets_no_gradient_and_the_router_does():
     assert float(jnp.abs(mlp["experts"]["gate_up_proj"]).max()) > 0.0
 
 
-def test_paths_written_for_the_lstm_refuse_another_core():
+@both_cores
+def test_paths_written_for_the_lstm_refuse_another_core(kind):
+    """... and the message names the core that was refused."""
     from r2d2_tpu.learner.train_step import make_external_batch_step
     from r2d2_tpu.replay.structs import ReplaySpec
-    cfg = tiny_config()
+    cfg = tiny_config(kind)
     net = tiny_net(cfg)
-    with pytest.raises(NotImplementedError, match="LSTM core"):
+    with pytest.raises(NotImplementedError,
+                       match=f"LSTM core; network.core.kind='{kind}'"):
         make_external_batch_step(net, ReplaySpec.from_config(cfg), cfg.optim,
                                  False)
-    with pytest.raises(ValueError, match="inference_dtype"):
-        tiny_net(tiny_config(**{"network.inference_dtype": "int8"}))
+    with pytest.raises(ValueError, match=f"inference_dtype.*{kind}"):
+        tiny_net(tiny_config(kind, **{"network.inference_dtype": "int8"}))
 
 
 # -- whoever stores a state row takes its width from the core ---------------
 
 
-def _spec():
+def _spec(kind):
     from r2d2_tpu.replay.structs import ReplaySpec
-    spec = ReplaySpec.from_config(tiny_config())
-    assert spec.hidden_dim == 80
+    spec = ReplaySpec.from_config(tiny_config(kind))
+    assert spec.hidden_dim == HALF[kind]
     return spec
 
 
@@ -352,10 +386,11 @@ def _blocks(spec, rng, n):
     return blocks, np.stack(rows)
 
 
-def test_local_buffer_stores_the_cores_row(rng):
-    spec = _spec()
+@both_cores
+def test_local_buffer_stores_the_cores_row(rng, kind):
+    spec = _spec(kind)
     (first, second), rows = _blocks(spec, rng, 2)
-    assert first.hidden.shape == (spec.seqs_per_block, 2, 80)
+    assert first.hidden.shape == (spec.seqs_per_block, 2, HALF[kind])
     # the second block's sequences start from rows the actor handed over
     stored = np.asarray(second.hidden).reshape(spec.seqs_per_block, -1)
     handed = rows.reshape(len(rows), -1)
@@ -363,29 +398,32 @@ def test_local_buffer_stores_the_cores_row(rng):
         assert (np.abs(handed - row).max(axis=1) == 0).any()
 
 
-def test_ring_stores_and_samples_the_cores_row(rng):
+@both_cores
+def test_ring_stores_and_samples_the_cores_row(rng, kind):
     from r2d2_tpu.replay.device_replay import (replay_add, replay_init,
                                                replay_sample)
-    spec = _spec()
+    spec, half = _spec(kind), HALF[kind]
     blocks, _ = _blocks(spec, rng, 3)
     state = replay_init(spec)
-    assert state.hidden.shape == (spec.num_blocks, spec.seqs_per_block, 2, 80)
+    assert state.hidden.shape == (spec.num_blocks, spec.seqs_per_block, 2,
+                                  half)
     for block in blocks:
         state = replay_add(spec, state, block)
     batch = replay_sample(spec, state, jax.random.PRNGKey(0))
-    assert batch.hidden.shape == (spec.batch_size, 2, 80)
+    assert batch.hidden.shape == (spec.batch_size, 2, half)
     stored = np.concatenate([np.asarray(b.hidden) for b in blocks]).reshape(
-        -1, 160)
+        -1, 2 * half)
     for row in np.asarray(batch.hidden).reshape(spec.batch_size, -1):
         assert (np.abs(stored - row).max(axis=1) == 0).any()
 
 
-def test_snapshot_round_trips_the_cores_row(rng, tmp_path):
+@both_cores
+def test_snapshot_round_trips_the_cores_row(rng, tmp_path, kind):
     from r2d2_tpu.replay.device_replay import replay_add, replay_init
     from r2d2_tpu.replay.snapshot import (capture_plain, load_snapshot,
                                           restore_plain, write_snapshot)
     from r2d2_tpu.replay.structs import RingAccountant
-    spec = _spec()
+    spec = _spec(kind)
     state, ring = replay_init(spec), RingAccountant(spec.num_blocks)
     for block in _blocks(spec, rng, 2)[0]:
         state = replay_add(spec, state, block)
@@ -404,14 +442,15 @@ def test_snapshot_round_trips_the_cores_row(rng, tmp_path):
                       load_snapshot(str(tmp_path), 0))
 
 
-def test_state_cache_and_policies_carry_the_cores_row(rng):
+@both_cores
+def test_state_cache_and_policies_carry_the_cores_row(rng, kind):
     from r2d2_tpu.actor.policy import ActorPolicy
     from r2d2_tpu.serve.state_cache import StateCache
-    cfg = tiny_config()
+    cfg, half = tiny_config(kind), HALF[kind]
     net = tiny_net(cfg)
     cache = StateCache(4, 1, (24, 24), 2, net.state_half, action_dim=ACTIONS)
-    assert cache.hidden.shape == (4, 2, 80)
-    row = rng.normal(size=(2, 80)).astype(np.float32)
+    assert cache.hidden.shape == (4, 2, half)
+    row = rng.normal(size=(2, half)).astype(np.float32)
     slot, fresh = cache.lease(7)
     assert fresh
     cache.write_hidden(slot, row)
@@ -423,17 +462,18 @@ def test_state_cache_and_policies_carry_the_cores_row(rng):
     # the actor's policy starts from the core's empty row and hands back
     # one of the same shape
     policy = ActorPolicy(net, net.init(jax.random.PRNGKey(0)), epsilon=0.0)
-    assert policy.hidden.shape == (1, 2, 80)
+    assert policy.hidden.shape == (1, 2, half)
     policy.observe_reset(np.zeros((24, 24), np.uint8))
     _, _, hidden = policy.act()
-    assert np.asarray(hidden).shape[-2:] == (2, 80)
+    assert np.asarray(hidden).shape[-2:] == (2, half)
 
 
-def test_anakin_carry_holds_the_cores_row():
+@both_cores
+def test_anakin_carry_holds_the_cores_row(kind):
     from r2d2_tpu.actor.anakin import init_act_carry
     from r2d2_tpu.envs.factory import create_jax_env
-    cfg = tiny_config()
+    cfg, half = tiny_config(kind), HALF[kind]
     env = create_jax_env(cfg.env)
-    carry = init_act_carry(env, _spec(), 3, jax.random.PRNGKey(0))
-    assert carry.hidden.shape == (3, 2, 80)
-    assert carry.tail_hidden.shape == (3, 4 + 1, 2, 80)
+    carry = init_act_carry(env, _spec(kind), 3, jax.random.PRNGKey(0))
+    assert carry.hidden.shape == (3, 2, half)
+    assert carry.tail_hidden.shape == (3, 4 + 1, 2, half)
