@@ -106,12 +106,13 @@ from r2d2_tpu.models.cores.experts import (INIT, SCOPE, Norm, SwiGLU, matmul,
 _INIT_OUT = experts.residual_init(24)
 _F32 = jnp.float32
 
-# Rows of sorted pairs a grouped product takes at a time
-# (experts.held_experts_ffn). A step's work moves in whole chunks, so the size
-# is chosen for the pairs a layer expects at the benchmark's batch (8,000
-# positions x 4 x 8/32 = 8,000): three chunks hold them with 15% to spare
-# (mla_moe.py's 2,560 would walk four, 10,240 rows, for the same pairs).
-CHUNK_ROWS = 3072
+# Rows of an overflow chunk: what ``experts.held_experts_ffn`` takes at a time
+# of the sorted pairs past its first chunk (8,704 rows for the benchmark's
+# 8,000 expected pairs). As in ``mla_moe.py``, where the sweep is: one layer
+# forward and backward, 9.2 ms with none over, reads 14.3 | 14.2 | 14.4 with
+# 9,000 pairs at chunks of 512 | 1,024 | 2,048 rows (the parent's walk: 19.6;
+# my chip runs, PR 34), so the size is the other core's.
+CHUNK_ROWS = 1024
 
 
 class Part(NamedTuple):
@@ -223,7 +224,7 @@ class GroupedQueryAttention(nn.Module):
 
 class MoE(experts.RoutedMoE):
     """This source's expert layer: ``experts.RoutedMoE`` with no shared
-    expert, 1e-6 in the weights' normalisation, and chunks of
+    expert, 1e-6 in the weights' normalisation, and overflow chunks of
     ``CHUNK_ROWS`` (read at every call)."""
     topk_eps: float = 1e-6
     shared: bool = False

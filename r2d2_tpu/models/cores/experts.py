@@ -12,15 +12,21 @@ expert every position goes through (``RoutedMoE``). What differs between the
 sources is an argument of the caller and no option of the program: the
 epsilon of the weights' normalisation, whether there is a shared expert, the
 initialiser of the projections into the residual stream (it follows the
-published depth), and the rows a chunk of the walk takes.
+published depth), and the rows an overflow chunk of the walk takes.
 
 Held experts: no pair is dropped and there is no capacity factor: all pairs
 are sorted by expert (pairs on absent experts last), and the held ones go
-through grouped matrix products a chunk of rows at a time, as many chunks as
-there are held pairs (``held_experts_ffn``). The way back follows the same
-pairs: each chunk adds its rows to their positions' float32 sums as it goes
-(``add_rows``), forward and backward, so no array has a row for every pair
-and a chip that holds an eighth of the experts moves an eighth of the rows.
+through grouped matrix products (``held_experts_ffn``): first one chunk of
+sorted rows that holds the pairs a layer expects here, which the shapes give
+(``first_chunk_rows``: positions x top-k x held / routed, and a margin), then
+overflow chunks of the core's ``CHUNK_ROWS`` rows, as many as the pairs past
+the first chunk fill. What a chunk costs beyond its rows (the weights read,
+the positions' sums streamed, the weights' gradient written) is paid once a
+layer-pass at the expected load, and a router that runs over it pays in
+small steps. The way back follows the same pairs: each chunk adds its rows
+to their positions' float32 sums as it goes (``add_rows``), forward and
+backward, so no array has a row for every pair and a chip that holds an
+eighth of the experts moves an eighth of the rows.
 
 The router reads what varies between positions. An agent's stream is not a
 language model's: the torso's latents are rectified, so every position
@@ -48,6 +54,7 @@ them). Parameter names are the ``mla_moe`` core's (``gate``,
 """
 
 import functools
+import math
 from typing import Any, Dict
 
 import flax.linen as nn
@@ -55,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from r2d2_tpu.config import CoreConfig
-from r2d2_tpu.ops.pallas_kernels import add_rows
+from r2d2_tpu.ops.pallas_kernels import add_rows, sum_rows
 
 # every core builds its modules under this scope (the module's name, the
 # device trace's scope and the parameter group's name)
@@ -142,15 +149,40 @@ def route(scores, bias, top_k: int, scale: float, eps: float):
     return chosen, weights
 
 
-def grouped_matmul(rows, weights, group_sizes, dtype):
+def grouped_matmul(rows, weights, group_sizes, dtype, out_dtype=None):
     """Rows sorted by group times their group's matrix: rows (M, k),
-    weights (G, k, n), ``group_sizes`` (G,) -> (M, n) in ``dtype``; rows
-    past the groups' total are undefined. ``jax.lax.ragged_dot``: XLA's own
-    grouped product on the TPU, with XLA's own backward (PERF.md, Findings,
-    PR 27 says why not the megablox kernels)."""
+    weights (G, k, n), ``group_sizes`` (G,) -> (M, n): ``dtype`` operands
+    accumulated in float32, the result in ``out_dtype`` (``dtype`` where
+    none is given); rows past the groups' total are undefined.
+    ``jax.lax.ragged_dot``: XLA's own grouped product on the TPU (PERF.md,
+    Findings, PR 27 says why not the megablox kernels)."""
     return jax.lax.ragged_dot(rows.astype(dtype), weights.astype(dtype),
                               group_sizes, preferred_element_type=_F32
-                              ).astype(dtype)
+                              ).astype(out_dtype or dtype)
+
+
+# (M, k) x (M, n) -> (G, k, n): the rows are the contracted axis, cut into
+# the groups (what XLA's own backward of ``ragged_dot`` asks for)
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def grouped_outer(rows, cots, group_sizes, dtype):
+    """The gradient of ``grouped_matmul``'s weights: each group's rows (M, k)
+    transposed times its rows of ``cots`` (M, n) -> (G, k, n), ``dtype``
+    operands, float32 accumulated and float32 out: a sum over pairs is kept
+    in float32, never rounded on its way to the optimizer."""
+    return jax.lax.ragged_dot_general(
+        rows.astype(dtype), cots.astype(dtype), group_sizes,
+        _ROWS_CONTRACTED, preferred_element_type=_F32)
+
+
+def _activation(gu):
+    """SwiGLU's elementwise middle on the first product's [gate | up]."""
+    width = gu.shape[1] // 2
+    return (jax.nn.silu(gu[:, :width].astype(_F32))
+            * gu[:, width:].astype(_F32)).astype(gu.dtype)
 
 
 def _chunk_ffn(x, weight, gate_up, down, sizes, live):
@@ -159,28 +191,46 @@ def _chunk_ffn(x, weight, gate_up, down, sizes, live):
     marks the rows that stand for a pair on a held expert; a grouped
     product leaves the rows past its groups undefined, so those read zero
     and give no gradient."""
-    width = down.shape[1]
     x = jnp.where(live, x, 0)
     with jax.named_scope("moe_experts"):
-        gu = grouped_matmul(x, gate_up, sizes, x.dtype)
-    act = (jax.nn.silu(gu[:, :width].astype(_F32))
-           * gu[:, width:].astype(_F32)).astype(x.dtype)
-    with jax.named_scope("moe_experts"):
-        out = grouped_matmul(act, down, sizes, _F32)
+        act = _activation(grouped_matmul(x, gate_up, sizes, x.dtype))
+        out = grouped_matmul(act, down, sizes, x.dtype, _F32)
     return jnp.where(live, out * weight[:, None], 0).astype(x.dtype)
 
 
-def _chunk_of(i, chunk: int, h, order, pair_weight, group_sizes):
-    """Chunk ``i`` of the sorted pairs: (its pairs, the position each
-    row's sum goes to, ``len(h)`` where the row stands for no pair on a
+def _chunk_ffn_back(x, weight, gate_up, gate_up_t, down_t, sizes, live, g):
+    """``_chunk_ffn``'s backward for the rows' gradient ``g``: (the rows'
+    gradient in ``x``'s dtype, the routing weights' (rows,) float32, and
+    the two weights' gradients in float32, as the grouped products
+    accumulate them). ``gate_up_t`` (G, 2f, d) and ``down_t`` (G, d, f) are
+    the weights transposed. It recomputes the activation, not the second
+    product: with t = g times ``down_t``, a row's routing weight has the
+    gradient t . act and its activation weight x t."""
+    dt = x.dtype
+    x, g = jnp.where(live, x, 0), jnp.where(live, g, 0)
+    with jax.named_scope("moe_experts"):
+        gu = grouped_matmul(x, gate_up, sizes, dt)
+        act, middle_back = jax.vjp(_activation, gu)
+        t = jnp.where(live, grouped_matmul(g, down_t, sizes, dt, _F32), 0)
+        d_weight = jnp.sum(t * act.astype(_F32), axis=-1)
+        d_down = grouped_outer(act, g.astype(_F32) * weight[:, None], sizes,
+                               dt)
+        (d_gu,) = middle_back((t * weight[:, None]).astype(dt))
+        d_x = jnp.where(live, grouped_matmul(d_gu, gate_up_t, sizes, dt), 0)
+        d_gate_up = grouped_outer(x, d_gu, sizes, dt)
+    return d_x, d_weight, d_gate_up, d_down
+
+
+def _chunk_of(lo, rows: int, h, order, pair_weight, group_sizes):
+    """The ``rows`` sorted pairs from ``lo`` on: (its pairs, the position
+    each row's sum goes to, ``len(h)`` where the row stands for no pair on a
     held expert; its positions' rows of ``h``, its pairs' weights, the
     groups' sizes inside it, its live rows)."""
-    lo = i * chunk
-    pairs = jax.lax.dynamic_slice_in_dim(order, lo, chunk)
+    pairs = jax.lax.dynamic_slice_in_dim(order, lo, rows)
     ends = jnp.cumsum(group_sizes)
-    sizes = (jnp.clip(ends, lo, lo + chunk)
-             - jnp.clip(ends - group_sizes, lo, lo + chunk))
-    live = lo + jnp.arange(chunk) < ends[-1]
+    sizes = (jnp.clip(ends, lo, lo + rows)
+             - jnp.clip(ends - group_sizes, lo, lo + rows))
+    live = lo + jnp.arange(rows) < ends[-1]
     n = h.shape[0]
     at = pairs % n
     with jax.named_scope("moe_dispatch"):
@@ -189,91 +239,157 @@ def _chunk_of(i, chunk: int, h, order, pair_weight, group_sizes):
     return pairs, jnp.where(live, at, n), x, w, sizes, live[:, None]
 
 
-def _live_chunks(group_sizes, chunk: int):
-    return (jnp.sum(group_sizes) + chunk - 1) // chunk
+# The first chunk's room over the pairs a layer expects, and the tile its
+# rows are rounded up to. The tile is the grouped product's (XLA's Mosaic
+# kernel works on 512 rows at a time): it takes 6,656 rows (13 tiles) in less
+# time than 6,400 and a third less than 6,528 (0.177 | 0.199 | 0.268 us a row
+# in the first product at the moonlight-core cell's widths; 8,704 | 8,448 |
+# 8,576 rows read the same at the lfm2-core cell's). The margin: a centred
+# router's layers hold up to 6% more than the expectation as their mean over
+# a run (it moves with the seed) and 1% more or less from step to step, the
+# first overflow chunk costs a layer 4.3 | 5.0 ms forward and backward (its
+# program's own accumulators and their add) and a tile more of first chunk
+# 0.3 ms, so the margin is worth its rows as soon as one layer-pass in
+# fourteen would run over; with the tile, 5% makes 6,656 rows of 6,000
+# expected and 8,704 of 8,000, and no layer of 160 steps of either cell ran
+# over (my chip runs, PR 34: one layer alone and the cells; PERF.md,
+# Findings).
+FIRST_CHUNK_MARGIN = 0.05
+ROW_TILE = 512
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def first_chunk_rows(positions: int, core: CoreConfig) -> int:
+    """Sorted rows the walk's first chunk takes, from the shapes alone: the
+    pairs a layer expects on the experts held here (every expert as likely
+    as another), ``FIRST_CHUNK_MARGIN`` more, in whole ``ROW_TILE``s, and
+    never more than all pairs."""
+    pairs = positions * core.num_experts_per_tok
+    expected = pairs * core.experts_held / core.n_routed_experts
+    tiles = math.ceil(expected * (1 + FIRST_CHUNK_MARGIN) / ROW_TILE)
+    return min(tiles * ROW_TILE, pairs)
+
+
+def _overflow_chunks(group_sizes, first: int, chunk: int):
+    """Chunks of ``chunk`` rows that the pairs past the first chunk fill
+    (``chunk`` 0: the first chunk holds every pair there can be)."""
+    if not chunk:
+        return jnp.zeros((), jnp.int32)
+    return (jnp.maximum(jnp.sum(group_sizes) - first + chunk - 1, 0)
+            // chunk).astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def held_experts_ffn(h, order, pair_weight, gate_up, down, group_sizes,
-                     chunk: int):
+                     first: int, chunk: int):
     """The held experts' weighted SwiGLU, summed to the positions.
 
     ``h`` (N, d) the positions; ``order`` (M,) the pairs sorted by expert,
-    the pairs on absent experts last, padded to whole chunks with numbers
-    past the pairs' (pair = choice * N + position); ``pair_weight``
-    (N * top_k,) the pairs' routing weights in pair order; gate_up
-    (G, d, 2f), down (G, f, d); ``group_sizes`` (G,) the pairs on each held
-    expert. Returns each position's sum over its pairs on held experts
-    (N, d) float32, and the number of rows the chunks it walked took in as
-    pairs.
+    the pairs on absent experts last, padded to the walk's whole chunks
+    with numbers past the pairs' (pair = choice * N + position);
+    ``pair_weight`` (N * top_k,) the pairs' routing weights in pair order;
+    gate_up (G, d, 2f), down (G, f, d); ``group_sizes`` (G,) the pairs on
+    each held expert. Returns each position's sum over its pairs on held
+    experts (N, d) float32, and the number of rows the chunks it walked
+    took in as pairs.
 
-    The sorted pairs are taken ``chunk`` at a time, and only as many chunks
-    as hold a pair on a held expert (a loop with a dynamic trip count): a
-    chunk gathers its positions' rows and its pairs' weights, runs the two
-    grouped products and adds its rows to their positions' sums
-    (``add_rows``). So the work follows the pairs that are here, there and
-    back, whatever the router's skew, with static shapes and no pair
-    dropped: no array has a row for every pair. The backward is its own: it
-    walks the same chunks, gathers a chunk's positions' rows of the sum's
-    gradient, recomputes the chunk's activation, adds the weights'
-    gradients into one float32 accumulator in place and the rows'
-    gradients to their positions as the forward does."""
+    The walk: one chunk of the first ``first`` sorted rows, which holds the
+    pairs a layer expects (``first_chunk_rows``) and runs outside any loop,
+    then as many chunks of ``chunk`` rows as the pairs past it fill (a loop
+    with a dynamic trip count; ``chunk`` 0: the first chunk holds every
+    pair). A chunk gathers its positions' rows and its pairs' weights, runs
+    the two grouped products and adds its rows to their positions' sums
+    (``add_rows``; the first chunk's start at zero: ``sum_rows``). What a
+    chunk costs beyond its rows (the weights read, the sums streamed through
+    ``add_rows``, in the backward the weights' gradient written) a layer at
+    its expected load pays once, and a load above it pays in small steps.
+    So the work follows the pairs that are
+    here, there and back, whatever the router's skew, with static shapes and
+    no pair dropped: no array has a row for every pair. The backward is its
+    own: it walks the same chunks, gathers a chunk's positions' rows of the
+    sum's gradient, recomputes the chunk's activation and adds the rows'
+    gradients to their positions as the forward does. The first chunk's
+    gradient of the weights, float32 as the grouped product accumulates it,
+    is the gradient where nothing runs over; where something does, another
+    program (a ``lax.cond`` on the overflow chunks' number) sums the
+    overflow chunks' into float32 arrays of its own and adds them to it."""
     # the weights come as the parameters are kept (float32) and are cast
     # here, once a call, so that their gradient goes back uncast
     gate_up, down = gate_up.astype(h.dtype), down.astype(h.dtype)
-    # the second product takes float32 operands: cast once here; left to
-    # the chunk's own cast, XLA makes it again for every chunk (0.17 ms
-    # each at the cell's sizes; PERF.md, Findings, PR 30)
-    down = down.astype(_F32)
 
-    def one(i, carry):
-        total, covered = carry
-        _, pos, x, w, sizes, live = _chunk_of(i, chunk, h, order,
+    def one(lo, rows, total, covered):
+        _, pos, x, w, sizes, live = _chunk_of(lo, rows, h, order,
                                               pair_weight, group_sizes)
-        rows = _chunk_ffn(x, w, gate_up, down, sizes, live)
+        out = _chunk_ffn(x, w, gate_up, down, sizes, live)
         with jax.named_scope("moe_combine"):
-            total = add_rows(total, rows, pos)
+            # the first chunk's sums start at zero: none are read
+            total = (sum_rows(out, pos, h.shape[0]) if total is None
+                     else add_rows(total, out, pos))
         return total, covered + jnp.sum(live, dtype=jnp.int32)
 
+    walked = one(0, first, None, jnp.zeros((), jnp.int32))
+    if not chunk:
+        return walked
     return jax.lax.fori_loop(
-        0, _live_chunks(group_sizes, chunk), one,
-        (jnp.zeros(h.shape, _F32), jnp.zeros((), jnp.int32)))
+        0, _overflow_chunks(group_sizes, first, chunk),
+        lambda i, carry: one(first + i * chunk, chunk, *carry), walked)
 
 
 def _held_experts_fwd(h, order, pair_weight, gate_up, down, group_sizes,
-                      chunk):
+                      first, chunk):
     return (held_experts_ffn(h, order, pair_weight, gate_up, down,
-                             group_sizes, chunk),
+                             group_sizes, first, chunk),
             (h, order, pair_weight, gate_up, down, group_sizes))
 
 
-def _held_experts_bwd(chunk, res, g):
+def _held_experts_bwd(first, chunk, res, g):
     g, _ = g                                # the count carries no gradient
     h, order, pair_weight, gate_up, down, group_sizes = res
     kept = gate_up.dtype, down.dtype
     gate_up, down = gate_up.astype(h.dtype), down.astype(h.dtype)
+    gate_up_t, down_t = jnp.swapaxes(gate_up, 1, 2), jnp.swapaxes(down, 1, 2)
 
-    def one(i, carry):
-        dh, dw, dw1, dw2 = carry
-        pairs, pos, x, w, sizes, live = _chunk_of(i, chunk, h, order,
+    def one(lo, rows, dh, dw):
+        pairs, pos, x, w, sizes, live = _chunk_of(lo, rows, h, order,
                                                   pair_weight, group_sizes)
         with jax.named_scope("moe_combine"):
             # a row's gradient is its position's
             gi = g[pairs % g.shape[0]].astype(h.dtype)
-        _, vjp = jax.vjp(
-            lambda x, w, w1, w2: _chunk_ffn(x, w, w1, w2, sizes, live),
-            x, w, gate_up, down)
-        dxi, dwi, dw1i, dw2i = vjp(gi)
+        dxi, dwi, dw1, dw2 = _chunk_ffn_back(x, w, gate_up, gate_up_t, down_t,
+                                             sizes, live, gi)
         with jax.named_scope("moe_dispatch"):
-            dh = add_rows(dh, dxi, pos)
+            dh = (sum_rows(dxi, pos, h.shape[0]) if dh is None
+                  else add_rows(dh, dxi, pos))
             # a permutation's numbers and, past them, the padding's
             dw = dw.at[pairs].set(dwi, mode="drop", unique_indices=True)
-        return dh, dw, dw1 + dw1i.astype(_F32), dw2 + dw2i.astype(_F32)
+        return dh, dw, dw1, dw2
 
-    dh, dw, dw1, dw2 = jax.lax.fori_loop(
-        0, _live_chunks(group_sizes, chunk), one,
-        (jnp.zeros(h.shape, _F32), jnp.zeros_like(pair_weight),
-         jnp.zeros(gate_up.shape, _F32), jnp.zeros(down.shape, _F32)))
+    overflow_chunks = _overflow_chunks(group_sizes, first, chunk)
+
+    def walk(run_over: bool):
+        dh, dw, dw1, dw2 = one(0, first, None, jnp.zeros_like(pair_weight))
+        if not run_over:
+            return dh, dw, dw1, dw2
+
+        def overflow(i, carry):
+            dh, dw, over1, over2 = carry
+            dh, dw, dw1i, dw2i = one(first + i * chunk, chunk, dh, dw)
+            return dh, dw, over1 + dw1i, over2 + dw2i
+
+        # the overflow chunks' sum starts from zeros of its own and is added
+        # to the first chunk's at the end: a loop that carries the first
+        # chunk's product and adds to it makes XLA lay the parameters out
+        # transposed, copy every float32 array the optimizer touches and
+        # recompute forward fusions for want of memory (CPU, count, PR 34)
+        dh, dw, over1, over2 = jax.lax.fori_loop(
+            0, overflow_chunks, overflow,
+            (dh, dw, jnp.zeros_like(dw1), jnp.zeros_like(dw2)))
+        return dh, dw, dw1 + over1, dw2 + over2
+
+    # two programs, so that a walk that stays inside its first chunk pays
+    # nothing for the other's accumulators
+    dh, dw, dw1, dw2 = (jax.lax.cond(overflow_chunks > 0, lambda: walk(True),
+                                     lambda: walk(False))
+                        if chunk else walk(False))
     return (dh.astype(h.dtype), None, dw, dw1.astype(kept[0]),
             dw2.astype(kept[1]), None)
 
@@ -284,12 +400,14 @@ held_experts_ffn.defvjp(_held_experts_fwd, _held_experts_bwd)
 class HeldExperts(nn.Module):
     """The routed experts this chip holds, for the pairs that fall on them:
     all N*k (position, expert) pairs are sorted by expert, the pairs on
-    absent experts last; the held ones go through ``held_experts_ffn``,
-    ``chunk_rows`` sorted rows at a time, which gives each position's sum.
-    Beside it the layer's counters: ``dropped``, the pairs the router put on
-    held experts less the rows the chunks took in (none: there is no
-    capacity to run out of), and ``rows_walked``, the sorted rows of the
-    chunks that were walked."""
+    absent experts last; the held ones go through ``held_experts_ffn``, the
+    first ``first_chunk_rows`` sorted rows at once and what runs over
+    ``chunk_rows`` at a time, which gives each position's sum. Beside it the
+    layer's counters: ``dropped``, the pairs the router put on held experts
+    less the rows the chunks took in (none: there is no capacity to run out
+    of), ``rows_walked``, the sorted rows of the chunks that were walked
+    (the first and the overflow chunks), and ``overflow_chunks``, how many
+    of the latter."""
     core: CoreConfig
     dtype: Any
     chunk_rows: int
@@ -303,8 +421,10 @@ class HeldExperts(nn.Module):
                           c.moe_intermediate_size)
         gate_up = self.param("gate_up_proj", INIT, (held, d, 2 * width))
         down = self.param("down_proj", self.out_init, (held, width, d))
-        chunk = min(self.chunk_rows, n * k)
-        padded = -(-n * k // chunk) * chunk
+        first = first_chunk_rows(n, c)
+        # 0: the first chunk holds every pair there can be
+        chunk = min(self.chunk_rows, n * k - first)
+        padded = first + (chunk and -(-(n * k - first) // chunk) * chunk)
 
         with jax.named_scope("moe_dispatch"):
             # pairs numbered choice-major: pair = choice * N + position
@@ -319,12 +439,14 @@ class HeldExperts(nn.Module):
                 key[:, None] == jnp.arange(held)[None, :], axis=0,
                 dtype=jnp.int32)
             pair_weight = jnp.where(on_held, weights.T.reshape(-1), 0.0)
-        routed, covered = held_experts_ffn(h.astype(dt), order, pair_weight,
-                                           gate_up, down, group_sizes, chunk)
+        routed, covered = held_experts_ffn(
+            h.astype(dt), order, pair_weight, gate_up, down, group_sizes,
+            first, chunk)
+        overflow = _overflow_chunks(group_sizes, first, chunk)
         return routed, {
             "dropped": jnp.sum(on_held, dtype=jnp.int32) - covered,
-            "rows_walked": (_live_chunks(group_sizes, chunk)
-                            * chunk).astype(jnp.int32)}
+            "rows_walked": first + overflow * chunk,
+            "overflow_chunks": overflow}
 
 
 class RoutedMoE(nn.Module):
@@ -343,9 +465,8 @@ class RoutedMoE(nn.Module):
 
     @property
     def chunk_rows(self) -> int:
-        """Rows of sorted pairs a grouped product takes at a time: a step's
-        work moves in whole chunks, so a core sizes it for the pairs a
-        layer expects at its benchmark's batch."""
+        """Rows of an overflow chunk: what the walk takes at a time of the
+        sorted pairs past its first chunk."""
         raise NotImplementedError
 
     @nn.compact
@@ -396,8 +517,8 @@ class RoutedMoE(nn.Module):
 def moe_counters(mutated: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
     """The stack's sown counters out of ``apply(..., mutable=['moe'])``'s
     second result: {chosen (L_moe, routed), entropy (L_moe,), dropped
-    (L_moe,), rows_walked (L_moe,), input_mean (L_moe, hidden)}, or {} for
-    a stack without expert layers."""
+    (L_moe,), rows_walked (L_moe,), overflow_chunks (L_moe,), input_mean
+    (L_moe, hidden)}, or {} for a stack without expert layers."""
     found = jax.tree_util.tree_leaves(
         mutated.get("moe", {}), is_leaf=lambda x: isinstance(x, tuple))
     return found[0][0] if found else {}

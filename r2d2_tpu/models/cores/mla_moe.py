@@ -140,17 +140,23 @@ def route(scores, bias, core: CoreConfig):
                          core.routed_scaling_factor, 1e-20)
 
 
-# Rows of sorted pairs a grouped product takes at a time (held_experts_ffn).
-# A step's work moves in whole chunks, so the size is chosen for the pairs a
-# layer expects at the benchmark's batch (8,000 positions x 6 x 8/64 = 6,000):
-# three chunks hold them with a quarter to spare and two fall short by a
-# seventh, so a router a few per cent off its expectation costs the same.
-CHUNK_ROWS = 2560
+# Rows of an overflow chunk: what ``experts.held_experts_ffn`` takes at a time
+# of the sorted pairs past its first chunk (which holds the pairs a layer
+# expects, from the shapes: 6,656 rows for the benchmark's 6,000). What an
+# overflow chunk costs is mostly not its rows (the weights' gradient summed,
+# the sums streamed, grouped products that small chunks do not fill): one
+# layer forward and backward, 7.4 ms with none over, reads 10.6 | 11.7 | 11.6
+# with 6,900 pairs at chunks of 512 | 1,024 | 2,048 rows and 13.8 | 13.1 |
+# 10.8 with 8,000 pairs on one held expert (the parent's walk: 17.5 and
+# 20.2; my chip runs, PR 34). So a router just over its expectation pays
+# about the same whatever the size and a skewed one pays fewer of them.
+# Read at every call.
+CHUNK_ROWS = 1024
 
 
 class MoE(experts.RoutedMoE):
     """This source's expert layer: ``experts.RoutedMoE`` with the shared
-    expert, 1e-20 in the weights' normalisation, and chunks of
+    expert, 1e-20 in the weights' normalisation, and overflow chunks of
     ``CHUNK_ROWS`` (read at every call)."""
     topk_eps: float = 1e-20
     shared: bool = True

@@ -630,6 +630,13 @@ def gather_rows(ring: jnp.ndarray, block_idx: jnp.ndarray, start: jnp.ndarray,
 # 0.696 / 0.690 ms at 256 / 512 / 1,000 / 2,000; the smaller block leaves
 # the fast memory to the kernel's neighbours.
 _ADD_ROWS_BLOCK = 512
+# Bytes of a call's rows, which it keeps whole in VMEM as float32 (a dynamic
+# sublane of a packed bf16 array is not something Mosaic reads); with the
+# pipeline's four blocks of the sums (16 MiB at 2,048 wide) and Mosaic's own,
+# under a v5e's 128 MiB. 9,216 rows at 2,048 wide: the first chunk of either
+# cell with experts (6,656 | 8,704 rows: 52 | 68 MiB) is one call, and more
+# rows than that go through in slices, each streaming the sums once.
+_ADD_ROWS_VMEM_BYTES = 72 * 2**20
 
 
 def add_rows_reference(acc: jnp.ndarray, rows: jnp.ndarray,
@@ -638,29 +645,29 @@ def add_rows_reference(acc: jnp.ndarray, rows: jnp.ndarray,
     return acc.at[pos].add(rows.astype(acc.dtype), mode="drop")
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def add_rows_pallas(acc: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
-                    block: int = _ADD_ROWS_BLOCK,
-                    interpret: bool = False) -> jnp.ndarray:
-    """``acc[pos[r]] += rows[r]`` in float32 for every row with ``pos[r]``
-    under ``acc``'s length; a row at or past it (a caller's mark for "no
-    pair here") is not read. acc (N, d) float32, updated in place; rows
-    (R, d); pos (R,) int32, repeats allowed.
+def sum_rows_reference(rows: jnp.ndarray, pos: jnp.ndarray,
+                       positions: int) -> jnp.ndarray:
+    """The jnp twin of ``sum_rows_pallas``."""
+    return add_rows_reference(
+        jnp.zeros((positions, rows.shape[1]), jnp.float32), rows, pos)
 
-    A scatter as a gather: the rows are numbered in the order of their
-    positions (one sort of R keys, the row's number in the key's low
-    digits, so equal positions keep the rows' order), the positions are cut
-    into blocks of ``block``, and a grid step holds one block of ``acc`` in
-    VMEM and adds its rows to it, one dynamic sublane at a time, from the
-    chunk's rows, which the first step brought into VMEM whole. ``acc``
-    streams through once (the pipeline's blocks), whatever R is: 131 MB at
-    the cell's 8,000 x 2,048, where XLA's scatter-add of 2,560 rows takes
-    three times as long and the gather of all pairs' rows it replaces
-    (2.9 ms for three chunks' worth) four times (my chip runs, PR 30)."""
+
+def _rows_to_positions(acc, n: int, rows, pos, block: int, interpret: bool,
+                       slice_rows: int):
+    """``add_rows_pallas`` (``acc`` (n, d)) and ``sum_rows_pallas`` (``acc``
+    None: the sums start at zero and no array of zeros is made or read)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, d = acc.shape
+    d = rows.shape[1]
+    if not slice_rows:
+        slice_rows = max(_ADD_ROWS_VMEM_BYTES // (4 * d) // 8 * 8, 8)
+    if rows.shape[0] > slice_rows:
+        for lo in range(0, rows.shape[0], slice_rows):
+            acc = _rows_to_positions(
+                acc, n, rows[lo:lo + slice_rows], pos[lo:lo + slice_rows],
+                block, interpret, slice_rows)
+        return acc
     if rows.shape[0] % 8:
         pad = -rows.shape[0] % 8
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
@@ -678,8 +685,8 @@ def add_rows_pallas(acc: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
         where, jnp.arange(blocks + 1, dtype=jnp.int32) * block,
         side="left").astype(jnp.int32)
 
-    def kernel(source_ref, where_ref, starts_ref, acc_ref, rows_hbm, out_ref,
-               rows_ref, sem):
+    def kernel(source_ref, where_ref, starts_ref, *refs):
+        rows_hbm, out_ref, rows_ref, sem = refs[-4:]
         b = pl.program_id(0)
 
         @pl.when(b == 0)
@@ -688,7 +695,8 @@ def add_rows_pallas(acc: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
             copy.start()
             copy.wait()
 
-        out_ref[...] = acc_ref[...]
+        out_ref[...] = (jnp.zeros_like(out_ref) if acc is None
+                        else refs[0][...])
 
         def add(j, carry):
             p = where_ref[j] - b * block
@@ -697,18 +705,20 @@ def add_rows_pallas(acc: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
 
         jax.lax.fori_loop(starts_ref[b], starts_ref[b + 1], add, 0)
 
+    streamed = pl.BlockSpec((block, d), lambda b, *_: (b, 0))
+    held = [] if acc is None else [acc]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(blocks,),
-            in_specs=[pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
-            scratch_shapes=[pltpu.VMEM((count, d), acc.dtype),
+            in_specs=[streamed] * len(held) + [
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=streamed,
+            scratch_shapes=[pltpu.VMEM((count, d), jnp.float32),
                             pltpu.SemaphoreType.DMA]),
-        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
-        input_output_aliases={3: 0},
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        input_output_aliases={3: 0} if held else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # the pipeline's two blocks in and two out, the chunk's rows,
@@ -716,10 +726,48 @@ def add_rows_pallas(acc: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
             vmem_limit_bytes=(4 * block + count) * d * 4 + 4 * 2**20),
         cost_estimate=pl.CostEstimate(
             flops=count * d, transcendentals=0,
-            bytes_accessed=(2 * n + count) * d * 4),
+            bytes_accessed=((1 + len(held)) * n + count) * d * 4),
         name="add_rows",
         interpret=interpret,
-    )(source, where, starts, acc, rows.astype(acc.dtype))
+    )(source, where, starts, *held, rows.astype(jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def add_rows_pallas(acc: jnp.ndarray, rows: jnp.ndarray, pos: jnp.ndarray,
+                    block: int = _ADD_ROWS_BLOCK,
+                    interpret: bool = False,
+                    slice_rows: int = 0) -> jnp.ndarray:
+    """``acc[pos[r]] += rows[r]`` in float32 for every row with ``pos[r]``
+    under ``acc``'s length; a row at or past it (a caller's mark for "no
+    pair here") is not read. acc (N, d) float32, updated in place; rows
+    (R, d); pos (R,) int32, repeats allowed.
+
+    A scatter as a gather: the rows are numbered in the order of their
+    positions (one sort of R keys, the row's number in the key's low
+    digits, so equal positions keep the rows' order), the positions are cut
+    into blocks of ``block``, and a grid step holds one block of ``acc`` in
+    VMEM and adds its rows to it, one dynamic sublane at a time, from the
+    chunk's rows, which the first step brought into VMEM whole. ``acc``
+    streams through once (the pipeline's blocks) for every ``slice_rows``
+    rows (what ``_ADD_ROWS_VMEM_BYTES`` holds, where none is given): 131 MB
+    at the cell's 8,000 x 2,048, where XLA's scatter-add of 2,560 rows takes
+    three times as long and the gather of all pairs' rows it replaces
+    (2.9 ms for three chunks' worth) four times (my chip runs, PR 30)."""
+    return _rows_to_positions(acc, acc.shape[0], rows, pos, block, interpret,
+                              slice_rows)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def sum_rows_pallas(rows: jnp.ndarray, pos: jnp.ndarray, positions: int,
+                    block: int = _ADD_ROWS_BLOCK,
+                    interpret: bool = False,
+                    slice_rows: int = 0) -> jnp.ndarray:
+    """``add_rows_pallas`` onto sums that start at zero, (positions, d)
+    float32: the same kernel with no sums to read, so the walk's first
+    chunk writes its positions' sums once and neither fills nor reads an
+    array of zeros (half of the call's traffic at the cell's sizes)."""
+    return _rows_to_positions(None, positions, rows, pos, block, interpret,
+                              slice_rows)
 
 
 def add_rows(acc: jnp.ndarray, rows: jnp.ndarray,
@@ -729,3 +777,12 @@ def add_rows(acc: jnp.ndarray, rows: jnp.ndarray,
     one lowered for anything else (Mosaic compiles for the TPU alone)."""
     return jax.lax.platform_dependent(
         acc, rows, pos, tpu=add_rows_pallas, default=add_rows_reference)
+
+
+def sum_rows(rows: jnp.ndarray, pos: jnp.ndarray,
+             positions: int) -> jnp.ndarray:
+    """``add_rows`` onto (positions, d) float32 sums that start at zero."""
+    return jax.lax.platform_dependent(
+        rows, pos,
+        tpu=functools.partial(sum_rows_pallas, positions=positions),
+        default=functools.partial(sum_rows_reference, positions=positions))

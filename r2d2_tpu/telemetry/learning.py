@@ -264,11 +264,14 @@ class MoeAggregator:
     (position, expert) pairs that fell on the experts held here, the
     largest and the mean load among those, the router's entropy (nats, of
     the scores normalised over the experts, mean over positions and steps),
-    the pairs dropped (always 0: the core has no capacity limit) and
+    the pairs dropped (always 0: the core has no capacity limit),
     ``rows_walked``, the sorted rows of the chunks the held experts walked
-    (live chunks x chunk, summed over the steps): ``pairs_held`` over it is
-    how full the walk was, and it over steps x all pairs the share of the
-    pairs' rows that the experts' way there and back touched."""
+    (the first chunk, which holds the pairs a layer expects, and
+    ``overflow_chunks`` x the overflow chunk's rows, summed over the steps):
+    ``pairs_held`` over it is how full the walk was, and it over steps x
+    all pairs the share of the pairs' rows that the experts' way there and
+    back touched; and ``overflow_chunks``, the chunks walked past the first:
+    over the steps, how often the first chunk's margin was too small."""
 
     def __init__(self, core):
         self.held = slice(core.expert_offset,
@@ -299,9 +302,9 @@ class MoeAggregator:
         sums, steps = jax.device_get(self._sums), self._steps
         self._sums, self._steps = None, 0
         layers = []
-        for chosen, entropy, dropped, walked in zip(
+        for chosen, entropy, dropped, walked, overflow in zip(
                 sums["chosen"], sums["entropy"], sums["dropped"],
-                sums["rows_walked"]):
+                sums["rows_walked"], sums["overflow_chunks"]):
             held = chosen[self.held]
             layers.append({
                 "chosen_hist": [int(c) for c in chosen],
@@ -311,6 +314,7 @@ class MoeAggregator:
                 "router_entropy": float(entropy) / steps,
                 "dropped": int(dropped),
                 "rows_walked": int(walked),
+                "overflow_chunks": int(overflow),
             })
         return {"steps": steps, "layers": layers}
 

@@ -150,21 +150,28 @@ def run_chip_checks(only: str = "") -> int:
     # that route, models/cores/experts.py) at the moonlight-core cell's
     # shapes (8,000 positions, chunks of 2,560 bf16 rows) and the lfm2-core
     # cell's (chunks of 3,072), at acting's (T = 1: 64 lanes, one chunk of
-    # 64 x 6 pairs) and at sizes that fill no tile
-    def add_rows(positions, rows, dtype):
+    # 64 x 6 pairs), at sizes that fill no tile, and since PR 34 at the
+    # walk's first chunk in either cell (6,656 | 8,704 rows, float32 in
+    # VMEM: 52 | 68 MiB; ``sum_rows``: its sums start at zero), at an
+    # overflow chunk's 1,024 rows and at more rows than one call holds (two
+    # slices)
+    def add_rows(positions, rows, dtype, from_zero=False):
         def check():
             rng = fresh_rng()
             from r2d2_tpu.ops.pallas_kernels import (add_rows_pallas,
-                                                     add_rows_reference)
+                                                     add_rows_reference,
+                                                     sum_rows_pallas)
             # a third of the rows stand for no pair
             pos = jnp.asarray(np.where(
                 rng.random(rows) < 0.33, positions,
                 rng.integers(0, positions, rows)), jnp.int32)
             x = jnp.asarray(rng.standard_normal((rows, 2048)), dtype)
-            acc = jnp.asarray(rng.standard_normal((positions, 2048)),
-                              jnp.float32)
+            acc = (jnp.zeros((positions, 2048)) if from_zero else
+                   jnp.asarray(rng.standard_normal((positions, 2048)),
+                               jnp.float32))
             want = add_rows_reference(acc, x, pos)
-            got = add_rows_pallas(acc, x, pos)
+            got = (sum_rows_pallas(x, pos, positions) if from_zero
+                   else add_rows_pallas(acc, x, pos))
             # float32 sums of at most a few rows, in another order
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=1e-6, atol=1e-6)
@@ -173,6 +180,11 @@ def run_chip_checks(only: str = "") -> int:
     add("add_rows_n8000_r3072", add_rows(8000, 3072, jnp.bfloat16))
     add("add_rows_n64_r384", add_rows(64, 384, jnp.bfloat16))
     add("add_rows_n13_r78_f32", add_rows(13, 78, jnp.float32))
+    add("sum_rows_n8000_r6656", add_rows(8000, 6656, jnp.bfloat16, True))
+    add("sum_rows_n8000_r8704", add_rows(8000, 8704, jnp.bfloat16, True))
+    add("sum_rows_n64_r384", add_rows(64, 384, jnp.bfloat16, True))
+    add("add_rows_n8000_r1024", add_rows(8000, 1024, jnp.bfloat16))
+    add("add_rows_n8000_r12800_sliced", add_rows(8000, 12800, jnp.bfloat16))
 
     # --- quantized acting forward (ISSUE 14): compile + parity ----------
     def quant_forward():

@@ -20,6 +20,7 @@ from r2d2_tpu.models.cores import make_core, state_block, state_half
 from r2d2_tpu.models.cores.conv_attn_moe import Part, state_layout
 from tests.test_cores import (TINY_CONV_CORE, TINY_ENV, inputs,
                               tiny_config, tiny_net)
+from tests.test_moe_way_back import WALKS, check_walk
 
 KIND = "conv_attn_moe"
 
@@ -272,3 +273,12 @@ def test_cli_train_trains_and_acts_with_the_benchmarks_overrides(tmp_path):
     (core_block,) = [r["core"] for r in records if "core" in r]
     assert [p["kind"] for p in core_block["parts"]] == [
         "conv_state", "key_value_window"]
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_expert_layer_and_gradients_are_the_references_however_the_walk_fills(
+        monkeypatch, walk):
+    """This core's expert layer (no shared expert, 1e-6 in the weights'
+    normalisation) against ``benchmarks/reference/r2d2_lfm2.py``, through
+    the cases of the held experts' walk (``tests/test_moe_way_back.py``)."""
+    check_walk("conv_attn_moe", monkeypatch, walk)
