@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from r2d2_tpu.config import CoreConfig
+from r2d2_tpu.ops import pallas_kernels
 from r2d2_tpu.ops.pallas_kernels import add_rows, sum_rows
 
 # every core builds its modules under this scope (the module's name, the
@@ -149,23 +150,21 @@ def route(scores, bias, top_k: int, scale: float, eps: float):
     return chosen, weights
 
 
-def grouped_matmul(rows, weights, group_sizes, dtype, out_dtype=None):
+def grouped_matmul(rows, weights, group_sizes, dtype, out_dtype=None,
+                   transposed=False):
     """Rows sorted by group times their group's matrix: rows (M, k),
-    weights (G, k, n), ``group_sizes`` (G,) -> (M, n): ``dtype`` operands
-    accumulated in float32, the result in ``out_dtype`` (``dtype`` where
-    none is given); rows past the groups' total are undefined.
-    ``jax.lax.ragged_dot``: XLA's own grouped product on the TPU (PERF.md,
-    Findings, PR 27 says why not the megablox kernels)."""
-    return jax.lax.ragged_dot(rows.astype(dtype), weights.astype(dtype),
-                              group_sizes, preferred_element_type=_F32
-                              ).astype(out_dtype or dtype)
-
-
-# (M, k) x (M, n) -> (G, k, n): the rows are the contracted axis, cut into
-# the groups (what XLA's own backward of ``ragged_dot`` asks for)
-_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())),
-    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    weights (G, k, n), or (G, n, k) ``transposed`` (contracted over their
+    last axis, so the weights as they are kept serve the backward too),
+    ``group_sizes`` (G,) -> (M, n): ``dtype`` operands accumulated in
+    float32, the result in ``out_dtype`` (``dtype`` where none is given);
+    rows past the groups' total are undefined. On a TPU the grouped product
+    of ``ops/pallas_kernels.py``, whose row tile fits groups of some hundred
+    rows; elsewhere, for shapes that kernel does not tile and for calls of
+    few rows (the overflow chunks, acting) ``jax.lax.ragged_dot`` (PERF.md,
+    Findings, PR 37)."""
+    return pallas_kernels.grouped_matmul(
+        rows.astype(dtype), weights.astype(dtype), group_sizes, transposed,
+        out_dtype or dtype)
 
 
 def grouped_outer(rows, cots, group_sizes, dtype):
@@ -173,9 +172,8 @@ def grouped_outer(rows, cots, group_sizes, dtype):
     transposed times its rows of ``cots`` (M, n) -> (G, k, n), ``dtype``
     operands, float32 accumulated and float32 out: a sum over pairs is kept
     in float32, never rounded on its way to the optimizer."""
-    return jax.lax.ragged_dot_general(
-        rows.astype(dtype), cots.astype(dtype), group_sizes,
-        _ROWS_CONTRACTED, preferred_element_type=_F32)
+    return pallas_kernels.grouped_outer(rows.astype(dtype),
+                                        cots.astype(dtype), group_sizes)
 
 
 def _activation(gu):
@@ -198,25 +196,28 @@ def _chunk_ffn(x, weight, gate_up, down, sizes, live):
     return jnp.where(live, out * weight[:, None], 0).astype(x.dtype)
 
 
-def _chunk_ffn_back(x, weight, gate_up, gate_up_t, down_t, sizes, live, g):
+def _chunk_ffn_back(x, weight, gate_up, down, sizes, live, g):
     """``_chunk_ffn``'s backward for the rows' gradient ``g``: (the rows'
     gradient in ``x``'s dtype, the routing weights' (rows,) float32, and
     the two weights' gradients in float32, as the grouped products
-    accumulate them). ``gate_up_t`` (G, 2f, d) and ``down_t`` (G, d, f) are
-    the weights transposed. It recomputes the activation, not the second
-    product: with t = g times ``down_t``, a row's routing weight has the
-    gradient t . act and its activation weight x t."""
+    accumulate them). The two products against the weights transposed
+    contract the weights as they are kept over their last axis: no
+    transposed copy is made. It recomputes the activation, not the second
+    product: with t = g times ``down`` transposed, a row's routing weight
+    has the gradient t . act and its activation weight x t."""
     dt = x.dtype
     x, g = jnp.where(live, x, 0), jnp.where(live, g, 0)
     with jax.named_scope("moe_experts"):
         gu = grouped_matmul(x, gate_up, sizes, dt)
         act, middle_back = jax.vjp(_activation, gu)
-        t = jnp.where(live, grouped_matmul(g, down_t, sizes, dt, _F32), 0)
+        t = jnp.where(live, grouped_matmul(g, down, sizes, dt, _F32, True),
+                      0)
         d_weight = jnp.sum(t * act.astype(_F32), axis=-1)
         d_down = grouped_outer(act, g.astype(_F32) * weight[:, None], sizes,
                                dt)
         (d_gu,) = middle_back((t * weight[:, None]).astype(dt))
-        d_x = jnp.where(live, grouped_matmul(d_gu, gate_up_t, sizes, dt), 0)
+        d_x = jnp.where(live, grouped_matmul(d_gu, gate_up, sizes, dt,
+                                             transposed=True), 0)
         d_gate_up = grouped_outer(x, d_gu, sizes, dt)
     return d_x, d_weight, d_gate_up, d_down
 
@@ -240,22 +241,23 @@ def _chunk_of(lo, rows: int, h, order, pair_weight, group_sizes):
 
 
 # The first chunk's room over the pairs a layer expects, and the tile its
-# rows are rounded up to. The tile is the grouped product's (XLA's Mosaic
-# kernel works on 512 rows at a time): it takes 6,656 rows (13 tiles) in less
-# time than 6,400 and a third less than 6,528 (0.177 | 0.199 | 0.268 us a row
-# in the first product at the moonlight-core cell's widths; 8,704 | 8,448 |
-# 8,576 rows read the same at the lfm2-core cell's). The margin: a centred
-# router's layers hold up to 6% more than the expectation as their mean over
-# a run (it moves with the seed) and 1% more or less from step to step, the
-# first overflow chunk costs a layer 4.3 | 5.0 ms forward and backward (its
-# program's own accumulators and their add) and a tile more of first chunk
-# 0.3 ms, so the margin is worth its rows as soon as one layer-pass in
-# fourteen would run over; with the tile, 5% makes 6,656 rows of 6,000
-# expected and 8,704 of 8,000, and no layer of 160 steps of either cell ran
-# over (my chip runs, PR 34: one layer alone and the cells; PERF.md,
-# Findings).
-FIRST_CHUNK_MARGIN = 0.05
-ROW_TILE = 512
+# rows are rounded up to. The tile is the grouped product's row tile
+# (``ops/pallas_kernels.py``: 256 rows, which the cells' groups of 750 | 1,000
+# rows fill about 0.76 | 0.80 full where tiles of 512 would be 0.60 | 0.66
+# full; PERF.md, Findings, PR 37), so every chunk is whole tiles. The margin: a
+# centred router's layers hold up to 6% more than the expectation as their
+# mean over a run (it moves with the seed) and 1% more or less from step to
+# step; the first overflow chunk costs a layer 4 to 5 ms forward and backward
+# (its program's own accumulators and their add), a tile more of first chunk
+# a tenth of that, the products passing over the tiles that no pair reached;
+# so the margin is worth its rows as soon as one layer-pass in some twenty
+# would run over. With tiles of 256, 8% makes 6,656 rows of 6,000 expected and
+# 8,704 of 8,000, the rows PR 34 fitted at tiles of 512 and 5%, which no
+# layer of 160 steps of either cell ran over (the largest step held 6,483 |
+# 8,349 pairs: a tile less, 6,400 | 8,448, would have run over in the one
+# cell and come within 100 rows of it in the other).
+FIRST_CHUNK_MARGIN = 0.08
+ROW_TILE = pallas_kernels.GROUPED_ROW_TILE
 
 
 def first_chunk_rows(positions: int, core: CoreConfig) -> int:
@@ -346,7 +348,6 @@ def _held_experts_bwd(first, chunk, res, g):
     h, order, pair_weight, gate_up, down, group_sizes = res
     kept = gate_up.dtype, down.dtype
     gate_up, down = gate_up.astype(h.dtype), down.astype(h.dtype)
-    gate_up_t, down_t = jnp.swapaxes(gate_up, 1, 2), jnp.swapaxes(down, 1, 2)
 
     def one(lo, rows, dh, dw):
         pairs, pos, x, w, sizes, live = _chunk_of(lo, rows, h, order,
@@ -354,8 +355,8 @@ def _held_experts_bwd(first, chunk, res, g):
         with jax.named_scope("moe_combine"):
             # a row's gradient is its position's
             gi = g[pairs % g.shape[0]].astype(h.dtype)
-        dxi, dwi, dw1, dw2 = _chunk_ffn_back(x, w, gate_up, gate_up_t, down_t,
-                                             sizes, live, gi)
+        dxi, dwi, dw1, dw2 = _chunk_ffn_back(x, w, gate_up, down, sizes, live,
+                                             gi)
         with jax.named_scope("moe_dispatch"):
             dh = (sum_rows(dxi, pos, h.shape[0]) if dh is None
                   else add_rows(dh, dxi, pos))
@@ -452,7 +453,11 @@ class HeldExperts(nn.Module):
 class RoutedMoE(nn.Module):
     """An expert layer's feed-forward half on the residual stream ``x``
     (B, T, d) with its norm's weight: router, held experts, the shared
-    expert where there is one, and the layer's counters. A core's file
+    expert where there is one, and the layer's counters (the router's, the
+    walk's, and ``tile_rows``, the rows of the row tiles a grouped product
+    visits for the held experts' groups, ``ROW_TILE`` x the visits, a tile
+    that two experts' pairs share counted for each: the pairs here over it
+    is how full the MXU's row tiles are). A core's file
     fixes what its source fixes in a subclass: ``topk_eps``, ``shared``
     (the shared expert is ``n_shared_experts`` experts wide), ``out_init``
     and ``chunk_rows``."""
@@ -503,9 +508,15 @@ class RoutedMoE(nn.Module):
         out = routed.reshape(b, t, d)
         with jax.named_scope("moe_router"):
             share = scores / scores.sum(-1, keepdims=True)
+            histogram = jnp.sum(jax.nn.one_hot(
+                chosen, c.n_routed_experts, dtype=jnp.int32), axis=(0, 1))
             stats = {
-                "chosen": jnp.sum(jax.nn.one_hot(
-                    chosen, c.n_routed_experts, dtype=jnp.int32), axis=(0, 1)),
+                "chosen": histogram,
+                # the rows of the row tiles a grouped product visits for
+                # the held experts' groups: from their sizes and the tile
+                "tile_rows": pallas_kernels.tile_rows_visited(
+                    histogram[c.expert_offset:
+                              c.expert_offset + c.experts_held], ROW_TILE),
                 "entropy": -jnp.mean(jnp.sum(share * jnp.log(share + 1e-30),
                                              axis=-1)),
                 "input_mean": mean,
@@ -517,8 +528,9 @@ class RoutedMoE(nn.Module):
 def moe_counters(mutated: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
     """The stack's sown counters out of ``apply(..., mutable=['moe'])``'s
     second result: {chosen (L_moe, routed), entropy (L_moe,), dropped
-    (L_moe,), rows_walked (L_moe,), overflow_chunks (L_moe,), input_mean
-    (L_moe, hidden)}, or {} for a stack without expert layers."""
+    (L_moe,), rows_walked (L_moe,), overflow_chunks (L_moe,), tile_rows
+    (L_moe,), input_mean (L_moe, hidden)}, or {} for a stack without expert
+    layers."""
     found = jax.tree_util.tree_leaves(
         mutated.get("moe", {}), is_leaf=lambda x: isinstance(x, tuple))
     return found[0][0] if found else {}

@@ -786,3 +786,405 @@ def sum_rows(rows: jnp.ndarray, pos: jnp.ndarray,
         rows, pos,
         tpu=functools.partial(sum_rows_pallas, positions=positions),
         default=functools.partial(sum_rows_reference, positions=positions))
+
+
+# ---------------------------------------------------------------------------
+# Grouped products (the held experts' SwiGLU of the cores that route,
+# models/cores/experts.py: rows sorted by expert, each group of rows times
+# its expert's matrix, and the weights' gradient back).
+
+# What the blocks of a grouped product may take of a v5e's 128 MiB of VMEM:
+# the pipeline's two buffers of each block (``_grouped_vmem`` adds the
+# product's float32 result and room for Mosaic's own).
+_GROUPED_VMEM_BYTES = 64 * 2**20
+# Rows of a grouped product's row tile, and of the tile of a call whose rows
+# are no multiple of it (acting's 384 pairs at 64 lanes).
+GROUPED_ROW_TILE = 256
+# Parts a row tile that groups share is cut into: a visit takes only the
+# parts that hold rows of its group.
+_GROUPED_PARTS = 2
+# Rows from which a call takes the kernel. Its code is larger than that of
+# XLA's product, and every call site's is traced, lowered and loaded at each
+# start whether it ever runs: with the kernel at all 140 sites of a cell's
+# step the executable was 460 MB against 394 and the warm set-up 8 s longer
+# (my chip runs, PR 37). The walk's overflow chunks (1,024 rows, in loops and
+# branches that no logged step has entered) and acting's chunk (256 rows) are
+# most of the sites and little of the work: they keep XLA's product.
+_GROUPED_MIN_ROWS = 2048
+# Columns of a block that one product inside the kernel takes
+# (``_each_column_chunk``).
+_GROUPED_COLUMNS = 512
+_GROUPED_ROW_TILES = (GROUPED_ROW_TILE, GROUPED_ROW_TILE // 2)
+# (M, k) x (M, n) -> (G, k, n): the rows are the contracted axis, cut into
+# the groups (what XLA's own backward of ``ragged_dot`` asks for)
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def grouped_matmul_reference(rows, weights, group_sizes, transposed=False,
+                             out_dtype=None):
+    """The ``jax.lax`` twin of ``grouped_matmul_pallas``."""
+    if transposed:
+        weights = jnp.swapaxes(weights, 1, 2)
+    return jax.lax.ragged_dot(rows, weights, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(out_dtype or rows.dtype)
+
+
+def grouped_outer_reference(rows, cots, group_sizes):
+    """The ``jax.lax`` twin of ``grouped_outer_pallas``."""
+    return jax.lax.ragged_dot_general(
+        rows, cots, group_sizes, _ROWS_CONTRACTED,
+        preferred_element_type=jnp.float32)
+
+
+def _running_sum(counts):
+    """The inclusive running sum of a few int32 counts, as one sum over a
+    (n, n) table: a scan of eight numbers is a loop of its own in the TPU's
+    program, and XLA fuses nothing into it."""
+    index = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(index[None, :] <= index[:, None],
+                             counts[None, :], 0), axis=1)
+
+
+def group_visits(group_sizes, rows: int, tile: int, outer: bool = False):
+    """The walk of a grouped product over ``rows`` sorted rows in tiles of
+    ``tile``: (tile, group, first row, end row) of each visit and the visits'
+    number, each (visits,) int32 but the last, (1,). A tile is visited once
+    for every group that has rows in it, in the rows' order, with that
+    group's rows (``[first, end)``, numbered over all rows: the visit's are
+    those of them that lie in its tile); so a tile that two groups share is
+    visited twice and ``rows // tile + groups - 1`` visits always suffice.
+    The visits past the last repeat it, so a step that makes one of them
+    moves no block. Where the product writes rows (``outer`` false) the
+    tiles wholly past the groups' total are visited once each, for no rows:
+    they are written as zeros. Where it writes a block a group (``outer``) a
+    group without rows is visited once, for no rows, and those tiles are
+    not."""
+    groups, tiles = group_sizes.shape[0], rows // tile
+    # a few dozen visits of a few groups: sums over small tables in place of
+    # scans, searches and gathers, so that XLA makes a few small fusions of
+    # the walk and merges the walks of the products of one chunk
+    ends = jnp.minimum(_running_sum(group_sizes.astype(jnp.int32)), rows)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    first = starts // tile
+    count = jnp.where(ends > starts, (ends - 1) // tile - first + 1,
+                      int(outer))
+    before = _running_sum(count)
+    live_tiles = (ends[-1] + tile - 1) // tile
+    total = before[-1] + (0 if outer else tiles - live_tiles)
+    visit = jnp.minimum(jnp.arange(tiles + groups - 1, dtype=jnp.int32),
+                        total - 1)
+    group = jnp.sum(visit[:, None] >= before[None, :], axis=1,
+                    dtype=jnp.int32)
+    past = group >= groups              # a tile past the groups' total
+    group = jnp.minimum(group, groups - 1)
+    mine = group[:, None] == jnp.arange(groups, dtype=jnp.int32)[None, :]
+
+    def of_group(values):
+        return jnp.sum(jnp.where(mine, values[None, :], 0), axis=1)
+
+    at = jnp.where(past, live_tiles + visit - before[-1],
+                   of_group(first) + visit - of_group(before - count))
+    return (jnp.clip(at, 0, tiles - 1), group,
+            jnp.where(past, 0, of_group(starts)),
+            jnp.where(past, 0, of_group(ends)), total[None])
+
+
+def tile_rows_visited(group_sizes, tile: int):
+    """Rows of the row tiles a grouped product visits for these groups at
+    tiles of ``tile`` rows (visits x tile; a tile two groups share counts
+    twice): the pairs over it is how full the MXU's row tiles are."""
+    sizes = group_sizes.astype(jnp.int32)
+    ends = _running_sum(sizes)
+    return tile * jnp.sum(jnp.where(
+        sizes > 0, (ends - 1) // tile - (ends - sizes) // tile + 1, 0))
+
+
+def _shared_tile_parts(tm: int, base, lo, hi):
+    """The parts a row tile of ``tm`` rows from row ``base`` on is cut into
+    where groups share it: of each, (its rows' slice of the tile, which of
+    them lie in ``[lo, hi)`` (rows, 1), whether any does)."""
+    rows = tm // _GROUPED_PARTS if tm % (8 * _GROUPED_PARTS) == 0 else tm
+    for at in range(0, tm, rows):
+        row = base + at + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        yield (slice(at, at + rows), (row >= lo) & (row < hi),
+               (hi > base + at) & (lo < base + at + rows))
+
+
+def _each_column_chunk(columns: int, body) -> None:
+    """``body(chunk)`` for the chunks of ``_GROUPED_COLUMNS`` columns of a
+    block ``columns`` wide, in a loop, and for what is left over: the
+    kernel's code is one chunk's product and not the whole block's, which
+    Mosaic unrolls: with whole blocks a cell's step program, 140 such calls,
+    was 736 MB where the parent's was 394 (CPU, count) and took 12 s longer
+    to load (my chip runs, PR 37); with chunks of 512 columns it is 460 MB
+    and the nine products of a layer cost 5% more (1,024: 1-3%, 256: 7-8%)."""
+    from jax.experimental import pallas as pl
+    whole = columns // _GROUPED_COLUMNS
+
+    def step(j, carry):
+        body(pl.ds(pl.multiple_of(j * _GROUPED_COLUMNS, _GROUPED_COLUMNS),
+                   _GROUPED_COLUMNS))
+        return carry
+
+    if whole:
+        jax.lax.fori_loop(0, whole, step, 0)
+    if columns % _GROUPED_COLUMNS:
+        body(slice(whole * _GROUPED_COLUMNS, columns))
+
+
+def _grouped_vmem(result: int, *blocks) -> int:
+    """The pipeline's two buffers of every block, the product's float32
+    ``result`` and room for Mosaic's own."""
+    return 2 * sum(blocks) + result + 4 * 2**20
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "transposed", "out_dtype", "tiles", "interpret"))
+def grouped_matmul_pallas(rows, weights, group_sizes, transposed=False,
+                          out_dtype=None, tiles=None, interpret=False):
+    """Rows sorted by group times their group's matrix: rows (M, k), weights
+    (G, k, n), or (G, n, k) ``transposed`` (contracted over their last axis:
+    the weights as they are kept serve the backward), ``group_sizes`` (G,) ->
+    (M, n) in ``out_dtype`` (the rows' where none is given), accumulated in
+    float32; rows past the groups' total are zeros.
+
+    ``tiles`` = (row tile, column tile). The grid is (column tiles, visits),
+    the visits of ``group_visits`` innermost: a step takes one row tile whole
+    in k and its group's (k, column tile) block of the weights, whose block
+    index stays while that group's row tiles pass, so the weights are read
+    once a column tile and the rows once for each. A tile that lies inside
+    one group is one product and one store; one that groups share is visited
+    for each, and keeps the other groups' rows as they are (the block stays
+    in VMEM between visits that follow each other)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (m, k), groups = rows.shape, weights.shape[0]
+    n = weights.shape[1 if transposed else 2]
+    out_dtype = jnp.dtype(out_dtype or rows.dtype)
+    tm, tn = tiles or grouped_tiles(m, k, n, rows.dtype.itemsize,
+                                    out_dtype.itemsize)
+    assert m % tm == 0 and n % tn == 0, (m, n, tm, tn)
+    walk = group_visits(group_sizes, m, tm)
+    contract = (((1,), (1 if transposed else 0,)), ((), ()))
+
+    def kernel(tile_ref, group_ref, lo_ref, hi_ref, total_ref, x_ref, w_ref,
+               o_ref):
+        v = pl.program_id(1)
+        tile, lo, hi = tile_ref[v], lo_ref[v], hi_ref[v]
+        base = tile * tm
+        real = v < total_ref[0]
+        whole = (lo <= base) & (hi >= base + tm)
+
+        def product(part, columns):
+            weights = w_ref[columns, :] if transposed else w_ref[:, columns]
+            return jax.lax.dot_general(
+                x_ref[part, :], weights, contract,
+                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+        @pl.when(real & whole)
+        def _():
+            def write(columns):
+                o_ref[:, columns] = product(slice(None), columns)
+            _each_column_chunk(tn, write)
+
+        @pl.when(real & jnp.logical_not(whole))
+        def _():
+            # a tile that groups share, or that no group reaches: of each
+            # part of it that holds rows of this group, those rows; the
+            # others keep what an earlier visit wrote, or are zeros at the
+            # tile's first
+            first = (v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != tile)
+            for part, mine, reached in _shared_tile_parts(tm, base, lo, hi):
+                @pl.when(reached)
+                def _():
+                    def write(columns):
+                        kept = o_ref[part, columns]
+                        kept = jnp.where(first, jnp.zeros_like(kept), kept)
+                        o_ref[part, columns] = jnp.where(
+                            mine, product(part, columns), kept)
+                    _each_column_chunk(tn, write)
+
+                @pl.when(jnp.logical_not(reached) & first)
+                def _():
+                    o_ref[part, :] = jnp.zeros_like(o_ref[part, :])
+
+    w_block = ((None, tn, k) if transposed else (None, k, tn))
+    w_index = ((lambda j, v, t, g, *_: (g[v], j, 0)) if transposed
+               else (lambda j, v, t, g, *_: (g[v], 0, j)))
+    size = rows.dtype.itemsize
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n // tn, m // tm + groups - 1),
+            in_specs=[pl.BlockSpec((tm, k), lambda j, v, t, *_: (t[v], 0)),
+                      pl.BlockSpec(w_block, w_index)],
+            out_specs=pl.BlockSpec((tm, tn), lambda j, v, t, *_: (t[v], j))),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_grouped_vmem(
+                tm * tn * 4, tm * k * size, k * tn * size,
+                tm * tn * out_dtype.itemsize)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(n // tn * m * k + groups * k * n) * size
+            + m * n * out_dtype.itemsize),
+        name="grouped_matmul",
+        interpret=interpret,
+    )(*walk, rows, weights)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def grouped_outer_pallas(rows, cots, group_sizes, tiles=None,
+                         interpret=False):
+    """The gradient of a grouped product's weights: each group's rows (M, k)
+    transposed times its rows of ``cots`` (M, n) -> (G, k, n) float32, as the
+    MXU accumulates it; a group without rows gives zeros.
+
+    ``tiles`` = (row tile, column tile). The grid is (column tiles, visits):
+    a step takes one row tile of both operands and adds its product to its
+    group's (k, column tile) block of the result, which stays in VMEM while
+    that group's row tiles pass and is written once; of a tile that groups
+    share each visit takes its own group's rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (m, k), n, groups = rows.shape, cots.shape[1], group_sizes.shape[0]
+    tm, tn = tiles or grouped_tiles(m, k, n, rows.dtype.itemsize, 4,
+                                    outer=True)
+    assert m % tm == 0 and n % tn == 0, (m, n, tm, tn)
+    walk = group_visits(group_sizes, m, tm, outer=True)
+
+    def kernel(tile_ref, group_ref, lo_ref, hi_ref, total_ref, x_ref, c_ref,
+               o_ref):
+        v = pl.program_id(1)
+        lo, hi = lo_ref[v], hi_ref[v]
+        base = tile_ref[v] * tm
+        real = v < total_ref[0]
+
+        @pl.when((v == 0)
+                 | (group_ref[jnp.maximum(v - 1, 0)] != group_ref[v]))
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        def add(x, part, mine=None):
+            def to(columns):
+                c = c_ref[part, columns]
+                if mine is not None:
+                    c = jnp.where(mine, c, jnp.zeros_like(c))
+                o_ref[:, columns] += jax.lax.dot_general(
+                    x, c, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            _each_column_chunk(tn, to)
+
+        whole = (lo <= base) & (hi >= base + tm)
+
+        @pl.when(real & whole)
+        def _():
+            add(x_ref[...], slice(None))
+
+        @pl.when(real & jnp.logical_not(whole))
+        def _():
+            # a tile that groups share: this group's rows of each part of
+            # it that holds any
+            for part, mine, reached in _shared_tile_parts(tm, base, lo, hi):
+                @pl.when(reached)
+                def _():
+                    x = x_ref[part, :]
+                    add(jnp.where(mine, x, jnp.zeros_like(x)), part, mine)
+
+    size = rows.dtype.itemsize
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n // tn, m // tm + groups - 1),
+            in_specs=[pl.BlockSpec((tm, k), lambda j, v, t, *_: (t[v], 0)),
+                      pl.BlockSpec((tm, tn), lambda j, v, t, *_: (t[v], j))],
+            out_specs=pl.BlockSpec((None, k, tn),
+                                   lambda j, v, t, g, *_: (g[v], 0, j))),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_grouped_vmem(
+                k * tn * 4, tm * k * size, tm * tn * size, k * tn * 4)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(n // tn * m * k + m * n) * size
+            + groups * k * n * 4),
+        name="grouped_outer",
+        interpret=interpret,
+    )(*walk, rows, cots)
+
+
+def grouped_tiles(m: int, k: int, n: int, itemsize: int, out_itemsize: int,
+                  outer: bool = False):
+    """(row tile, column tile) of a grouped product of (m, k) rows by
+    (k, n) matrices (``outer``: of the (k, n) float32 result a group), from
+    the shapes and the operands' bytes alone, or None where the kernel does
+    not tile them (the caller then takes ``jax.lax.ragged_dot``): rows that
+    are no multiple of a row tile, a k or n that does not fill the lanes,
+    blocks that ``_GROUPED_VMEM_BYTES`` does not hold.
+
+    The row tile is 256 rows, 128 where the rows are no multiple of 256
+    (my chip runs, PR 37, the products alone at both cells' shapes, groups
+    of ~730 | ~975 rows: 256 and 128 read within 3% of each other, 512
+    10-20% slower: with groups this small nearly every tile of 512 is
+    shared). The contraction is whole in one step, and the column tile the
+    widest divisor of n in whole lanes whose blocks fit: the block that
+    stays in VMEM while a group's row tiles pass (the group's weights, or
+    its float32 result) is then fetched or written once, and the rows are
+    read once for every column tile (the widest read fastest: the first
+    product 0.548 ms at 2,816 columns, 0.567 at 1,408, 0.66 and more at 256
+    and 512; XLA's kernel 1.121)."""
+    tm = next((t for t in _GROUPED_ROW_TILES if m % t == 0), None)
+    if tm is None or k % 128 or n % 128:
+        return None
+    for tn in range(n, 0, -128):
+        blocks = (tm * k * itemsize
+                  + (k * tn * 4 + tm * tn * itemsize if outer
+                     else k * tn * itemsize + tm * tn * out_itemsize))
+        if n % tn == 0 and 2 * blocks <= _GROUPED_VMEM_BYTES:
+            return tm, tn
+    return None
+
+
+def grouped_matmul(rows, weights, group_sizes, transposed=False,
+                   out_dtype=None):
+    """Rows (M, k) sorted by group times their group's matrix of ``weights``
+    (G, k, n), or (G, n, k) ``transposed``, -> (M, n) in ``out_dtype``,
+    accumulated in float32; the rows past the groups' total are undefined.
+    The Pallas kernel in a program lowered for a TPU where the shapes give
+    it tiles (``grouped_tiles``) and there are ``_GROUPED_MIN_ROWS`` rows or
+    more, ``jax.lax.ragged_dot`` in one lowered for anything else and for
+    the other shapes."""
+    out_dtype = jnp.dtype(out_dtype or rows.dtype)
+    n = weights.shape[1 if transposed else 2]
+    tiles = grouped_tiles(*rows.shape, n, rows.dtype.itemsize,
+                          out_dtype.itemsize)
+    reference = functools.partial(grouped_matmul_reference,
+                                  transposed=transposed, out_dtype=out_dtype)
+    if tiles is None or rows.shape[0] < _GROUPED_MIN_ROWS:
+        return reference(rows, weights, group_sizes)
+    return jax.lax.platform_dependent(
+        rows, weights, group_sizes, default=reference,
+        tpu=functools.partial(grouped_matmul_pallas, transposed=transposed,
+                              out_dtype=out_dtype, tiles=tiles))
+
+
+def grouped_outer(rows, cots, group_sizes):
+    """Each group's rows (M, k) transposed times its rows of ``cots`` (M, n)
+    -> (G, k, n) float32: the Pallas kernel or ``jax.lax.ragged_dot_general``
+    as ``grouped_matmul`` chooses."""
+    tiles = grouped_tiles(*rows.shape, cots.shape[1], rows.dtype.itemsize, 4,
+                          outer=True)
+    if tiles is None or rows.shape[0] < _GROUPED_MIN_ROWS:
+        return grouped_outer_reference(rows, cots, group_sizes)
+    return jax.lax.platform_dependent(
+        rows, cots, group_sizes, default=grouped_outer_reference,
+        tpu=functools.partial(grouped_outer_pallas, tiles=tiles))

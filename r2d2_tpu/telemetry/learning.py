@@ -270,8 +270,13 @@ class MoeAggregator:
     ``overflow_chunks`` x the overflow chunk's rows, summed over the steps):
     ``pairs_held`` over it is how full the walk was, and it over steps x
     all pairs the share of the pairs' rows that the experts' way there and
-    back touched; and ``overflow_chunks``, the chunks walked past the first:
-    over the steps, how often the first chunk's margin was too small."""
+    back touched; ``overflow_chunks``, the chunks walked past the first:
+    over the steps, how often the first chunk's margin was too small; and
+    ``tile_rows``, the rows of the row tiles the grouped products visited
+    for the held experts' groups (visits x the kernel's row tile, a tile
+    that two experts share counted for each; from the groups' sizes alone,
+    summed over the steps): ``pairs_held`` over it is how full the MXU's row
+    tiles were, the number that says whether the tile fits the groups."""
 
     def __init__(self, core):
         self.held = slice(core.expert_offset,
@@ -302,9 +307,10 @@ class MoeAggregator:
         sums, steps = jax.device_get(self._sums), self._steps
         self._sums, self._steps = None, 0
         layers = []
-        for chosen, entropy, dropped, walked, overflow in zip(
+        for chosen, entropy, dropped, walked, overflow, tile_rows in zip(
                 sums["chosen"], sums["entropy"], sums["dropped"],
-                sums["rows_walked"], sums["overflow_chunks"]):
+                sums["rows_walked"], sums["overflow_chunks"],
+                sums["tile_rows"]):
             held = chosen[self.held]
             layers.append({
                 "chosen_hist": [int(c) for c in chosen],
@@ -315,6 +321,7 @@ class MoeAggregator:
                 "dropped": int(dropped),
                 "rows_walked": int(walked),
                 "overflow_chunks": int(overflow),
+                "tile_rows": int(tile_rows),
             })
         return {"steps": steps, "layers": layers}
 

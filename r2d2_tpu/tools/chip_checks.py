@@ -186,6 +186,72 @@ def run_chip_checks(only: str = "") -> int:
     add("add_rows_n8000_r1024", add_rows(8000, 1024, jnp.bfloat16))
     add("add_rows_n8000_r12800_sliced", add_rows(8000, 12800, jnp.bfloat16))
 
+    # --- the held experts' grouped products (ISSUE 37), the three forms at
+    # the cells' first chunks (6,656 x 2,048 x 2,816, 8,704 x 2,048 x 3,584:
+    # the first product, the one against the weights' last axis, the
+    # weights' gradient), the second product's float32 out, an overflow
+    # chunk's 1,024 rows and acting's 384, through the tiles the shapes
+    # give (``grouped_tiles``) against ``jax.lax.ragged_dot``: bf16 operands,
+    # groups 0.9 full that end inside tiles, one of them empty
+    def grouped(form, rows, k, n, out_dtype=jnp.bfloat16):
+        def check():
+            rng = fresh_rng()
+            from r2d2_tpu.ops import pallas_kernels as pk
+            share = rng.dirichlet(np.full(7, 60.0))
+            sizes = np.floor(share * 0.9 * rows).astype(np.int32)
+            sizes = jnp.asarray(np.insert(sizes, 3, 0), jnp.int32)
+            live = int(sizes.sum())
+            x = jnp.asarray(rng.standard_normal((rows, k)), jnp.bfloat16)
+            if form == "outer":
+                cots = jnp.asarray(rng.standard_normal((rows, n)),
+                                   jnp.bfloat16)
+                assert pk.grouped_tiles(rows, k, n, 2, 4, True)
+                got = pk.grouped_outer_pallas(x, cots, sizes)
+                want = pk.grouped_outer_reference(x, cots, sizes)
+                assert not np.asarray(got[3]).any()
+            else:
+                last = form == "weights_last"
+                w = jnp.asarray(rng.standard_normal(
+                    (8, n, k) if last else (8, k, n)) * 0.02, jnp.bfloat16)
+                assert pk.grouped_tiles(rows, k, n, 2,
+                                        jnp.dtype(out_dtype).itemsize)
+                got = pk.grouped_matmul_pallas(x, w, sizes, transposed=last,
+                                               out_dtype=out_dtype)
+                want = pk.grouped_matmul_reference(x, w, sizes, last,
+                                                   out_dtype)
+                assert got.dtype == out_dtype
+                assert not np.asarray(got[live:], np.float32).any()
+                got, want = got[:live], want[:live]
+            # float32 sums of k bf16 products in another order, then (the
+            # rows' forms) one rounding to bf16 on either side
+            got, want = (np.asarray(a, np.float32) for a in (got, want))
+            scale = float(np.abs(want).max())
+            np.testing.assert_allclose(
+                got, want, rtol=0,
+                atol=scale * (2e-2 if out_dtype == jnp.bfloat16 else 1e-4))
+        return check
+    for cell, rows, width in (("moonlight", 6656, 1408),
+                              ("lfm2", 8704, 1792)):
+        add(f"grouped_{cell}_gate_up_r{rows}",
+            grouped("weights", rows, 2048, 2 * width))
+        add(f"grouped_{cell}_down_f32_r{rows}",
+            grouped("weights", rows, width, 2048, jnp.float32))
+        add(f"grouped_{cell}_down_last_axis_r{rows}",
+            grouped("weights_last", rows, 2048, width, jnp.float32))
+        add(f"grouped_{cell}_gate_up_last_axis_r{rows}",
+            grouped("weights_last", rows, 2 * width, 2048))
+        add(f"grouped_{cell}_outer_gate_up_r{rows}",
+            grouped("outer", rows, 2048, 2 * width))
+        add(f"grouped_{cell}_outer_down_r{rows}",
+            grouped("outer", rows, width, 2048))
+    add("grouped_overflow_chunk_r1024",
+        grouped("weights", 1024, 2048, 2816))
+    add("grouped_overflow_chunk_outer_r1024",
+        grouped("outer", 1024, 2048, 2816))
+    add("grouped_acting_r384", grouped("weights", 384, 2048, 2816))
+    add("grouped_acting_down_f32_r256",
+        grouped("weights", 256, 1792, 2048, jnp.float32))
+
     # --- quantized acting forward (ISSUE 14): compile + parity ----------
     def quant_forward():
         rng = fresh_rng()

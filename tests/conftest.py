@@ -25,7 +25,7 @@ def rng():
 
 
 # sha256 of the StableHLO text of moonlight-core's tiny twin's fused learner
-# step as this tree lowers it on the CPU (CPU, count, PR 34), where
+# step as this tree lowers it on the CPU (CPU, count, PR 37), where
 # tests/benchmarks/test_bm_lfm2.py holds the value of PR 33's parent. PR 34
 # changed that program on purpose (the held experts' walk: one first chunk,
 # overflow chunks, a backward of its own), the test's comment asks such a PR
@@ -33,9 +33,12 @@ def rng():
 # edit a file under ``tests/benchmarks/``. So the new value stands here and
 # the fixture below hands it to that one test; the ``benchmark`` PR that next
 # touches test_bm_lfm2.py moves it there and takes both out (PERF.md, Open
-# questions). A PR that changes the step again records its value here.
+# questions). A PR that changes the step again records its value here:
+# PR 37 did (the backward's two products against the weights' last axis in
+# place of transposed copies, the ``tile_rows`` counter, a first chunk in
+# tiles of 256 rows at a margin of 8%; PR 34's value was cc540f31...9296e).
 MOONLIGHT_TINY_STEP = \
-    "cc540f31d668dcc73017d3bdfe0d836e1e0e389692d68153ef4df6635ea9296e"
+    "4304645140f23c272503c43641357084a4d046b949c4b9229bdf8425ff784a37"
 
 
 @pytest.fixture(autouse=True)

@@ -12,8 +12,9 @@ held pairs under the first chunk, one pair over it, skewed onto one held
 expert across many overflow chunks, none on a held expert, every expert
 held (this core's cases here, ``conv_attn_moe``'s in
 ``test_core_conv_attn_moe.py``); the structure of the step's jaxpr; the
-``rows_walked`` and ``overflow_chunks`` counters of the record; the first
-chunk's rows at the cells' shapes.
+``rows_walked``, ``overflow_chunks`` and ``tile_rows`` counters of the record;
+the first chunk's rows at the cells' shapes. (The grouped products' kernel:
+``test_grouped_products.py``.)
 
 What they cannot hold: that Mosaic compiles the kernel
 (``tools/chip_checks.py``, ``tests/benchmarks/test_bm_compile_v5e.py``) and
@@ -335,6 +336,11 @@ def test_rows_walked_is_the_first_chunk_and_the_overflow_chunks(
     assert 0 < layer["pairs_held"] <= layer["rows_walked"] <= 3 * (
         first + (chunk and -(-(pairs - first) // chunk) * chunk))
     assert layer["dropped"] == 0
+    # the rows of the row tiles the grouped products visit: whole tiles, the
+    # pairs and at most a tile more for every held expert and step
+    assert layer["tile_rows"] % row_tile == 0
+    assert layer["pairs_held"] <= layer["tile_rows"] <= (
+        layer["pairs_held"] + 3 * core.experts_held * row_tile)
 
 
 def _cell_core(kind, routed, top_k, held=8):
@@ -346,7 +352,9 @@ def _cell_core(kind, routed, top_k, held=8):
     # the cells' learner steps (64 x 125 positions) and acting (64 lanes)
     (8000, _cell_core("mla_moe", 64, 6), FIRST_CHUNK["moonlight-core"]),
     (8000, _cell_core("conv_attn_moe", 32, 4), FIRST_CHUNK["lfm2-core"]),
-    (64, _cell_core("mla_moe", 64, 6), 384),
+    # acting: 48 | 32 pairs expected here, one tile of 256 rows (of 384 | 256
+    # pairs: the former keeps an overflow chunk of the other 128)
+    (64, _cell_core("mla_moe", 64, 6), 256),
     (64, _cell_core("conv_attn_moe", 32, 4), 256),
     # every expert held: every pair is expected
     (8000, _cell_core("conv_attn_moe", 32, 4, 32), 32000),
