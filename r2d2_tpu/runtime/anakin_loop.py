@@ -207,22 +207,20 @@ def run_anakin_train(cfg: Config, *, max_training_steps: Optional[int] = None,
 
     # system-health pillar (ISSUE 7), the on-device twin of the
     # PlayerStack wiring: resource sampler (the Learner registered ring +
-    # train-state footprints; the lane carry registers here), the compile/
-    # retrace monitor, and the alert engine. No actor fleet, so no board
-    # gauges — this process's RSS/CPU is the whole host picture.
+    # train-state footprints; the lane carry registers here) and the alert
+    # engine. No actor fleet, so no board gauges — this process's RSS/CPU
+    # is the whole host picture. The compile/retrace monitor is the one
+    # the Learner installed, bound to this loop's Telemetry: a build's
+    # phases are spans under the stage open on the compiling thread, its
+    # iteration and its call.
     resources = None
-    compile_mon = None
+    compile_mon = learner.compile_monitor
     if cfg.telemetry.enabled and cfg.telemetry.resources_enabled:
-        from r2d2_tpu.telemetry import (AlertEngine, CompileMonitor,
-                                        ResourceMonitor, active_monitor,
+        from r2d2_tpu.telemetry import (AlertEngine, ResourceMonitor,
                                         default_rules)
         from r2d2_tpu.telemetry.resources import (pytree_nbytes,
                                                   register_buffer)
         register_buffer("p0/anakin_carry", pytree_nbytes(carry))
-        if cfg.telemetry.compile_enabled and active_monitor() is None:
-            # a build records a ``compile`` span under the stage open on
-            # the compiling thread: its iteration and its call
-            compile_mon = CompileMonitor(telemetry).install()
         resources = ResourceMonitor(
             0, cfg.runtime.save_dir or ".",
             interval_s=cfg.telemetry.resources_interval_s,
@@ -407,9 +405,5 @@ def run_anakin_train(cfg: Config, *, max_training_steps: Optional[int] = None,
         except Exception:
             import logging
             logging.getLogger(__name__).exception("final checkpoint failed")
-        stack.close()
-        if compile_mon is not None:
-            # restore the pxla logger exactly and release the process-
-            # global active-monitor slot (same contract as PlayerStack)
-            compile_mon.uninstall()
+        stack.close()     # the Learner's stop releases the compile monitor
     return [stack]

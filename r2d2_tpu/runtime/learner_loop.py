@@ -18,6 +18,7 @@ batches over RPC and a train thread running torch ops
     (ref worker.py:368,192-209).
 """
 
+import os
 import queue as queue_mod
 import threading
 import time
@@ -42,6 +43,40 @@ from r2d2_tpu.runtime.metrics import TrainMetrics
 class Learner:
     def __init__(self, cfg: Config, net: NetworkApply, player_idx: int = 0,
                  seed: Optional[int] = None, metrics: Optional[TrainMetrics] = None):
+        """Set-up is a span tree of the process's Telemetry (PR 38): a root
+        ``learner/build`` (``iter="setup"``) over construction, the build-
+        time compiles under its children, then the first dispatch of the
+        step program (``learner/train_dispatch``, ``first=1``) and
+        ``learner/first_ready``, one block on its outputs. A Learner handed
+        no Telemetry (through ``metrics``) builds its own, with no drain
+        thread: its spans go to ``spans_player{p}.jsonl`` as set-up ends
+        and at ``stop_background``. The first Learner of a process installs
+        the compile monitor, bound to that Telemetry; the loop around it
+        takes it over (``compile_monitor``)."""
+        from r2d2_tpu.telemetry import (NULL_TELEMETRY, CompileMonitor,
+                                        Telemetry, active_monitor)
+        self.metrics = metrics or TrainMetrics(player_idx, cfg.runtime.save_dir,
+                                               resume=bool(cfg.runtime.resume))
+        self._own_telemetry = None
+        if self.metrics.telemetry is NULL_TELEMETRY and cfg.telemetry.enabled:
+            self._own_telemetry = Telemetry.from_config(
+                cfg, name=f"learner-p{player_idx}")
+            self._own_telemetry.write_spans_to(
+                os.path.join(cfg.runtime.save_dir or ".",
+                             f"spans_player{player_idx}.jsonl"),
+                append=bool(cfg.runtime.resume))
+            self.metrics.set_telemetry(self._own_telemetry)
+        self.compile_monitor = None
+        if (cfg.telemetry.enabled and cfg.telemetry.compile_enabled
+                and active_monitor() is None):
+            self.compile_monitor = CompileMonitor(self.tele).install()
+        self._first_dispatch = True
+        with self.tele.stage("learner/build", iter="setup"):
+            self._build(cfg, net, player_idx, seed)
+
+    def _build(self, cfg: Config, net: NetworkApply, player_idx: int,
+               seed: Optional[int]) -> None:
+        tele = self.tele
         self.cfg = cfg
         self.net = net
         self.player_idx = player_idx
@@ -49,9 +84,11 @@ class Learner:
         seed = cfg.runtime.seed if seed is None else seed
         key = jax.random.PRNGKey(seed + 1000 * player_idx)
 
-        self.train_state = create_train_state(key, net, cfg.optim)
-        self.train_state, resumed_env_steps = apply_restore(
-            cfg.runtime, self.train_state)
+        with tele.stage("learner/create_train_state"):
+            self.train_state = create_train_state(key, net, cfg.optim)
+        with tele.stage("learner/apply_restore"):
+            self.train_state, resumed_env_steps = apply_restore(
+                cfg.runtime, self.train_state)
         self.host_mode = cfg.replay.placement == "host"
         self.mesh = None
         # learning-dynamics diagnostics (ISSUE 5): a LearningDiag fuses
@@ -220,7 +257,9 @@ class Learner:
                     self.train_state = jax.device_put(
                         self.train_state,
                         state_shardings(self.train_state, self.mesh))
-                self.replay_state = sharded_replay_init(self.spec, self.mesh)
+                with tele.stage("learner/replay_init"):
+                    self.replay_state = sharded_replay_init(self.spec,
+                                                            self.mesh)
                 self._step_fn = make_sharded_learner_step(
                     net, self.spec, cfg.optim, cfg.network.use_double,
                     self.mesh, steps_per_dispatch=self._k, diag=self._diag,
@@ -228,7 +267,8 @@ class Learner:
                 self._sharded_add = make_sharded_replay_add(
                     self.spec, self.mesh)
             else:
-                self.replay_state = replay_init(self.spec)
+                with tele.stage("learner/replay_init"):
+                    self.replay_state = replay_init(self.spec)
                 if self._k > 1:
                     self._step_fn = make_multi_learner_step(
                         net, self.spec, cfg.optim, cfg.network.use_double,
@@ -238,8 +278,6 @@ class Learner:
                         net, self.spec, cfg.optim, cfg.network.use_double,
                         diag=self._diag, rdiag=self._rdiag)
 
-        self.metrics = metrics or TrainMetrics(player_idx, cfg.runtime.save_dir,
-                                               resume=bool(cfg.runtime.resume))
         # what a sequence's stored state row holds (record block 'core', on
         # the run's first record)
         from r2d2_tpu.models.cores import state_block
@@ -879,6 +917,15 @@ class Learner:
             self._bg_threads.append(t)
 
     def stop_background(self, join_timeout: float = 10.0) -> None:
+        try:
+            self._stop_threads(join_timeout)
+        finally:
+            if self._own_telemetry is not None:
+                self._own_telemetry.flush()
+            if self.compile_monitor is not None:
+                self.compile_monitor.uninstall()
+
+    def _stop_threads(self, join_timeout: float) -> None:
         stuck = []
         if self._snap_writer is not None:
             # drain + stop the snapshot writer first: a queued cut still
@@ -1118,7 +1165,9 @@ class Learner:
             # host-side dispatch cost (the device executes
             # asynchronously; device occupancy is what xprof captures
             # measure)
-            with tele.stage("learner/train_dispatch", k=self._k, step=prev):
+            first = {"first": 1} if self._first_dispatch else {}
+            with tele.stage("learner/train_dispatch", k=self._k, step=prev,
+                            **first):
                 if self.host_mode:
                     m = self._host_step_once()
                 elif self.service is not None:
@@ -1126,6 +1175,13 @@ class Learner:
                 else:
                     self.train_state, self.replay_state, m = self._step_fn(
                         self.train_state, self.replay_state)
+            if first:
+                # the one sync of a run's start: the first execution
+                # (the program's load and first run) ends set-up's spans
+                self._first_dispatch = False
+                with tele.stage("learner/first_ready"):
+                    jax.block_until_ready(m)
+                tele.flush_soon()
             self._host_step += self._k
             step = self._host_step
             # scalar (k=1) or (k,) array

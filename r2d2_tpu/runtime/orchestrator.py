@@ -141,7 +141,6 @@ class PlayerStack:
         self.store = None
         self.queue: Optional[BlockQueue] = None
         self.resources = None
-        self.compile_monitor = None
         self.sentinel = None
         # central policy inference service (ISSUE 13): in server mode the
         # stack owns ONE PolicyServer + its endpoint/stats; the endpoint
@@ -248,25 +247,22 @@ class PlayerStack:
                 self.tele_board.close()
                 self.heartbeats.close()
                 raise
-        # system-health pillar (ISSUE 7): resource sampler + compile/
-        # retrace monitor + the alert engine, all behind the
-        # telemetry.resources_enabled kill switch — off, none of the
-        # three exists and the periodic record stays byte-identical to
+        # system-health pillar (ISSUE 7): resource sampler + the alert
+        # engine behind the telemetry.resources_enabled kill switch — off,
+        # neither exists and the periodic record stays byte-identical to
         # the pre-PR7 schema. The Learner registered its buffer
         # footprints during construction above; the sampler reads the
         # shared registry and the actor gauges off the telemetry board.
-        # Compile events are process-global, so only the FIRST stack of a
-        # multiplayer process installs the monitor. Wired LAST (the alert
-        # stream truncation is file I/O): a failure here must unwind the
-        # shm segments allocated above.
+        # Compile events are process-global: the FIRST stack's Learner of
+        # a multiplayer process installed the compile/retrace monitor,
+        # bound to this stack's Telemetry, and this stack takes it over.
+        # Wired LAST (the alert stream truncation is file I/O): a failure
+        # here must unwind the shm segments allocated above.
+        self.compile_monitor = self.learner.compile_monitor
         if cfg.telemetry.enabled and cfg.telemetry.resources_enabled:
-            from r2d2_tpu.telemetry import (AlertEngine, CompileMonitor,
-                                            ResourceMonitor, active_monitor,
+            from r2d2_tpu.telemetry import (AlertEngine, ResourceMonitor,
                                             default_rules)
             try:
-                if (cfg.telemetry.compile_enabled
-                        and active_monitor() is None):
-                    self.compile_monitor = CompileMonitor().install()
                 self.resources = ResourceMonitor(
                     player_idx, cfg.runtime.save_dir or ".",
                     interval_s=cfg.telemetry.resources_interval_s,
@@ -1120,10 +1116,6 @@ class PlayerStack:
         self.telemetry.close()   # stops the drain thread, final flush
         if self.tele_board is not None:
             self.tele_board.close()
-        if self.compile_monitor is not None:
-            # restore the pxla logger exactly (level/propagation) and
-            # release the process-global active-monitor slot
-            self.compile_monitor.uninstall()
 
 
 def train(cfg: Config, *, max_training_steps: Optional[int] = None,

@@ -1,5 +1,6 @@
-"""XLA compilation telemetry (ISSUE 7): compile counts/wall-time and
-post-warm-up retrace detection.
+"""XLA compilation telemetry (ISSUE 7): compile counts/wall-time, the
+persistent cache's hits and misses, each build's phases as spans (PR 38)
+and post-warm-up retrace detection.
 
 Recompiles are this stack's quietest failure mode: a jitted function
 handed a new abstract shape silently recompiles (~1.5 s each on the CPU
@@ -8,12 +9,18 @@ showed a single lazy mid-run ``replay_add_many`` compile backing the
 feeder up enough to park the whole actor fleet. Nothing surfaced it —
 the symptom was a throughput dip a human had to correlate by hand.
 
-Two capture channels, both public-ish and cheap:
+Two capture channels, both public and cheap:
 
-  * ``jax.monitoring`` duration events
-    (``/jax/core/compile/backend_compile_duration``): every backend
-    compile's wall time, no function identity — the aggregate
-    count/time counters.
+  * ``jax.monitoring``'s compile events, each with ``fun_name``: tracing
+    to a jaxpr (``/jax/core/compile/jaxpr_trace_duration``), lowering to
+    MLIR (``jaxpr_to_mlir_module_duration``) and XLA's build, or the
+    persistent cache's read and load of the executable
+    (``backend_compile_duration``). Each phase is announced as it starts
+    (a scalar event whose value is its start on ``time.time()``) and
+    reported as it ends (a time-span event). The cache's events
+    (``compile_requests_use_cache``, ``cache_hits``,
+    ``cache_retrieval_time_sec``) fire on the compiling thread inside the
+    backend phase.
   * the ``jax._src.interpreters.pxla`` DEBUG log line
     ``"Compiling <fn> with global shapes and types [avals]"``: function
     NAME + ABSTRACT SHAPES per compile. The monitor attaches a logging
@@ -30,14 +37,21 @@ burst. Late FIRST compiles (a new function after warm-up, e.g. an
 odd-size stager bucket) count as ``late_compiles`` — noteworthy, but not
 a retrace.
 
-Given a ``Telemetry``, every backend compile also records a ``compile``
-span (tag ``fn``: the name the log line gave on that thread just before)
-under whatever stage is open on the compiling thread, so a build inside
-a measured window names its iteration and its call.
+Given a ``Telemetry``, each phase is a span (PR 38): ``compile/trace``,
+``compile/lower`` and ``compile/backend``, tagged ``fn``; the backend's
+also ``cache`` (``hit`` | ``miss`` | ``off``: the cache was not asked)
+and, on a hit, ``cache_read_s``. A span opens at the phase's start under
+the stage open on the compiling thread and closes at its end, so a build
+names its iteration and its call, and what runs inside a phase (an eager
+op's build while tracing) hangs under it. A trace or lowering inside
+another (each jnp function a traced function calls is a jit of its own:
+~1,700 in a tiny train step) is part of the outer span and records none:
+the spans of trace and lowering on a thread are their union
+(``trace_lower_s``).
 
-One monitor per process (module-level active slot): jax.monitoring has
-no per-listener unregister, so ONE dispatching listener is registered on
-first install and routes to whichever monitor is active.
+One monitor per process (module-level active slot): the listeners are
+registered once, on first install, and route to whichever monitor is
+active.
 """
 
 import logging
@@ -46,7 +60,14 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-_COMPILE_DURATION_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_SPAN_OF = {_TRACE: "compile/trace", _LOWER: "compile/lower",
+            _BACKEND: "compile/backend"}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 # "Compiling <name> with global shapes and types [<avals>]. Argument ..."
 _COMPILING_RE = re.compile(
@@ -54,16 +75,40 @@ _COMPILING_RE = re.compile(
     r"\[?(.*?)\]?\.? Argument", re.DOTALL)
 
 _ACTIVE: Optional["CompileMonitor"] = None
-_LISTENER_REGISTERED = False
+_LISTENERS_REGISTERED = False
 # reentrant: install() displaces a previous owner by calling ITS
 # uninstall() while already holding the lock
 _INSTALL_LOCK = threading.RLock()
 
 
-def _duration_listener(event: str, duration: float, **kwargs) -> None:
+def _phase_start(event: str, value: float, **kwargs) -> None:
     mon = _ACTIVE
-    if mon is not None and event == _COMPILE_DURATION_EVENT:
-        mon._on_backend_compile(duration)
+    if mon is not None and event in _SPAN_OF:
+        mon._on_phase_start(event, value, kwargs.get("fun_name"))
+
+
+def _phase_end(event: str, start: float, end: float, **kwargs) -> None:
+    mon = _ACTIVE
+    if mon is not None and event in _SPAN_OF:
+        mon._on_phase_end(event, start, end)
+
+
+def _cache_event(event: str, **kwargs) -> None:
+    mon = _ACTIVE
+    if mon is not None and event in (_CACHE_ASKED, _CACHE_HIT):
+        mon._on_cache("hit" if event == _CACHE_HIT else "miss")
+
+
+def _cache_read(event: str, duration: float, **kwargs) -> None:
+    mon = _ACTIVE
+    if mon is not None and event == _CACHE_READ:
+        mon._on_cache("hit", read_s=duration)
+
+
+def _inside_trace(phases: list) -> bool:
+    """A trace or lowering is open on the thread: one that begins now is
+    part of it."""
+    return any(event != _BACKEND for event, _, _ in phases)
 
 
 class _CompileLogHandler(logging.Handler):
@@ -87,11 +132,11 @@ class _CompileLogHandler(logging.Handler):
 
 
 def active_monitor() -> Optional["CompileMonitor"]:
-    """The process's currently-installed monitor, or None. Orchestrating
-    loops check this before installing: compile events are process-global,
-    so the FIRST stack in a multiplayer process owns the monitor and later
-    stacks must not displace it (install() deactivates the previous
-    owner)."""
+    """The process's currently-installed monitor, or None. The first
+    ``Learner`` of a process installs one bound to its Telemetry and the
+    loop around it takes that one over; compile events are
+    process-global, so later stacks must not displace it (install()
+    deactivates the previous owner)."""
     return _ACTIVE
 
 
@@ -104,11 +149,14 @@ class CompileMonitor:
     MAX_RETRACE_LOG = 32      # retained retrace events (newest kept)
 
     def __init__(self, telemetry=None):
-        self._telemetry = telemetry    # where ``compile`` spans go
-        self._compiling = threading.local()   # .fn: the pxla line's name
+        self._telemetry = telemetry    # where the phases' spans go
+        self._local = threading.local()   # .phases: begun, not ended
         self._lock = threading.Lock()
-        self.compiles = 0              # backend compiles (monitoring event)
+        self.compiles = 0              # backend phases (monitoring event)
         self.compile_time_s = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self.trace_lower_s = 0.0       # union of trace + lowering a thread
         self.traced_compiles = 0       # named compiles (pxla log line)
         self.retraces = 0
         self.late_compiles = 0         # post-warm first compile of a new fn
@@ -121,19 +169,58 @@ class CompileMonitor:
 
     # -- capture-channel callbacks --
 
-    def _on_backend_compile(self, duration: float) -> None:
+    def _phases(self) -> list:
+        """This thread's phases begun and not ended, innermost last:
+        ``(event, open span or None, tags)``."""
+        local = self._local
+        if not hasattr(local, "phases"):
+            local.phases = []
+        return local.phases
+
+    def _on_phase_start(self, event: str, t0: float, fn: str) -> None:
+        phases = self._phases()
+        tags = {"fn": fn}
+        span = None
+        tele = self._telemetry
+        if (tele is not None and tele.spans.enabled
+                and (event == _BACKEND or not _inside_trace(phases))):
+            span = tele.spans.begin(_SPAN_OF[event], tags=tags, t0=t0)
+        phases.append((event, span, tags))
+
+    def _on_phase_end(self, event: str, t0: float, t1: float) -> None:
+        phases = self._phases()
+        if not phases or phases[-1][0] != event:
+            return          # began before this monitor was installed
+        _, span, tags = phases.pop()
+        if event == _BACKEND:
+            tags.setdefault("cache", "off")
+            self._on_backend_compile(t1 - t0, tags["cache"])
+        elif not _inside_trace(phases):
+            with self._lock:
+                self.trace_lower_s += t1 - t0
+        if span is not None:
+            self._telemetry.spans.end(span, t1)
+
+    def _on_cache(self, state: str, read_s: Optional[float] = None) -> None:
+        phases = self._phases()
+        if phases and phases[-1][0] == _BACKEND:
+            tags = phases[-1][2]
+            if tags.get("cache") != "hit":
+                tags["cache"] = state
+            if read_s is not None:
+                tags["cache_read_s"] = read_s
+
+    def _on_backend_compile(self, duration: float,
+                            cache: Optional[str] = None) -> None:
         with self._lock:
             self.compiles += 1
             self.compile_time_s += float(duration)
-        if self._telemetry is not None:
-            # the listener runs on the compiling thread, as the build ends
-            now = time.time()
-            self._telemetry.record_span(
-                "compile", now - duration, now,
-                {"fn": getattr(self._compiling, "fn", None)})
+            if cache == "hit":
+                self.cache_hits += 1
+            elif cache == "miss":
+                self.cache_misses += 1
 
     def _on_compile(self, name: str, avals: str) -> None:
-        self._compiling.fn = name
         with self._lock:
             self.traced_compiles += 1
             seen = self._signatures.setdefault(name, set())
@@ -150,17 +237,19 @@ class CompileMonitor:
     # -- lifecycle --
 
     def install(self) -> "CompileMonitor":
-        global _ACTIVE, _LISTENER_REGISTERED
+        global _ACTIVE, _LISTENERS_REGISTERED
         with _INSTALL_LOCK:
             if _ACTIVE is self:
                 return self
             if _ACTIVE is not None:
                 _ACTIVE.uninstall()
-            if not _LISTENER_REGISTERED:
-                import jax.monitoring
-                jax.monitoring.register_event_duration_secs_listener(
-                    _duration_listener)
-                _LISTENER_REGISTERED = True
+            if not _LISTENERS_REGISTERED:
+                import jax.monitoring as m
+                m.register_scalar_listener(_phase_start)
+                m.register_event_time_span_listener(_phase_end)
+                m.register_event_listener(_cache_event)
+                m.register_event_duration_secs_listener(_cache_read)
+                _LISTENERS_REGISTERED = True
             logger = logging.getLogger(_PXLA_LOGGER)
             self._saved_logger_state = (logger.level, logger.propagate)
             self._handler = _CompileLogHandler(level=logging.DEBUG)
@@ -206,6 +295,7 @@ class CompileMonitor:
                 "retraces_total": self.retraces,
                 "late_compiles": self.late_compiles,
                 "warm": self.warm,
+                **self._set_up(),
             }
             if self._retrace_log:
                 out["last_retrace"] = dict(self._retrace_log[-1])
@@ -230,10 +320,20 @@ class CompileMonitor:
                 "retraces_total": cur[2],
                 "late_compiles": cur[3],
                 "warm": self.warm,
+                **self._set_up(),
             }
             if self._retrace_log:
                 out["last_retrace"] = dict(self._retrace_log[-1])
             return out
+
+    def _set_up(self) -> Dict[str, Any]:
+        """What the builds cost so far (callers hold the lock): the
+        cache's answers, trace + lowering (their union on each thread)
+        and the backend phases."""
+        return {"cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses,
+                "trace_lower_s": round(self.trace_lower_s, 3),
+                "backend_s": round(self.compile_time_s, 3)}
 
     def functions_seen(self) -> Dict[str, int]:
         """{fn name: distinct aval signatures} — the tracked universe."""

@@ -169,6 +169,7 @@ class Telemetry:
         self._agg_board = None       # owner side: aggregation source
         self._spans_path: Optional[str] = None
         self._drain_stop: Optional[threading.Event] = None
+        self._drain_wake = threading.Event()
         self._drain_thread: Optional[threading.Thread] = None
 
     @classmethod
@@ -245,24 +246,33 @@ class Telemetry:
 
     # -- background drain --
 
+    def write_spans_to(self, spans_path: str, append: bool = False) -> None:
+        """Make ``flush`` append drained spans to ``spans_path`` (JSONL).
+        ``append=False`` truncates now (a fresh run's file);
+        ``append=True`` keeps what's there — respawned actor processes
+        and resumed runs must not wipe the history a post-mortem needs."""
+        if not self.enabled or not self.spans.enabled:
+            return
+        os.makedirs(os.path.dirname(spans_path) or ".", exist_ok=True)
+        if not append:
+            open(spans_path, "w").close()
+        self._spans_path = spans_path
+
     def start_drain(self, spans_path: Optional[str] = None,
                     append: bool = False) -> None:
         """Start the off-thread drain loop: every flush_interval_s,
-        publish board counts and append spans to ``spans_path`` (JSONL).
-        ``append=False`` truncates at start (a fresh run's file);
-        ``append=True`` keeps what's there — respawned actor processes
-        and resumed runs must not wipe the history a post-mortem needs."""
+        publish board counts and append spans to ``spans_path``
+        (``write_spans_to``)."""
         if not self.enabled or self._drain_thread is not None:
             return
-        if spans_path and self.spans.enabled:
-            os.makedirs(os.path.dirname(spans_path) or ".", exist_ok=True)
-            if not append:
-                open(spans_path, "w").close()
-            self._spans_path = spans_path
+        if spans_path:
+            self.write_spans_to(spans_path, append)
         self._drain_stop = threading.Event()
 
         def loop():
-            while not self._drain_stop.wait(self.flush_interval_s):
+            while not self._drain_stop.is_set():
+                self._drain_wake.wait(self.flush_interval_s)
+                self._drain_wake.clear()
                 try:
                     self.flush()
                 except (OSError, ValueError):
@@ -275,9 +285,18 @@ class Telemetry:
             target=loop, daemon=True, name=f"telemetry-drain-{self.name}")
         self._drain_thread.start()
 
+    def flush_soon(self) -> None:
+        """Write what the spans hold now: by the drain thread where one
+        runs (a file has one writer), else here (``flush``)."""
+        if self._drain_thread is not None:
+            self._drain_wake.set()
+        else:
+            self.flush()
+
     def close(self) -> None:
         if self._drain_stop is not None:
             self._drain_stop.set()
+            self._drain_wake.set()
             self._drain_thread.join(timeout=2.0)
             self._drain_thread = None
             self._drain_stop = None

@@ -13,9 +13,10 @@ Spans nest. ``begin``/``end`` (driven by ``Telemetry.stage``) keep a
 thread-local stack of open spans: a span's ``parent`` is the one open on
 its thread when it began, its ``iter`` the identifier its root was opened
 with, and a parent's ``self`` is its duration less what its children
-cover. ``record`` takes a span that is already over (timed by its caller,
-or by a listener such as the compile monitor's) and hangs it under the
-open one the same way. While open, a span also holds a
+cover. ``record`` takes a span that is already over (timed by its caller)
+and hangs it under the open one the same way; a listener that hears of a
+span's start and end as they happen (the compile monitor's) passes their
+times to ``begin`` and ``end``. While open, a span also holds a
 ``jax.profiler.TraceAnnotation``: with no capture live that is a flag
 test, with one live the span stands on the host plane of the capture, on
 the device operations' clock.
@@ -85,9 +86,10 @@ class SpanTracer:
                    local.stack[-1].iter if local.stack else None)
 
     def begin(self, name: str, iter: Any = None,
-              tags: Optional[Dict] = None) -> OpenSpan:
-        """Open a span on this thread (callers check ``enabled``). A root
-        names its ``iter``; a child inherits its parent's."""
+              tags: Optional[Dict] = None,
+              t0: Optional[float] = None) -> OpenSpan:
+        """Open a span on this thread (callers check ``enabled``), at ``t0``
+        or now. A root names its ``iter``; a child inherits its parent's."""
         if self._annotate is None:
             # not at import: the log tools read spans without loading jax
             from jax.profiler import TraceAnnotation
@@ -106,13 +108,14 @@ class SpanTracer:
             meta["iter"] = span.iter
         span.annotation = self._annotate(name, **meta)
         span.annotation.__enter__()
-        span.t0 = time.time()
+        span.t0 = time.time() if t0 is None else t0
         return span
 
-    def end(self, span: OpenSpan) -> float:
-        """Close ``span`` (the innermost open on this thread) and record
-        it; returns its duration."""
-        t1 = time.time()
+    def end(self, span: OpenSpan, t1: Optional[float] = None) -> float:
+        """Close ``span`` (the innermost open on this thread), at ``t1`` or
+        now, and record it; returns its duration."""
+        if t1 is None:
+            t1 = time.time()
         span.annotation.__exit__(None, None, None)
         local = self._local
         local.stack.pop()

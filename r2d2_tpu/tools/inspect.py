@@ -665,6 +665,10 @@ def render_resources(rb: dict) -> str:
                 f"interval={comp.get('compiles', 0)} "
                 f"retraces={comp.get('retraces_total', 0)}"
                 + (" [warm]" if comp.get("warm") else " [warming up]"))
+        if "trace_lower_s" in comp:
+            line += (f"  trace+lower={comp['trace_lower_s']:.1f}s cache "
+                     f"hits={comp['cache_hits']} "
+                     f"misses={comp['cache_misses']}")
         aot = comp.get("aot") or {}
         if aot.get("missing"):
             line += f"  !! AOT buckets missing: {aot['missing']}"

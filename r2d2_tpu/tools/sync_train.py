@@ -139,6 +139,8 @@ def sync_fitness(cfg: Config, train_steps: int,
     deadline = time.time() + max_seconds if max_seconds else None
     net, learner = sync_train(cfg, train_steps, collect_eps, seed=seed,
                               deadline=deadline)
+    # the next genome's Learner installs the process's compile monitor
+    learner.stop_background()
     returns = []
     for s in eval_seeds:
         if deadline is not None and time.time() > deadline:

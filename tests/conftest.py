@@ -46,3 +46,15 @@ def _the_mla_moe_steps_record_of_this_tree(request, monkeypatch):
     if request.node.name == "test_the_mla_moe_program_is_the_parents":
         monkeypatch.setattr(request.module, "MOONLIGHT_TINY_STEP",
                             MOONLIGHT_TINY_STEP)
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_monitor_outlives_its_test():
+    """The process's compile monitor is installed by the first ``Learner``
+    built and released by its ``stop_background`` (PR 38); one that a test
+    built and never stopped would leave the next test's Learner without."""
+    yield
+    from r2d2_tpu.telemetry.compile import active_monitor
+    mon = active_monitor()
+    if mon is not None:
+        mon.uninstall()

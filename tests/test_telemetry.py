@@ -226,7 +226,8 @@ def test_stage_nests_parent_iter_self_across_two_threads():
                 # a span that is over already (the compile listener's
                 # path) hangs under the open one
                 now = time.time()
-                tele.record_span("compile", now - 0.004, now, {"fn": "f"})
+                tele.record_span("compile/backend", now - 0.004, now,
+                                 {"fn": "f"})
             time.sleep(0.005)
             root.tag(train_steps=1)
 
@@ -247,14 +248,14 @@ def test_stage_nests_parent_iter_self_across_two_threads():
         assert mine["actor/act_scan"]["parent"] == root["id"]
         assert step["parent"] == root["id"]
         assert mine["learner/train_dispatch"]["parent"] == step["id"]
-        assert mine["compile"]["parent"] == step["id"]
+        assert mine["compile/backend"]["parent"] == step["id"]
         # self = duration less what the direct children cover
         assert root["self"] == pytest.approx(
             root["dur"] - mine["actor/act_scan"]["dur"] - step["dur"])
         assert 0.004 < root["self"] < root["dur"]
         assert step["self"] == pytest.approx(
             step["dur"] - mine["learner/train_dispatch"]["dur"]
-            - mine["compile"]["dur"])
+            - mine["compile/backend"]["dur"])
         leaf = mine["learner/train_dispatch"]
         assert leaf["self"] == pytest.approx(leaf["dur"])
     # the stage histogram was fed where the name is one of STAGES, only
@@ -689,23 +690,43 @@ def test_render_record_without_telemetry():
 
 def test_compile_monitor_hangs_a_build_under_the_open_stage():
     from r2d2_tpu.telemetry import CompileMonitor
+    from r2d2_tpu.telemetry.compile import _BACKEND, _LOWER, _TRACE
     tele = Telemetry()
     mon = CompileMonitor(tele)          # not installed: the callbacks alone
     with tele.stage("anakin/iteration", iter=7):
         with tele.stage("learner/train_dispatch"):
-            mon._on_compile("jit(step)", "f32[8]")
             t0 = time.time()
-            mon._on_backend_compile(0.25)
-    spans = {e["name"]: e for e in tele.spans.drain()}
-    build = spans["compile"]
-    assert build["tags"] == {"fn": "jit(step)"}
+            mon._on_phase_start(_TRACE, t0, "step")
+            mon._on_phase_start(_TRACE, t0 + 0.01, "tanh")   # nested: no span
+            mon._on_phase_end(_TRACE, t0 + 0.01, t0 + 0.02)
+            mon._on_phase_end(_TRACE, t0, t0 + 0.05)
+            mon._on_phase_start(_LOWER, t0 + 0.05, "jit(step)")
+            mon._on_phase_end(_LOWER, t0 + 0.05, t0 + 0.1)
+            mon._on_phase_start(_BACKEND, t0 + 0.1, "jit(step)")
+            mon._on_cache("miss")
+            mon._on_phase_end(_BACKEND, t0 + 0.1, t0 + 0.35)
+    rows = tele.spans.drain()
+    spans = {e["name"]: e for e in rows}
+    assert [e["name"] for e in rows].count("compile/trace") == 1
+    build = spans["compile/backend"]
+    assert build["tags"] == {"fn": "jit(step)", "cache": "miss"}
     assert build["dur"] == pytest.approx(0.25)
-    assert build["ts"] + build["dur"] == pytest.approx(t0, abs=0.05)
-    assert build["parent"] == spans["learner/train_dispatch"]["id"]
-    assert build["iter"] == 7
-    assert mon.compiles == 1
+    assert build["ts"] == pytest.approx(t0 + 0.1)
+    dispatch = spans["learner/train_dispatch"]
+    for name in ("compile/trace", "compile/lower", "compile/backend"):
+        assert spans[name]["parent"] == dispatch["id"]
+        assert spans[name]["iter"] == 7
+    assert spans["compile/trace"]["tags"] == {"fn": "step"}
+    assert spans["compile/trace"]["dur"] == pytest.approx(0.05)
+    # the dispatch's own time is what the three phases leave
+    assert dispatch["self"] == pytest.approx(dispatch["dur"] - 0.35)
+    assert mon.compiles == 1 and mon.cache_misses == 1
+    assert mon.totals()["trace_lower_s"] == pytest.approx(0.1)
     # without a Telemetry it only counts, as before
-    CompileMonitor()._on_backend_compile(0.1)
+    bare = CompileMonitor()
+    bare._on_phase_start(_BACKEND, t0, "f")
+    bare._on_phase_end(_BACKEND, t0, t0 + 0.1)
+    assert bare.compiles == 1 and bare.cache_misses == 0
 
 
 ITERATION_CHILDREN = ["actor/act_scan", "ingest/commit", "anakin/accounting",
@@ -798,8 +819,14 @@ def test_fused_loop_iteration_is_tiled_by_its_children(fused_loop_run):
         assert [k["name"] for k in sorted(
             kids[by["anakin/log"]["id"]], key=lambda k: k["ts"])
         ] == LOG_CHILDREN
-        assert [k["name"] for k in kids[by["learner/step"]["id"]]
-                ] == ["learner/train_dispatch"]
+        step = sorted(kids[by["learner/step"]["id"]], key=lambda k: k["ts"])
+        # the run's first dispatch, and only it, is set-up's: tagged, and
+        # followed by the one block on its outputs
+        first = root is trained[0]
+        assert [k["name"] for k in step] == (
+            ["learner/train_dispatch"]
+            + (["learner/first_ready"] if first else []))
+        assert (step[0]["tags"].get("first") == 1) == first
         # everything under a root carries its identifier
         assert all(k["iter"] == root["iter"] for k in kids[root["id"]])
         # counts at the same boundaries
@@ -843,8 +870,6 @@ def test_fused_loop_spans_stand_on_the_profilers_clock(fused_loop_run):
         + ["learner/train_dispatch"])
     late_ms = []
     for row in rows:
-        if row["name"] == "compile":     # over before it is recorded
-            continue
         name, start_ns, stats = on_plane[row["id"]]
         assert name == row["name"]
         assert stats.get("parent") == row["parent"]
@@ -859,7 +884,9 @@ def test_fused_loop_spans_stand_on_the_profilers_clock(fused_loop_run):
 def test_fused_loop_retrace_names_its_iteration_and_call(fused_loop_run):
     rows = fused_loop_run["rows"]
     by_id = {r["id"]: r for r in rows}
-    builds = [r for r in rows if r["name"] == "compile"]
+    # the loop's builds (the Learner's own, under ``iter="setup"``, aside)
+    builds = [r for r in rows if r["name"] == "compile/backend"
+              and isinstance(r["iter"], int)]
     # warm-up's builds sit in the iterations that made them
     assert {by_id[b["parent"]]["name"] for b in builds
             if b["iter"] < RETRACE_AT} >= {
