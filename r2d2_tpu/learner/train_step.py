@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import optax
 
 from r2d2_tpu.config import OptimConfig
+from r2d2_tpu.learner.target import target_of
 from r2d2_tpu.models.cores import require_lstm
 from r2d2_tpu.models.network import NetworkApply
 from r2d2_tpu.ops.indexing import (
@@ -66,34 +67,45 @@ def make_optimizer(optim: OptimConfig) -> optax.GradientTransformation:
 
 def create_train_state(key: jax.Array, net: NetworkApply, optim: OptimConfig
                        ) -> TrainState:
+    """A fresh train state: the parameters, the target as ``target_of``
+    holds them (a copy), Adam's state."""
     pkey, skey = jax.random.split(key)
     params = net.init(pkey)
     tx = make_optimizer(optim)
+
+    def held_target(p):
+        # one program for the whole tree, not one a leaf's shape
+        return jax.tree_util.tree_map(jnp.copy, target_of(net, p))
+
     return TrainState(
         params=params,
-        target_params=jax.tree_util.tree_map(jnp.copy, params),
+        target_params=jax.jit(held_target)(params),
         opt_state=tx.init(params),
         step=jnp.zeros((), jnp.int32),
         key=skey,
     )
 
 
-def sync_target(optim: OptimConfig, use_double: bool, new_step, params,
-                target_params):
+def sync_target(net: NetworkApply, optim: OptimConfig, use_double: bool,
+                new_step, params, target_params):
     """The hard target sync (ref worker.py:375-377) as ONE branch over the
     whole tree: every ``optim.target_net_update_interval`` steps, counted
-    1-based like the reference's post-increment check, the target becomes a
-    copy of ``params``; on every other step it is passed through, which XLA
-    aliases in place (a per-leaf ``jnp.where`` read and rewrote every
-    target leaf on every step). Returns (target_params, fired) with
-    ``fired`` the step's 0/1 ``target_sync`` counter; with double-Q off
-    nothing reads the target and nothing fires. Shared by every step
-    factory so their schedules cannot diverge."""
+    1-based like the reference's post-increment check, the target becomes
+    ``target_of(params)``, the parameters in the dtypes the target is held
+    in (learner/target.py); on every other step it is passed through, which
+    XLA aliases in place (a per-leaf ``jnp.where`` read and rewrote every
+    target leaf on every step). Both branches go through ``target_of``, so
+    a target handed in as float32 leaves narrowed and the branches agree.
+    Returns (target_params, fired) with ``fired`` the step's 0/1
+    ``target_sync`` counter; with double-Q off nothing reads the target and
+    nothing fires. Shared by every step factory so their schedules cannot
+    diverge."""
     if not use_double:
         return target_params, jnp.zeros((), jnp.int32)
     sync = (new_step % optim.target_net_update_interval) == 0
     target_params = jax.lax.cond(
-        sync, lambda p, t: p, lambda p, t: t, params, target_params)
+        sync, lambda p, t: target_of(net, p), lambda p, t: target_of(net, t),
+        params, target_params)
     return target_params, sync.astype(jnp.int32)
 
 
@@ -300,7 +312,7 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             params = store_router_means(params, aux["moe"]["input_mean"])
 
         target_params, target_sync = sync_target(
-            optim, use_double, new_step, params, old_target)
+            net, optim, use_double, new_step, params, old_target)
 
         metrics = {
             "loss": loss,
@@ -362,7 +374,8 @@ def make_external_batch_step(net: NetworkApply, spec: ReplaySpec,
 
         new_step = train_state.step + 1
         target_params, target_sync = sync_target(
-            optim, use_double, new_step, params, train_state.target_params)
+            net, optim, use_double, new_step, params,
+            train_state.target_params)
 
         grad_norm = optax.global_norm(grads)
         metrics = {
@@ -423,6 +436,12 @@ def make_multi_learner_step(net: NetworkApply, spec: ReplaySpec,
                               diag=diag, rdiag=rdiag)
 
     def multi_step(train_state: TrainState, replay_state: ReplayState):
+        if use_double:
+            # the loop carries the target as the sync leaves it: a target
+            # handed in as float32 enters narrowed (a held one as it is)
+            train_state = train_state.replace(target_params=target_of(
+                net, train_state.target_params))
+
         def body(carry, _):
             ts, rs = carry
             ts, rs, m = inner(ts, rs)

@@ -257,9 +257,9 @@ def make_sharded_replay_add_many(spec: ReplaySpec, mesh: Mesh):
     return jax.jit(add_fn, donate_argnums=0)
 
 
-def _post_gradient_update(tx, optim: OptimConfig, use_double: bool,
-                          train_state: TrainState, grads, key, loss,
-                          mean_abs_td, mean_q):
+def _post_gradient_update(net: NetworkApply, tx, optim: OptimConfig,
+                          use_double: bool, train_state: TrainState, grads,
+                          key, loss, mean_abs_td, mean_q):
     """Everything after the (already-reduced) gradients: Adam update,
     target-net sync schedule, metrics dict, TrainState advance. ONE
     implementation shared by the manual shard_map dp path and the GSPMD
@@ -270,7 +270,7 @@ def _post_gradient_update(tx, optim: OptimConfig, use_double: bool,
 
     new_step = train_state.step + 1
     target_params, target_sync = sync_target(
-        optim, use_double, new_step, params, train_state.target_params)
+        net, optim, use_double, new_step, params, train_state.target_params)
 
     metrics = {
         "loss": loss,
@@ -376,7 +376,7 @@ def make_sharded_learner_step(net: NetworkApply, spec: ReplaySpec,
             ld.update(shard_replay_diag(rd, "dp"))
 
         train_state, metrics = _post_gradient_update(
-            tx, optim, use_double, train_state, grads, key, loss,
+            net, tx, optim, use_double, train_state, grads, key, loss,
             jax.lax.pmean(aux["mean_abs_td"], "dp"),
             jax.lax.pmean(aux["mean_q"], "dp"))
         metrics.update(ld)
@@ -489,8 +489,9 @@ def _make_gspmd_learner_step(net: NetworkApply, spec: ReplaySpec,
                        for k, v in rdm.items()})
 
         train_state, metrics = _post_gradient_update(
-            tx, optim, use_double, train_state, grads, key, loss_v.mean(),
-            aux_v["mean_abs_td"].mean(), aux_v["mean_q"].mean())
+            net, tx, optim, use_double, train_state, grads, key,
+            loss_v.mean(), aux_v["mean_abs_td"].mean(),
+            aux_v["mean_q"].mean())
         metrics.update(ld)
         return train_state, replay_global, metrics
 

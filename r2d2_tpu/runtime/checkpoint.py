@@ -130,9 +130,13 @@ def resume_training_state(path: str, train_state):
     }
     restored = restore_checkpoint(path, template)
     import jax.numpy as jnp
+    from r2d2_tpu.learner.target import like_target
     new_state = train_state.replace(
         params=restored["params"],
-        target_params=restored["target_params"],
+        # a target saved as float32 (or narrowed) enters in the dtypes the
+        # train state's target_of holds it in
+        target_params=like_target(train_state.target_params,
+                                  restored["target_params"]),
         opt_state=restored["opt_state"],
         step=jnp.asarray(int(restored["step"]), jnp.int32),
     )
@@ -152,10 +156,11 @@ def apply_restore(runtime_cfg, train_state) -> Tuple[Any, int]:
     if runtime_cfg.resume:
         return resume_training_state(runtime_cfg.resume, train_state)
     if runtime_cfg.pretrain:
+        from r2d2_tpu.learner.target import like_target
         params = load_pretrain(runtime_cfg.pretrain, train_state.params)
         return train_state.replace(
             params=params,
-            target_params=jax.tree_util.tree_map(np.copy, params)), 0
+            target_params=like_target(train_state.target_params, params)), 0
     return train_state, 0
 
 

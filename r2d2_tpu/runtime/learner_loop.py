@@ -282,6 +282,10 @@ class Learner:
         # the run's first record)
         from r2d2_tpu.models.cores import state_block
         self.metrics.set_core(state_block(cfg.network))
+        # the dtypes the target is held in (record block 'target', first
+        # record): what the plan narrowed, and the bytes it holds
+        from r2d2_tpu.learner.target import target_block
+        self.metrics.set_target(target_block(self.train_state.target_params))
         if self._exp_trace is not None:
             # experience lineage (ISSUE 19): the record's 'trace' block
             self.metrics.set_tracing(self._exp_trace.interval_block)

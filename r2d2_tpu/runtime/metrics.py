@@ -116,6 +116,7 @@ class TrainMetrics:
         # to pre-PR9, stability-tested)
         self._costs = None
         self._core = None
+        self._target = None
 
         # serving plane (ISSUE 13): a serving-block provider
         # (ServingStats.interval_block, attached by the orchestrating
@@ -284,6 +285,14 @@ class TrainMetrics:
         LSTM's (h, c) from a latent cache, and a convolution's state from a
         window of keys and values. Emitted on exactly one record."""
         self._core = block
+
+    def set_target(self, block: Optional[dict]) -> None:
+        """Attach the one-shot target block: the target network's leaves
+        held narrower than float32 and those kept, its bytes and what the
+        same tree would hold in float32 (learner/target.py target_block),
+        so that a reader can tell whether the target is held in the dtype
+        its forward reads. Emitted on exactly one record."""
+        self._target = block
 
     def set_serving(self, provider) -> None:
         """Attach the serving-block provider (ISSUE 13): a callable
@@ -483,6 +492,9 @@ class TrainMetrics:
         if self._core is not None:
             record["core"] = self._core
             self._core = None
+        if self._target is not None:
+            record["target"] = self._target
+            self._target = None
         if self.telemetry.enabled:
             # ONE aggregated block per interval covering the whole fleet:
             # learner-local stage timers merged with the actor board's

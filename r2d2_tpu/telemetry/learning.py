@@ -97,13 +97,21 @@ def group_grad_norms(grads) -> Dict[str, Any]:
     return {str(k): optax.global_norm(v) for k, v in sorted(groups.items())}
 
 
-def param_distance(params, target_params):
+def param_distance(params, target_params, net):
     """Global L2 distance between the online and target parameter trees.
     With use_double off the target is frozen at init, so this reads as
-    total parameter drift since initialization instead."""
+    total parameter drift since initialization instead. The online tree is
+    first taken to the dtypes the target is held in (``learner/target.py
+    target_of``), so that right after a sync this reads 0, and the
+    difference is taken in float32."""
     import jax
+    import jax.numpy as jnp
     import optax
-    diff = jax.tree_util.tree_map(lambda p, t: p - t, params, target_params)
+    from r2d2_tpu.learner.target import target_of
+    params = target_of(net, params)
+    diff = jax.tree_util.tree_map(
+        lambda p, t: p.astype(jnp.float32) - t.astype(jnp.float32), params,
+        target_params)
     return optax.global_norm(diff)
 
 
@@ -226,7 +234,7 @@ def fused_diagnostics(net, spec, diag: LearningDiag, new_step, params,
     # interval-gated heavies: lax.cond executes ONE branch at runtime, so
     # the reference unroll's cost lands only on diagnostic steps
     def on(_):
-        tdist = param_distance(params, target_params)
+        tdist = param_distance(params, target_params, net)
         if replay_state is not None:
             dq_s, dq_z, dq_r = delta_q_diag(net, spec, params, batch,
                                             replay_state, diag.dq_batch)

@@ -252,6 +252,62 @@ def run_chip_checks(only: str = "") -> int:
     add("grouped_acting_down_f32_r256",
         grouped("weights", 256, 1792, 2048, jnp.float32))
 
+    # --- the target held in the dtype its forward reads
+    # (learner/target.py) at the widths of the benchmark's configurations
+    # with experts: through one program (float32 in), the float32 tree and
+    # the same tree with the leaves the plan narrows rounded to bf16 first
+    # give the same Q, state and routers' counters bit for bit; the
+    # routers' kernels, which the plan keeps, rounded as well do not. The
+    # reference check cannot see a leaf narrowed by mistake (it is handed
+    # the program's own target); this can
+    def target_plan(config_name, batch=16):
+        def check():
+            rng = fresh_rng()
+            from benchmarks import harness
+            from r2d2_tpu.envs.factory import create_env
+            from r2d2_tpu.learner.target import target_plan as plan_of
+            from r2d2_tpu.models.network import NetworkApply
+            config = harness.config_doc(harness.load_benchmark(), config_name)
+            cfg = harness.build_config(
+                harness.program_overrides(config, {}), "", 0)
+            env = create_env(cfg.env, seed=0)
+            net = NetworkApply(env.action_space.n, cfg.network,
+                               cfg.env.frame_stack, cfg.env.frame_height,
+                               cfg.env.frame_width)
+            env.close()
+            params = net.init(jax.random.PRNGKey(0))
+            plan = plan_of(net, params)
+            assert plan is not None, "nothing narrowed"
+            h, w, s = net.obs_hw
+            window = (cfg.sequence.burn_in_steps + cfg.sequence.learning_steps
+                      + cfg.sequence.forward_steps)
+            obs = jnp.asarray(rng.random((batch, window, h, w, s)),
+                              net.module.compute_dtype)
+            la = jax.nn.one_hot(rng.integers(0, net.action_dim,
+                                             (batch, window)),
+                                net.action_dim)
+            hidden = net.init_state(batch)
+            forward = jax.jit(net.apply_learner)
+            want = jax.tree_util.tree_leaves(jax.device_get(
+                forward(params, obs, la, hidden)))
+
+            def same(p):
+                got = jax.tree_util.tree_leaves(jax.device_get(
+                    forward(p, obs, la, hidden)))
+                return all(np.array_equal(x, y) for x, y in zip(got, want))
+
+            assert same(jax.tree_util.tree_map(
+                lambda d, p: p.astype(d).astype(p.dtype), plan, params)), \
+                "the leaves the plan narrows, rounded first, moved the forward"
+            assert not same(jax.tree_util.tree_map_with_path(
+                lambda path, p: (p.astype(jnp.bfloat16).astype(p.dtype)
+                                 if getattr(path[-1], "key", None) == "gate"
+                                 else p), params)), \
+                "rounding the routers' kernels left the forward as it was"
+        return check
+    add("target_plan_moonlight_core", target_plan("moonlight-core"))
+    add("target_plan_lfm2_core", target_plan("lfm2-core"))
+
     # --- quantized acting forward (ISSUE 14): compile + parity ----------
     def quant_forward():
         rng = fresh_rng()
