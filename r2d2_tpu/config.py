@@ -96,6 +96,25 @@ class CoreConfig:
     ``n_shared_experts``, and it has no defaults of its own: a
     configuration spells every size (benchmarks/configs/lfm2-core.json).
 
+    ``"sparse_attn_moe"``: Qwen3-MoE-form layers whose grouped-query
+    attention reads the keys a learned indexer chooses for each query
+    (DeepSeek-V3.2's sparse attention), as Keye-VL-2.0-30B-A3B's
+    (https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json,
+    ``model_type: KeyeVL2``, its ``sa_config``; models/cores/sparse_attn_moe.py).
+    It reads ``num_key_value_heads`` and ``head_dim`` (heads of their own
+    width, not ``hidden_size / num_attention_heads``), the indexer's
+    ``index_n_heads`` x ``index_head_dim`` and ``index_topk`` (the source's
+    ``indexer_num_heads``, ``indexer_head_dim``, ``topk``), and
+    ``q_chunk_size`` / ``kv_chunk_size``, the blocks of queries and keys the
+    attention is computed in; dense layers (``first_k_dense_replace``) are
+    ``intermediate_size`` wide. Its own keys default to 0, which it refuses:
+    a configuration spells every size (benchmarks/configs/keye-core.json).
+
+    ``scoring_func`` is the form of every routed layer's scores, as the
+    sources spell it: "sigmoid" (Moonlight, LFM2: the expert bias in the
+    choice) or "softmax" over all routed experts (Qwen3-MoE, Keye: no bias;
+    the chosen weights renormalised over the chosen, no epsilon).
+
     Three keys are this program's own: ``experts_held`` / ``expert_offset``
     (the router scores all ``n_routed_experts`` and picks
     ``num_experts_per_tok`` of them; this chip computes the experts
@@ -127,16 +146,28 @@ class CoreConfig:
     layer_types: Tuple[str, ...] = ()
     conv_L_cache: int = 3
     num_key_value_heads: int = 8
+    scoring_func: str = "sigmoid"
+    head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    q_chunk_size: int = 0
+    kv_chunk_size: int = 0
 
     def __post_init__(self):
         # a JSON list (a checkpoint's .config.json) hashes as a tuple
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        if self.kind not in ("lstm", "mla_moe", "conv_attn_moe"):
+        if self.kind not in ("lstm", "mla_moe", "conv_attn_moe",
+                             "sparse_attn_moe"):
             raise ValueError(
                 f"network.core.kind ({self.kind!r}) must be 'lstm', "
-                "'mla_moe' or 'conv_attn_moe'")
+                "'mla_moe', 'conv_attn_moe' or 'sparse_attn_moe'")
         if self.kind == "lstm":
             return
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"network.core.scoring_func ({self.scoring_func!r}) must be "
+                "'sigmoid' or 'softmax'")
         if not (0 <= self.expert_offset and self.experts_held >= 1
                 and self.expert_offset + self.experts_held
                 <= self.n_routed_experts):
@@ -155,6 +186,21 @@ class CoreConfig:
             if self.qk_rope_head_dim % 2:
                 raise ValueError(
                     "network.core.qk_rope_head_dim must be even")
+            return
+        if self.kind == "sparse_attn_moe":
+            heads, groups = self.num_attention_heads, self.num_key_value_heads
+            if (heads < 1 or groups < 1 or heads % groups
+                    or min(self.head_dim, self.index_head_dim) < 2
+                    or self.head_dim % 2 or self.index_head_dim % 2):
+                raise ValueError(
+                    "network.core: num_key_value_heads must divide "
+                    "num_attention_heads; head_dim and index_head_dim must "
+                    "be even and at least 2")
+            if min(self.index_n_heads, self.index_topk, self.q_chunk_size,
+                   self.kv_chunk_size) < 1:
+                raise ValueError(
+                    "network.core: index_n_heads, index_topk, q_chunk_size "
+                    "and kv_chunk_size must be at least 1")
             return
         if len(self.layer_types) != self.num_hidden_layers or any(
                 kind not in ("conv", "full_attention")

@@ -158,6 +158,7 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
 
     def loss_fn(params, target_params, batch: SampleBatch):
         q_online, counters = _unrolled_q(net, spec, params, batch, use_pallas)
+        own = counters.pop("core", None)
 
         # the target unroll is computed BEFORE entering the loss scope —
         # its ops keep their torso/lstm/head component scopes un-nested
@@ -208,6 +209,19 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
 
             priorities = mixed_td_errors_masked(jnp.abs(td), mask,
                                                 optim.priority_eta)
+            if own is not None:
+                # a core's own loss (models/cores/__init__.py), per layer its
+                # mean over the valid learning positions, as the TD loss is
+                # taken. Its gradient joins the TD loss's under the one
+                # clip; the value returned stays the TD loss, which the
+                # priorities and the reference check are made of, and the
+                # core's is reported beside it
+                own = dict(own)
+                at = jnp.take_along_axis(own.pop("loss"), opos[None], axis=2)
+                core_loss = jnp.sum(at * mask[None], axis=(1, 2)) / num_valid
+                loss = loss + (jnp.sum(core_loss)
+                               - jax.lax.stop_gradient(jnp.sum(core_loss)))
+                own["loss"] = core_loss
         aux = {
             "priorities": priorities,
             "mean_abs_td": jnp.sum(jnp.abs(td)) / num_valid,
@@ -222,6 +236,8 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
         if counters:
             # the routing counters of the online forward (a core with experts)
             aux["moe"] = counters
+        if own is not None:
+            aux["core"] = own
         return loss, aux
 
     return loss_fn
@@ -325,6 +341,9 @@ def make_learner_step(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             # the experts' routing counters of this step
             metrics.update({f"moe/{k}": v for k, v in aux["moe"].items()
                             if k != "input_mean"})
+        if "core" in aux:
+            # the core's own counters and its loss, per layer
+            metrics.update({f"core/{k}": v for k, v in aux["core"].items()})
         metrics.update(ld)
         if rdiag is not None:
             # replay-pathology pillar (ISSUE 10): sample-count ring +

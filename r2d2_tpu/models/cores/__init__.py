@@ -26,7 +26,14 @@ experts: the forward pass sows routing counters, the train step stores the
 routers' input means among the parameters and the record carries a ``moe``
 block; ``experts.py``) and ``state_parts()``, the state row by kind of part
 as (kind, layers that keep such a part, floats in all of them), which the
-record's ``core`` block carries (``state_block``).
+record's ``core`` block carries (``state_block``). A core may sow, in the
+learner's call, counters of its own per layer into the ``core`` collection,
+among them ``loss``, a loss of its own at each window position, which the
+learner averages over its learning positions and adds to the TD loss
+(``learner/train_step.py``); such a core gives ``summarise(sums, steps)``,
+the record's blocks made of those counters summed over steps, and may give
+``record_block()``, its own part of the one-shot ``core`` block
+(``sparse_attn_moe``).
 
 ``lstm``: R2D2's LSTM (``HoistedLSTM``), the row is (h, c).
 ``mla_moe``: DeepSeek-V3-form layers: latent attention over a stored latent
@@ -38,7 +45,12 @@ state (the convolution's last inputs, a window of keys and values) packed
 layer by layer into the one row, and the same held experts with no shared
 expert (``conv_attn_moe.py``). Its departures from the source stand in its
 docstring and in ``benchmarks/configs/lfm2-core.json``.
-The two stacks share ``experts.py``: router, held experts, counters.
+``sparse_attn_moe``: Qwen3-MoE-form layers whose grouped-query attention
+reads, for each query, the keys a learned indexer chooses (DeepSeek-V3.2's
+sparse attention, Keye-VL-2.0's sizes), with the held experts routed by a
+softmax (``sparse_attn_moe.py``). It is the first core with a loss of its
+own: the indexer's, sown per position beside the attention's counters.
+The three stacks share ``experts.py``: router, held experts, counters.
 """
 
 from typing import Any, Dict
@@ -54,6 +66,9 @@ def make_core(config: NetworkConfig, dtype):
     if config.core.kind == "conv_attn_moe":
         from r2d2_tpu.models.cores.conv_attn_moe import ConvAttnMoeCore
         return ConvAttnMoeCore(config.core, dtype)
+    if config.core.kind == "sparse_attn_moe":
+        from r2d2_tpu.models.cores.sparse_attn_moe import SparseAttnMoeCore
+        return SparseAttnMoeCore(config.core, dtype)
     from r2d2_tpu.models.cores.mla_moe import MlaMoeCore
     return MlaMoeCore(config.core, dtype)
 
@@ -64,11 +79,14 @@ def state_block(config: NetworkConfig) -> Dict[str, Any]:
     sequence's stored row holds and how much of it."""
     import jax.numpy as jnp
     core = make_core(config, jnp.float32)
-    return {"kind": config.core.kind, "state_half": core.state_half,
-            "row_bytes": 8 * core.state_half,
-            "parts": [{"kind": kind, "layers": layers, "floats": floats,
-                       "bytes": 4 * floats}
-                      for kind, layers, floats in core.state_parts()]}
+    block = {"kind": config.core.kind, "state_half": core.state_half,
+             "row_bytes": 8 * core.state_half,
+             "parts": [{"kind": kind, "layers": layers, "floats": floats,
+                        "bytes": 4 * floats}
+                       for kind, layers, floats in core.state_parts()]}
+    if hasattr(core, "record_block"):
+        block.update(core.record_block())
+    return block
 
 
 def require_lstm(config: NetworkConfig, what: str) -> None:

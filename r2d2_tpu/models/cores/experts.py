@@ -4,7 +4,9 @@ cores that route to experts (``mla_moe.py``, ``conv_attn_moe.py``).
 An expert layer here is: a router over all ``n_routed_experts`` that scores
 by sigmoid, chooses ``num_experts_per_tok`` of them by score plus a bias that
 only the choice reads, and weights the chosen by their own scores normalised
-over the chosen and scaled (``route``); the experts this chip holds
+over the chosen and scaled (``route``), or, where ``CoreConfig.scoring_func``
+is "softmax" (Qwen3-MoE's form), scores by a softmax over all routed experts
+and chooses by score alone, with no bias; the experts this chip holds
 (``expert_offset .. expert_offset + experts_held - 1``) computed for the
 (position, expert) pairs that fall on them, what the others would add left
 out (``HeldExperts``); and, where the caller's source has one, a shared
@@ -483,16 +485,18 @@ class RoutedMoE(nn.Module):
             w_r = self.param("gate", INIT, (d, c.n_routed_experts))
             # neither gets a gradient, so Adam leaves both where they are:
             # only the choice reads the bias, and the train step writes the
-            # mean (store_router_means)
-            bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros, (c.n_routed_experts,))
+            # mean (store_router_means). The softmax form has no bias.
+            bias = (self.param("e_score_correction_bias",
+                               nn.initializers.zeros, (c.n_routed_experts,))
+                    if c.scoring_func == "sigmoid" else 0.0)
             stored = self.param("router_input_mean", nn.initializers.zeros,
                                 (d,))
             mean = jax.lax.stop_gradient(
                 jnp.mean(flat, axis=0) if self.window_stats else stored)
-            scores = jax.nn.sigmoid(jnp.einsum(
-                "nd,de->ne", flat - mean, w_r,
-                precision=jax.lax.Precision.HIGHEST))
+            logits = jnp.einsum("nd,de->ne", flat - mean, w_r,
+                                precision=jax.lax.Precision.HIGHEST)
+            scores = (jax.nn.sigmoid(logits) if c.scoring_func == "sigmoid"
+                      else jax.nn.softmax(logits, axis=-1))
             chosen, weights = route(scores, bias, c.num_experts_per_tok,
                                     c.routed_scaling_factor, self.topk_eps)
         routed, walk = HeldExperts(c, self.dtype, self.chunk_rows,
@@ -525,14 +529,16 @@ class RoutedMoE(nn.Module):
         return out, stats
 
 
-def moe_counters(mutated: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
+def moe_counters(mutated: Dict[str, Any], collection: str = "moe"
+                 ) -> Dict[str, jnp.ndarray]:
     """The stack's sown counters out of ``apply(..., mutable=['moe'])``'s
     second result: {chosen (L_moe, routed), entropy (L_moe,), dropped
     (L_moe,), rows_walked (L_moe,), overflow_chunks (L_moe,), tile_rows
     (L_moe,), input_mean (L_moe, hidden)}, or {} for a stack without expert
-    layers."""
+    layers; those of another ``collection`` that a core sows its counters
+    into the same way (``core``: models/cores/__init__.py)."""
     found = jax.tree_util.tree_leaves(
-        mutated.get("moe", {}), is_leaf=lambda x: isinstance(x, tuple))
+        mutated.get(collection, {}), is_leaf=lambda x: isinstance(x, tuple))
     return found[0][0] if found else {}
 
 

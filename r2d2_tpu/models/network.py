@@ -524,11 +524,17 @@ class NetworkApply:
         """The learner's forward pass over a batch of windows: ``apply``
         with the core's ``window_stats`` on, and the counters the core sowed
         into the ``moe`` collection while it ran (the routing of a core with
-        experts: models/cores/experts.py; {} for a core that sows none)."""
+        experts: models/cores/experts.py; {} for a core that sows none),
+        with, for a core that sows counters of its own (its loss among
+        them), those under ``"core"`` (models/cores/__init__.py)."""
         from r2d2_tpu.models.cores.experts import moe_counters
         (q, new_hidden), sown = self.module.apply(
-            params, obs_seq, last_action_seq, hidden, True, mutable=["moe"])
-        return q, new_hidden, moe_counters(sown)
+            params, obs_seq, last_action_seq, hidden, True,
+            mutable=["moe", "core"])
+        counters, own = dict(moe_counters(sown)), moe_counters(sown, "core")
+        if own:
+            counters["core"] = own
+        return q, new_hidden, counters
 
 
 def init_network(

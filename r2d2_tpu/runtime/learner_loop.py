@@ -107,6 +107,11 @@ class Learner:
         from r2d2_tpu.telemetry.learning import MoeAggregator
         self._moe_agg = (MoeAggregator(cfg.network.core)
                          if net.core.routes_experts else None)
+        # the counters of a core that counts something of its own (its
+        # loss among them), made into record blocks by the core
+        from r2d2_tpu.telemetry.learning import CoreAggregator
+        self._core_agg = (CoreAggregator(net.core.summarise)
+                          if hasattr(net.core, "summarise") else None)
         # replay & data-pathology pillar (ISSUE 10): same spec/aggregator
         # pattern — a ReplayDiag fuses sum-tree health, sample-lifetime
         # accounting and lane composition into the step; None (the
@@ -1202,6 +1207,8 @@ class Learner:
                 self._replay_agg.on_dispatch(m)
             if self._moe_agg is not None:
                 self._moe_agg.on_dispatch(m)
+            if self._core_agg is not None:
+                self._core_agg.on_dispatch(m)
 
             rt = self.cfg.runtime
             if (self.publish is not None
@@ -1335,7 +1342,7 @@ class Learner:
             self.metrics.on_target_syncs(sum(int(np.sum(a)) for a in syncs))
         # the aggregators fetch their own device values: a second and a
         # third transfer at every log boundary, after the sync above
-        with self.tele.stage("learner/diag_flush"):
+        with self.tele.stage("learner/diag_flush") as flush:
             if self._learning_agg is not None:
                 pub = (int(self.weight_version_fn())
                        if self.weight_version_fn is not None else None)
@@ -1344,6 +1351,15 @@ class Learner:
                     occupancy_versions=self.ring.live_versions()))
             if self._moe_agg is not None:
                 self.metrics.set_moe(self._moe_agg.flush())
+            if self._core_agg is not None:
+                blocks = self._core_agg.flush()
+                self.metrics.set_core_blocks(blocks)
+                # the span tree carries the blocks' counts of the steps this
+                # flush closed, as <block>_<count> (k_sparse_attn_roofline
+                # reads dsa_pairs)
+                flush.tag(**{f"{name}_{k}": v
+                             for name, block in (blocks or {}).items()
+                             for k, v in block.items() if isinstance(v, int)})
             if self._replay_agg is not None:
                 # host placement: the HostReplay numpy twin supplies the
                 # sum-tree health + eviction snapshot the external-batch step

@@ -84,6 +84,7 @@ class TrainMetrics:
         # key on its presence, like the 'stages' block)
         self._learning = None
         self._moe = None
+        self._core_blocks = None
 
         # sharded-anakin composition block (ISSUE 8): per-shard rows +
         # the env-step imbalance ratio, set at each stats flush by the
@@ -247,6 +248,13 @@ class TrainMetrics:
         MoeAggregator); None = the core routes nothing and the record
         carries no 'moe' key."""
         self._moe = block
+
+    def set_core_blocks(self, blocks: Optional[dict]) -> None:
+        """Attach the interval's blocks that a core makes of its own
+        counters ({name: block}: the ``sparse_attn_moe`` core's ``dsa``;
+        telemetry/learning.py CoreAggregator); None = a core that counts
+        nothing of its own, and the record carries no such key."""
+        self._core_blocks = blocks
 
     def set_anakin(self, block: Optional[dict]) -> None:
         """Attach the interval's sharded-anakin block (per-shard env
@@ -463,6 +471,9 @@ class TrainMetrics:
         if self._moe is not None:
             record["moe"] = self._moe
             self._moe = None
+        if self._core_blocks is not None:
+            record.update(self._core_blocks)
+            self._core_blocks = None
         if self._anakin is not None:
             # ONE anakin block per interval (ISSUE 8), consumed like the
             # learning block; emitted before the sentinel pass so the

@@ -334,6 +334,45 @@ class MoeAggregator:
         return {"steps": steps, "layers": layers}
 
 
+class CoreAggregator:
+    """A core's own per-layer counters (``models/cores/__init__.py``: the
+    ``core/`` outputs of each dispatch, its loss among them) between two
+    flushes, summed on the device in float32 (a core may count some 3e7
+    pairs a layer a step, which int32 would overflow within a hundred
+    steps); ``flush`` hands the sums and the steps to the core's
+    ``summarise``, which makes the record's blocks of them."""
+
+    def __init__(self, summarise):
+        self._summarise = summarise
+        self._sums: Optional[Dict[str, Any]] = None
+        self._steps = 0
+
+    def on_dispatch(self, metrics: Dict[str, Any]) -> None:
+        import jax.numpy as jnp
+        own = {k[len("core/"):]: v for k, v in metrics.items()
+               if k.startswith("core/")}
+        if not own:
+            return
+        # (L,) a step, or (K, L) a multi-step dispatch
+        flat = {k: jnp.reshape(v.astype(jnp.float32),
+                               (-1, v.shape[-1])).sum(axis=0)
+                for k, v in own.items()}
+        loss = own["loss"]
+        self._steps += loss.size // loss.shape[-1]
+        self._sums = flat if self._sums is None else {
+            k: self._sums[k] + flat[k] for k in flat}
+
+    def flush(self) -> Optional[dict]:
+        import jax
+        if self._sums is None:
+            return None
+        sums = {k: np.asarray(v, np.float64)
+                for k, v in jax.device_get(self._sums).items()}
+        steps = self._steps
+        self._sums, self._steps = None, 0
+        return self._summarise(sums, steps)
+
+
 def _flatten_rows(values: List[np.ndarray], width: int) -> np.ndarray:
     """Stack per-dispatch histogram outputs — (width,) per step or
     (K, width) per multi-step dispatch — into one (n, width) matrix."""

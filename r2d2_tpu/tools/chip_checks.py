@@ -19,6 +19,7 @@ compiler's (or the assertion's) message.
 Exit code: 0 = all pass, 1 = any FAIL, 2 = no accelerator.
 """
 
+import dataclasses
 import sys
 import time
 
@@ -244,6 +245,15 @@ def run_chip_checks(only: str = "") -> int:
             grouped("outer", rows, 2048, 2 * width))
         add(f"grouped_{cell}_outer_down_r{rows}",
             grouped("outer", rows, width, 2048))
+    # the keye-core cell's first chunk (16,384 positions x 8 x 16 / 128
+    # pairs, 8% more, in tiles of 256) at its widths
+    add("grouped_keye_gate_up_r17920", grouped("weights", 17920, 2048, 1536))
+    add("grouped_keye_down_f32_r17920",
+        grouped("weights", 17920, 768, 2048, jnp.float32))
+    add("grouped_keye_down_last_axis_r17920",
+        grouped("weights_last", 17920, 2048, 768, jnp.float32))
+    add("grouped_keye_outer_down_r17920",
+        grouped("outer", 17920, 768, 2048))
     add("grouped_overflow_chunk_r1024",
         grouped("weights", 1024, 2048, 2816))
     add("grouped_overflow_chunk_outer_r1024",
@@ -251,6 +261,231 @@ def run_chip_checks(only: str = "") -> int:
     add("grouped_acting_r384", grouped("weights", 384, 2048, 2816))
     add("grouped_acting_down_f32_r256",
         grouped("weights", 256, 1792, 2048, jnp.float32))
+
+    # --- the learned sparse attention's kernels (ops/sparse_attention.py)
+    # at the keye-core cell's tiles (queries and keys in blocks of 512,
+    # heads of 128, the indexer's 16 heads of 64 padded to 128 lanes, 8
+    # query heads a key/value head) over a window of 2,048 positions and
+    # 2,560 keys, against the plain jnp forms: the forward and both
+    # backward kernels, the heads' probabilities, the indexer's scores and
+    # their backward, the threshold's bisection against lax.top_k. bf16
+    # operands on both sides: they differ by float32 sums in another order
+    def sparse_kernels(part):
+        def check():
+            rng = fresh_rng()
+            from r2d2_tpu.ops import sparse_attention as sa
+            b, g, n, t, s, e, bq, bk = 1, 4, 8, 2048, 2560, 128, 512, 512
+            offset = s - t
+            bf = jnp.bfloat16
+
+            def normal(*shape):
+                return jnp.asarray(rng.standard_normal(shape), bf)
+
+            def close(got, want, what, atol=2e-2):
+                got, want = (np.asarray(x, np.float32) for x in (got, want))
+                scale = max(float(np.abs(want).max()), 1e-30)
+                np.testing.assert_allclose(got, want, rtol=0,
+                                           atol=atol * scale, err_msg=what)
+
+            pos = np.arange(s) - offset
+            visible = pos[None, :] <= np.arange(t)[:, None]
+            if part == "indexer":
+                q, k = normal(b, 16, t, 64), normal(b, s, 64)
+                w = jnp.asarray(rng.standard_normal((b, 16, t)), jnp.float32)
+                mask = jnp.asarray(visible)[None]
+                close(jnp.where(mask, sa.indexer_pallas(
+                    q, w, k, offset, bq, bk), 0), jnp.where(
+                        mask, sa.indexer_reference(q, w, k), 0), "scores")
+                cot = jnp.where(mask, jnp.asarray(
+                    rng.standard_normal((b, t, s)), jnp.float32), 0)
+                want = jax.vjp(sa.indexer_reference, q, w, k)[1](cot)
+                got = sa.indexer_back_pallas(q, w, k, cot, offset, bq, bk)
+                for x, y, what in zip(got, want, ("dq", "dw", "dk")):
+                    close(x, y, what)
+                return
+            if part == "select":
+                x = jnp.where(jnp.asarray(visible)[None], jnp.asarray(
+                    rng.standard_normal((b, t, s)), jnp.float32), -jnp.inf)
+                want_k = jnp.minimum(jnp.asarray(visible.sum(-1))[None],
+                                     2048).astype(jnp.int32)
+                # a tie at the threshold in every eighth row
+                value, _ = sa.kth_largest_reference(x, want_k, 2048)
+                x = x.at[:, ::8, 600].set(value[:, ::8])
+                got = sa.kth_largest_pallas(x, want_k)
+                want = sa.kth_largest_reference(x, want_k, 2048)
+                for g, w in zip(got, want):
+                    assert np.array_equal(np.asarray(g), np.asarray(w)), \
+                        "the bisection's threshold is not lax.top_k's"
+                return
+            q, k, v = normal(b, g * n, t, e), normal(b, g, s, e), \
+                normal(b, g, s, e)
+            # each query chooses its own key and a random 30% of the rest
+            # of what it sees; a quarter of one block row chooses nothing
+            pick = visible[None] & (rng.random((b, t, s)) < 0.3)
+            pick |= pos[None, None, :] == np.arange(t)[None, :, None]
+            d = jnp.where(jnp.asarray(pick), 0.5, -jnp.inf)
+            scale = e ** -0.5
+            o, lse = sa.sparse_attention_pallas(q, k, v, d, scale, bq, bk)
+            o_ref, lse_ref = sa.attention_reference(q, k, v, d, scale)
+            if part == "forward":
+                close(o, o_ref, "o")
+                close(lse, lse_ref, "lse", 1e-4)
+                close(sa.chosen_probs_pallas(q, k, lse_ref, d, scale, bq, bk),
+                      sa.chosen_probs_reference(q, k, lse_ref, d, scale),
+                      "probs", 1e-3)
+                return
+            do = normal(b, g * n, t, e)
+            dl = jnp.sum(do.astype(jnp.float32) * o_ref.astype(jnp.float32),
+                         -1)
+            want = jax.vjp(lambda q, k, v: sa.attention_reference(
+                q, k, v, d, scale), q, k, v)[1](
+                    (do, jnp.zeros_like(lse_ref)))
+            got = sa.sparse_attention_back_pallas(q, k, v, d, do, lse_ref,
+                                                  dl, scale, bq, bk)
+            for x, y, what in zip(got, want, ("dq", "dk", "dv")):
+                close(x, y, what)
+        return check
+    for part in ("forward", "backward", "indexer", "select"):
+        add(f"sparse_attn_{part}", sparse_kernels(part))
+
+    # --- the keye-core cell's comparison, at its timed sizes, as its runner
+    # makes it (benchmarks/runners/learner_8k.py): the program's loss and Q
+    # against the plain reference (reference/check.py), and its choice of
+    # keys and indexer loss against the reference's, on two sequences of
+    # its full ring; and two controls that must fail it: the same program
+    # with the choice turned off (every query attends every key it sees)
+    # and with its core's products on 8-bit (e4m3) operands. Each prints
+    # its errors and the share of the reference's float32 choice of
+    # (query, key) pairs it chose as well, per layer
+    def keye_core():
+        """The cases, sharing one preparation (the full-size network on its
+        filled ring, two sampled sequences, the reference's outputs) that
+        the first of them to run makes."""
+        made = {}
+
+        def network(core):
+            from r2d2_tpu.models.network import NetworkApply
+            cfg = made["cfg"]
+            return NetworkApply(6, dataclasses.replace(cfg.network, core=core),
+                                cfg.env.frame_stack, cfg.env.frame_height,
+                                cfg.env.frame_width)
+
+        def prepare():
+            if made:
+                return made
+            import tempfile
+            from benchmarks import harness, traffic
+            from benchmarks.reference import check as reference_check
+            from benchmarks.reference import r2d2_keye
+            from r2d2_tpu.runtime.learner_loop import Learner
+            bench = harness.load_benchmark()
+            mix = harness.traffic_doc("learner-8k")
+            cfg = harness.build_config(harness.program_overrides(
+                harness.config_doc(bench, "keye-core"), mix),
+                tempfile.mkdtemp(), 0)
+            made["cfg"] = cfg
+            net = network(cfg.network.core)
+            learner = Learner(cfg, net, 0, seed=0)
+            try:
+                traffic.fill_ring(learner, 6, mix["parameters"]["replay"], 0)
+                batch = reference_check.sample_sequences(learner, 2, 1)
+                params, target = jax.device_get(
+                    (learner.train_state.params,
+                     learner.train_state.target_params))
+            finally:
+                learner.stop_background()
+            fields = {f.name: getattr(batch, f.name)
+                      for f in dataclasses.fields(batch)
+                      if getattr(batch, f.name) is not None}
+            made.update(
+                net=net, batch=batch, fields=fields, params=params,
+                target=target,
+                spec=dataclasses.replace(learner.spec, batch_size=2),
+                reference=jax.device_get(r2d2_keye.from_config(cfg)(
+                    params, target, fields)))
+            return made
+
+        def compared(net, what):
+            from benchmarks.reference import check as reference_check
+            from benchmarks.runners import learner_8k
+            from r2d2_tpu.learner.train_step import make_loss_fn
+            m = prepare()
+            loss, aux = jax.jit(make_loss_fn(net, m["spec"], m["cfg"].optim,
+                                             True))(m["params"], m["target"],
+                                                    m["batch"])
+            program = jax.device_get({"loss": loss, **{
+                k: aux[k] for k in ("priorities", "q_chosen", "abs_td")}})
+            out = reference_check.compare(
+                program, m["reference"], m["batch"].is_weights,
+                reference_check.TOLERANCE["bfloat16"])
+            chosen, kl = learner_8k.program_choice(
+                net, m["spec"], m["params"], m["batch"], True)
+            out = learner_8k.widen(out, learner_8k.compare_choice(
+                chosen, kl, m["reference"], m["fields"], "bfloat16"))
+            found = out["choice"]
+            errors = ", ".join(
+                f"{k} {v:.4g} (limit {part['limits'][k]:.4g})"
+                for part in (out, found) for k, v in part["errors"].items())
+            agreement = ", ".join(f"{x:.6f}"
+                                  for x in found["choice_agreement"])
+            print(f"  keye_core {what}: {errors}; the reference's pairs "
+                  f"chosen too, per layer: {agreement}; indexer loss "
+                  f"{found['indexer_loss_error']:.4g} off, per layer "
+                  f"(program, reference): "
+                  f"{found['indexer_loss_per_layer']}")
+            return out["ok"], errors
+
+        def sound():
+            ok, errors = compared(prepare()["net"], "comparison")
+            assert ok, errors
+
+        def choice_off():
+            core = prepare()["cfg"].network.core
+            ok, _ = compared(network(dataclasses.replace(
+                core, index_topk=1 << 20)), "choice off")
+            assert not ok, "every key attended passed the comparison"
+
+        def eight_bit():
+            from r2d2_tpu.models.cores import experts, sparse_attn_moe
+            from r2d2_tpu.ops import sparse_attention
+            f8 = jnp.float8_e4m3fn
+
+            def coarse(x, dtype):
+                return x.astype(f8).astype(dtype)
+
+            kept = (experts.matmul, experts.grouped_matmul,
+                    sparse_attention.sparse_attention)
+
+            def matmul(spec, x, w, dtype, out_dtype=None):
+                return kept[0](spec, coarse(x, dtype), coarse(w, dtype),
+                               dtype, out_dtype)
+
+            def grouped(rows, weights, sizes, dtype, out_dtype=None,
+                        transposed=False):
+                return kept[1](coarse(rows, dtype), coarse(weights, dtype),
+                               sizes, dtype, out_dtype, transposed)
+
+            def attention(q, k, v, d, *rest):
+                return kept[2](coarse(q, q.dtype), coarse(k, k.dtype),
+                               coarse(v, v.dtype), d, *rest)
+
+            core = prepare()["cfg"].network.core
+            experts.matmul = sparse_attn_moe.matmul = matmul
+            experts.grouped_matmul = grouped
+            sparse_attention.sparse_attention = attention
+            try:
+                ok, _ = compared(network(core), "8-bit operands")
+            finally:
+                experts.matmul = sparse_attn_moe.matmul = kept[0]
+                experts.grouped_matmul = kept[1]
+                sparse_attention.sparse_attention = kept[2]
+            assert not ok, "8-bit operands passed the comparison"
+
+        return {"keye_core_sound": sound,
+                "keye_core_choice_off_fails": choice_off,
+                "keye_core_8bit_fails": eight_bit}
+    for name, fn in keye_core().items():
+        add(name, fn)
 
     # --- the target held in the dtype its forward reads
     # (learner/target.py) at the widths of the benchmark's configurations
