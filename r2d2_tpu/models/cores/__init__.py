@@ -32,8 +32,9 @@ among them ``loss``, a loss of its own at each window position, which the
 learner averages over its learning positions and adds to the TD loss
 (``learner/train_step.py``); such a core gives ``summarise(sums, steps)``,
 the record's blocks made of those counters summed over steps, and may give
-``record_block()``, its own part of the one-shot ``core`` block
-(``sparse_attn_moe``).
+``record_block(batch, window)``, its own part of the one-shot ``core``
+block for the learner's step (``sparse_attn_moe``: the indexer's sizes and
+what its layers' remat keeps).
 
 ``lstm``: R2D2's LSTM (``HoistedLSTM``), the row is (h, c).
 ``mla_moe``: DeepSeek-V3-form layers: latent attention over a stored latent
@@ -73,19 +74,25 @@ def make_core(config: NetworkConfig, dtype):
     return MlaMoeCore(config.core, dtype)
 
 
-def state_block(config: NetworkConfig) -> Dict[str, Any]:
+def state_block(config: NetworkConfig, batch: int,
+                window: int) -> Dict[str, Any]:
     """The record's ``core`` block: the core's kind and its state row by
     kind of part (``state_parts``), so that a reader can tell what a
-    sequence's stored row holds and how much of it."""
+    sequence's stored row holds and how much of it; a core's
+    ``record_block`` adds its own, sized for the learner's train step of
+    ``batch`` windows of ``window`` positions."""
     import jax.numpy as jnp
-    core = make_core(config, jnp.float32)
+
+    from r2d2_tpu.ops.pallas_kernels import resolve_pallas_setting
+    bf16 = resolve_pallas_setting(config.bf16, "network.bf16")
+    core = make_core(config, jnp.bfloat16 if bf16 else jnp.float32)
     block = {"kind": config.core.kind, "state_half": core.state_half,
              "row_bytes": 8 * core.state_half,
              "parts": [{"kind": kind, "layers": layers, "floats": floats,
                         "bytes": 4 * floats}
                        for kind, layers, floats in core.state_parts()]}
     if hasattr(core, "record_block"):
-        block.update(core.record_block())
+        block.update(core.record_block(batch, window))
     return block
 
 

@@ -81,6 +81,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from r2d2_tpu.config import CoreConfig
 from r2d2_tpu.models.cores import experts
@@ -94,6 +95,14 @@ _INIT_OUT = experts.residual_init(48)
 _F32 = jnp.float32
 # Rows of an overflow chunk of the held experts' walk: the other cores'
 CHUNK_ROWS = 1024
+# What a layer's remat keeps for its backward, beside the layer's inputs: the
+# attention's output and log-sum-exp (named in ``sparse_attention``'s
+# forward) and each query's threshold and cut. The backward then runs
+# neither the attention's forward kernel nor the threshold's bisection
+# again; the rest of the layer it recomputes.
+THRESHOLD, CUT = "dsa_threshold", "dsa_cut"
+KEPT = (sparse.OUT, sparse.LSE, THRESHOLD, CUT)
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 
 def row_width(core: CoreConfig) -> int:
@@ -243,6 +252,8 @@ class SparseAttention(nn.Module):
                                   -jnp.inf)
                 value, cut = sparse.kth_largest(
                     shown, jnp.minimum(seen, topk), topk)
+                value = checkpoint_name(value, THRESHOLD)
+                cut = checkpoint_name(cut, CUT)
                 above = shown - value[..., None]
                 # ties at the threshold: the earlier keys, up to k
                 above = jnp.where((above == 0) & (jnp.arange(s)[None, None]
@@ -326,12 +337,16 @@ class Layer(nn.Module):
 
 
 class SparseAttnMoeStack(nn.Module):
-    """Input projection, the layers (each under ``jax.checkpoint``), the
-    final norm; the state row in and out, a layer's ``memory_len`` x
-    ``row_width`` floats after another's. The expert layers' routing
-    counters are sown into the ``moe`` collection, the attention's counters
-    and the indexer's loss into ``core`` (``NetworkApply.apply_learner``
-    reads both)."""
+    """Input projection, the layers (each under ``jax.checkpoint``, which
+    keeps the layer's inputs and the arrays ``KEPT`` names: the attention's
+    output (B, H, T, e) and log-sum-exp (B, H, T), each query's threshold
+    and cut (B, T); the backward recomputes the rest of the layer, the
+    indexer's scores and the heads' probabilities among it, whose (B, T, S)
+    float32 arrays would not fit kept beside the window), the final norm;
+    the state row in and out, a layer's ``memory_len`` x ``row_width``
+    floats after another's. The expert layers' routing counters are sown
+    into the ``moe`` collection, the attention's counters and the indexer's
+    loss into ``core`` (``NetworkApply.apply_learner`` reads both)."""
     core: CoreConfig
     dtype: Any
     window_stats: bool
@@ -350,7 +365,7 @@ class SparseAttnMoeStack(nn.Module):
         for i in range(c.num_hidden_layers):
             stored = flat[:, i * part:(i + 1) * part].reshape(
                 b, c.memory_len, row_width(c))
-            x, new, s, a = nn.remat(Layer)(
+            x, new, s, a = nn.remat(Layer, policy=_KEEP)(
                 c, self.dtype, dense=i < c.first_k_dense_replace,
                 window_stats=self.window_stats, name=f"layers_{i}")(x, stored)
             new_parts.append(new.reshape(b, -1))
@@ -404,15 +419,25 @@ class SparseAttnMoeCore:
         return SparseAttnMoeStack(self.core, self.dtype, window_stats,
                                   name=self.scope)(x_seq, state)
 
-    def record_block(self) -> Dict[str, Any]:
-        """The sparse attention's sizes, for the record's one-shot ``core``
-        block."""
+    def record_block(self, batch: int, window: int) -> Dict[str, Any]:
+        """The sparse attention's sizes, and the bytes of each array
+        ``KEPT`` names that the layers' remat keeps through a train step of
+        ``batch`` windows of ``window`` positions, all layers (the
+        threshold and cut only where a query chooses), for the record's
+        one-shot ``core`` block."""
         c = self.core
+        rows = c.num_hidden_layers * batch * window
+        heads = rows * c.num_attention_heads
+        kept = {sparse.OUT: heads * c.head_dim * np.dtype(self.dtype).itemsize,
+                sparse.LSE: heads * 4}
+        if c.memory_len + window > c.index_topk:
+            kept.update({THRESHOLD: rows * 4, CUT: rows * 4})
         return {"dsa": {"index_topk": c.index_topk,
                         "index_n_heads": c.index_n_heads,
                         "index_head_dim": c.index_head_dim,
                         "q_chunk_size": c.q_chunk_size,
-                        "kv_chunk_size": c.kv_chunk_size}}
+                        "kv_chunk_size": c.kv_chunk_size},
+                "remat": {"kept": kept, "bytes": sum(kept.values())}}
 
     def summarise(self, sums: Dict[str, np.ndarray], steps: int
                   ) -> Dict[str, Any]:

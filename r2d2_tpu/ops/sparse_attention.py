@@ -32,7 +32,9 @@ queries x keys x heads: a block's scores live in VMEM, per head.
     and each (head, query)'s log-sum-exp; its backward is two kernels, the
     queries' gradient and the keys' and values' (flash attention's). A
     block of ``bq`` x ``bk`` (query, key) pairs that holds no chosen pair is
-    skipped (``blocks_live``), forward and backward.
+    skipped (``blocks_live``), forward and backward. Under a gradient the
+    output and the log-sum-exp carry the names ``OUT`` and ``LSE``, which a
+    remat policy may keep (the sparse core's does).
   * ``chosen_probs``: the mean over the H heads of each head's attention
     probability of (query, key), zero off the chosen: the distribution the
     indexer's loss holds its scores to. No gradient.
@@ -42,6 +44,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _F32 = jnp.float32
 LANES = 128
@@ -52,6 +55,8 @@ _VMEM_BYTES = 64 * 2**20
 _TRANS_B = (((1,), (1,)), ((), ()))
 # rows of a query block the threshold's bisection holds at once
 SELECT_ROWS = 64
+# the names of ``sparse_attention``'s output and log-sum-exp under a gradient
+OUT, LSE = "sparse_attn_out", "sparse_attn_lse"
 
 
 def _lanes(x):
@@ -607,7 +612,13 @@ def sparse_attention(q, k, v, d, scale: float, bq: int, bk: int):
 
 
 def _attention_fwd(q, k, v, d, scale, bq, bk):
+    """The forward under a gradient: the output and the log-sum-exp (B, H,
+    T), which the backward reads, named here on the residuals themselves,
+    so that a remat policy that keeps ``OUT`` and ``LSE`` hands the backward
+    these arrays and the forward kernel does not run again in it (a name on
+    the values the caller receives would not reach the residuals)."""
     o, lse = sparse_attention(q, k, v, d, scale, bq, bk)
+    o, lse = checkpoint_name(o, OUT), checkpoint_name(lse, LSE)
     return (o, lse), (q, k, v, d, o, lse)
 
 

@@ -286,7 +286,8 @@ class Learner:
         # what a sequence's stored state row holds (record block 'core', on
         # the run's first record)
         from r2d2_tpu.models.cores import state_block
-        self.metrics.set_core(state_block(cfg.network))
+        self.metrics.set_core(state_block(
+            net.config, cfg.replay.batch_size, cfg.sequence.seq_len))
         # the dtypes the target is held in (record block 'target', first
         # record): what the plan narrowed, and the bytes it holds
         from r2d2_tpu.learner.target import target_block

@@ -72,8 +72,9 @@ def test_a_row_round_trips_through_its_parts(rng):
 def test_record_block_tells_the_kinds_of_part_apart(kind, parts):
     cfg = (Config().replace(**TINY_ENV) if kind == "lstm"
            else tiny_config(kind))
-    block = state_block(cfg.network)
-    assert block["kind"] == kind
+    block = state_block(cfg.network, cfg.replay.batch_size,
+                        cfg.sequence.seq_len)
+    assert block["kind"] == kind and "remat" not in block
     assert block["parts"] == [
         {"kind": k, "layers": n, "floats": f, "bytes": 4 * f}
         for k, n, f in parts]

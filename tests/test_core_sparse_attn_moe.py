@@ -5,9 +5,11 @@ backward; the exact top-k threshold and its ties; the configuration keys and
 the state row; the actor's step against the window; the indexer learning
 from its loss alone; the softmax router's shares against the uncut layer;
 the sigmoid cores' programs left as they were; and the core on the normal
-path (``cli.train``). The comparison with the plain reference is in
+path (``cli.train``); what the layers' remat keeps, against the remat that
+keeps nothing. The comparison with the plain reference is in
 ``tests/benchmarks/test_bm_keye.py``. Tiny sizes, CPU, seeded weights."""
 
+import collections
 import dataclasses
 import hashlib
 import json
@@ -205,7 +207,7 @@ def test_state_row_and_record_block():
     cfg = tiny_config()
     # 2 layers x 4 positions x (2 x 2 heads x 8 + 8 indexer) = 320 floats
     assert state_half(cfg.network) == 160
-    block = state_block(cfg.network)
+    block = state_block(cfg.network, 2, 37)
     assert block["parts"] == [
         {"kind": "key_value_window", "layers": 2, "floats": 256,
          "bytes": 1024},
@@ -451,3 +453,134 @@ def test_cli_train_trains_and_acts_with_the_benchmarks_overrides(tmp_path):
     assert [p["kind"] for p in core_block["parts"]] == [
         "key_value_window", "indexer_keys"]
     assert core_block["dsa"]["index_topk"] == 8
+
+
+# -- what the layers' remat keeps ---------------------------------------------
+
+
+def _loss_of(net, obs, action, state, seed=2):
+    """A loss of Q, the window's new row and the indexer's loss, each
+    weighted at random, so that every part of the backward has work."""
+    def loss(p):
+        q, row, counters = net.apply_learner(p, obs, action, state)
+        kl = counters["core"]["loss"]
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return (jnp.sum(jax.random.normal(ks[0], q.shape) * q)
+                + jnp.sum(jax.random.normal(ks[1], row.shape) * row)
+                + jnp.sum(jax.random.uniform(ks[2], kl.shape) * kl))
+    return loss
+
+
+def test_the_kept_remat_gives_the_gradients_of_the_remat_keeping_nothing(
+        monkeypatch):
+    """The layers under the policy that keeps ``KEPT`` give, on the plain
+    path, the loss and every parameter's gradient (the indexer's, which
+    its loss alone reaches, among them) that the same layers give under a
+    remat that keeps nothing but their inputs, to float32 rounding."""
+    from r2d2_tpu.models.cores import sparse_attn_moe
+    cfg = tiny_config()
+    net = tiny_net(cfg)
+    params = net.init(jax.random.PRNGKey(0))
+    obs, action, _ = inputs(jax.random.PRNGKey(1), 2, 37, net)
+    # a stored prefix of random rows, so that keys before the window count
+    state = jax.random.normal(jax.random.PRNGKey(3), net.init_state(2).shape)
+    loss = _loss_of(net, obs, action, state)
+    kept = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(sparse_attn_moe, "_KEEP", None)
+    plain = jax.jit(jax.value_and_grad(loss))(params)
+    _close(kept[0], plain[0])
+    indexer = 0
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(kept[1]),
+                                 jax.tree_util.tree_leaves(plain[1])):
+        name = jax.tree_util.keystr(path)
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        _close(got, want)
+        indexer += "'indexer'" in name and float(jnp.abs(want).max()) > 0
+    # both layers' five indexer leaves learn, from the indexer's loss
+    assert indexer == 2 * 5
+
+
+# the smallest core whose windows the kernels tile: heads of 128 lanes, 64
+# stored positions + a window of 64 in one key block of 128, query blocks
+# of 16, the bisection's 64 rows; a query that sees more than 16 keys chooses
+TILED_CORE = {**TINY_SPARSE_CORE, "num_attention_heads": 2,
+              "num_key_value_heads": 1, "head_dim": 128, "memory_len": 64,
+              "index_topk": 16, "q_chunk_size": 16, "kv_chunk_size": 128}
+
+
+def _kernel_calls(jaxpr) -> collections.Counter:
+    """The Pallas calls in ``jaxpr`` and the programs inside it, by name."""
+    found = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[str(eqn.params["name"])] += 1
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.update(_kernel_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("policy", ["kept", "nothing"])
+def test_the_backward_runs_no_attention_forward_and_no_bisection_again(
+        policy, monkeypatch):
+    """In the gradient's program at shapes the kernels tile, each layer
+    holds one call of the attention's forward kernel and one of the
+    threshold's bisection, the forward's: the backward reads the output,
+    the log-sum-exp, the threshold and the cut the layer's remat kept.
+    Under a remat that keeps nothing each runs again in the backward. The
+    indexer's scores and the heads' probabilities are recomputed either
+    way; the attention's backward kernels run once."""
+    from r2d2_tpu.models.cores import sparse_attn_moe
+    if policy == "nothing":
+        monkeypatch.setattr(sparse_attn_moe, "_KEEP", None)
+    core = CoreConfig(**TILED_CORE)
+    stack = sparse_attn_moe.SparseAttnMoeStack(core, jnp.float32, True)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 12))
+    state = jnp.zeros((1, 2, state_half(dataclasses.replace(
+        Config().network, core=core))))
+    params = stack.init(jax.random.PRNGKey(1), x, state)["params"]
+
+    def loss(p):
+        (y, _), sown = stack.apply({"params": p}, x, state,
+                                   mutable=["core", "moe", "intermediates"])
+        return jnp.sum(y ** 2) + jnp.sum(sown["core"]["counters"][0]["loss"])
+
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    layers, again = core.num_hidden_layers, 1 if policy == "kept" else 2
+    assert calls["sparse_attn"] == calls["dsa_select"] == again * layers
+    assert calls["dsa_probs"] == calls["dsa_indexer"] == 2 * layers
+    assert calls["sparse_attn_dq"] == calls["sparse_attn_dkv"] == layers
+    assert calls["dsa_indexer_back"] == layers
+
+
+def test_the_core_block_names_what_the_remat_keeps_and_its_bytes():
+    """The record's one-shot ``core`` block names the arrays the layers'
+    remat keeps and gives their bytes through a train step, all layers:
+    at the tiny size float32, and at the cell's sizes in bf16, where the
+    output is 128 MiB a layer and the log-sum-exp 2 MiB; a window in
+    which no query chooses keeps no threshold."""
+    from r2d2_tpu.models.cores.sparse_attn_moe import CUT, KEPT, THRESHOLD
+    assert KEPT == (sa.OUT, sa.LSE, THRESHOLD, CUT)
+    block = state_block(tiny_config().network, 2, 37)["remat"]
+    # 2 layers x 2 windows x 37 positions, 4 heads of 8, float32
+    assert block == {"kept": {sa.OUT: 2 * 2 * 37 * 4 * 8 * 4,
+                              sa.LSE: 2 * 2 * 37 * 4 * 4,
+                              THRESHOLD: 2 * 2 * 37 * 4,
+                              CUT: 2 * 2 * 37 * 4},
+                     "bytes": 2 * 2 * 37 * (4 * 8 * 4 + 4 * 4 + 8)}
+    assert list(block["kept"]) == list(KEPT)
+    # 4 + 3 positions a query sees at most: no query chooses
+    short = state_block(tiny_config().network, 2, 3)["remat"]["kept"]
+    assert list(short) == [sa.OUT, sa.LSE]
+    full = CoreConfig(kind=KIND, num_hidden_layers=4, num_attention_heads=32,
+                      num_key_value_heads=4, head_dim=128, memory_len=128,
+                      n_routed_experts=128, num_experts_per_tok=8,
+                      experts_held=16, index_n_heads=16, index_head_dim=64,
+                      index_topk=2048, q_chunk_size=512, kv_chunk_size=512)
+    cell = dataclasses.replace(Config().network, core=full, bf16="on")
+    kept = state_block(cell, 2, 8192)["remat"]["kept"]
+    assert kept == {sa.OUT: 4 * 128 * 2**20, sa.LSE: 4 * 2 * 2**20,
+                    THRESHOLD: 4 * 64 * 2**10, CUT: 4 * 64 * 2**10}
