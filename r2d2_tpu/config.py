@@ -115,6 +115,30 @@ class CoreConfig:
     choice) or "softmax" over all routed experts (Qwen3-MoE, Keye: no bias;
     the chosen weights renormalised over the chosen, no epsilon).
 
+    ``"kda_mla_moe"``: Kimi-Linear-form layers
+    (https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json,
+    ``model_type: kimi_linear``; models/cores/kda_mla_moe.py), each Kimi
+    Delta Attention (a gated delta rule with a decay for each key channel)
+    or the latent attention of ``mla_moe`` by ``layer_types`` ("kda" /
+    "full_attention", one name a layer; the source's
+    ``linear_attn_config.kda_layers`` and ``full_attn_layers``), then a
+    dense SwiGLU (the first ``first_k_dense_replace`` layers) or the
+    sigmoid-routed experts with ``n_shared_experts`` shared. It reads
+    ``kda_num_heads`` x ``kda_head_dim`` and ``short_conv_kernel_size``
+    (``linear_attn_config``'s ``num_heads``, ``head_dim``,
+    ``short_conv_kernel_size``), ``kda_chunk_size`` (the positions of one
+    chunk of the scan: a choice of compute, not a width), ``mla_use_nope``
+    and ``q_chunk_size`` beside the latent attention's keys. Its own keys
+    default to 0, which it refuses: a configuration spells every size
+    (benchmarks/configs/kimi-linear-core.json).
+
+    ``mla_use_nope`` (``mla_moe`` and ``kda_mla_moe``): the latent attention
+    rotates neither q's nor k's ``qk_rope_head_dim`` dimensions (the
+    source's NoPE form). ``q_chunk_size`` in those two kinds: where it is
+    above 0 and below a window's length and divides it, the NoPE form
+    computes its attention in blocks of that many queries, each block's
+    scores recomputed in the backward pass (0, the default: all at once).
+
     Three keys are this program's own: ``experts_held`` / ``expert_offset``
     (the router scores all ``n_routed_experts`` and picks
     ``num_experts_per_tok`` of them; this chip computes the experts
@@ -153,15 +177,21 @@ class CoreConfig:
     index_topk: int = 0
     q_chunk_size: int = 0
     kv_chunk_size: int = 0
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    short_conv_kernel_size: int = 0
+    kda_chunk_size: int = 0
+    mla_use_nope: bool = False
 
     def __post_init__(self):
         # a JSON list (a checkpoint's .config.json) hashes as a tuple
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.kind not in ("lstm", "mla_moe", "conv_attn_moe",
-                             "sparse_attn_moe"):
+                             "sparse_attn_moe", "kda_mla_moe"):
             raise ValueError(
                 f"network.core.kind ({self.kind!r}) must be 'lstm', "
-                "'mla_moe', 'conv_attn_moe' or 'sparse_attn_moe'")
+                "'mla_moe', 'conv_attn_moe', 'sparse_attn_moe' or "
+                "'kda_mla_moe'")
         if self.kind == "lstm":
             return
         if self.scoring_func not in ("sigmoid", "softmax"):
@@ -182,10 +212,30 @@ class CoreConfig:
             raise ValueError(
                 "network.core: memory_len and num_hidden_layers must be at "
                 "least 1")
-        if self.kind == "mla_moe":
+        if self.kind in ("mla_moe", "kda_mla_moe"):
             if self.qk_rope_head_dim % 2:
                 raise ValueError(
                     "network.core.qk_rope_head_dim must be even")
+            if self.q_chunk_size < 0:
+                raise ValueError("network.core.q_chunk_size must be at "
+                                 "least 0")
+        if self.kind == "mla_moe":
+            return
+        if self.kind == "kda_mla_moe":
+            if len(self.layer_types) != self.num_hidden_layers or any(
+                    kind not in ("kda", "full_attention")
+                    for kind in self.layer_types):
+                raise ValueError(
+                    f"network.core.layer_types ({self.layer_types!r}) must "
+                    f"name each of the {self.num_hidden_layers} layers "
+                    "'kda' or 'full_attention'")
+            if min(self.kda_num_heads, self.kda_head_dim,
+                   self.kda_chunk_size) < 1 \
+                    or self.short_conv_kernel_size < 2:
+                raise ValueError(
+                    "network.core: kda_num_heads, kda_head_dim and "
+                    "kda_chunk_size must be at least 1, "
+                    "short_conv_kernel_size at least 2")
             return
         if self.kind == "sparse_attn_moe":
             heads, groups = self.num_attention_heads, self.num_key_value_heads

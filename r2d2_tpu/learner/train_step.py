@@ -210,18 +210,21 @@ def make_loss_fn(net: NetworkApply, spec: ReplaySpec, optim: OptimConfig,
             priorities = mixed_td_errors_masked(jnp.abs(td), mask,
                                                 optim.priority_eta)
             if own is not None:
-                # a core's own loss (models/cores/__init__.py), per layer its
-                # mean over the valid learning positions, as the TD loss is
-                # taken. Its gradient joins the TD loss's under the one
-                # clip; the value returned stays the TD loss, which the
-                # priorities and the reference check are made of, and the
-                # core's is reported beside it
-                own = dict(own)
-                at = jnp.take_along_axis(own.pop("loss"), opos[None], axis=2)
-                core_loss = jnp.sum(at * mask[None], axis=(1, 2)) / num_valid
-                loss = loss + (jnp.sum(core_loss)
-                               - jax.lax.stop_gradient(jnp.sum(core_loss)))
-                own["loss"] = core_loss
+                # a core's own counters (models/cores/__init__.py): those
+                # per position (layers, B, T), its loss among them, per
+                # layer their mean over the valid learning positions, as
+                # the TD loss is taken. The loss's gradient joins the TD
+                # loss's under the one clip; the value returned stays the
+                # TD loss, which the priorities and the reference check are
+                # made of, and the core's is reported beside it
+                own = {name: jnp.sum(jnp.take_along_axis(
+                    value, opos[None], axis=2) * mask[None],
+                    axis=(1, 2)) / num_valid if value.ndim == 3 else value
+                    for name, value in own.items()}
+                if "loss" in own:
+                    core_loss = jnp.sum(own["loss"])
+                    loss = loss + (core_loss
+                                   - jax.lax.stop_gradient(core_loss))
         aux = {
             "priorities": priorities,
             "mean_abs_td": jnp.sum(jnp.abs(td)) / num_valid,

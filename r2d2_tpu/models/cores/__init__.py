@@ -29,9 +29,11 @@ as (kind, layers that keep such a part, floats in all of them), which the
 record's ``core`` block carries (``state_block``). A core may sow, in the
 learner's call, counters of its own per layer into the ``core`` collection,
 among them ``loss``, a loss of its own at each window position, which the
-learner averages over its learning positions and adds to the TD loss
-(``learner/train_step.py``); such a core gives ``summarise(sums, steps)``,
-the record's blocks made of those counters summed over steps, and may give
+learner adds to the TD loss (``learner/train_step.py``; a counter of shape
+(layers, B, T), ``loss`` or another, is taken as its mean over the
+learning positions, one of shape (layers,) as it is); such a core gives
+``summarise(sums, steps)``, the record's blocks made of those counters
+summed over steps, and may give
 ``record_block(batch, window)``, its own part of the one-shot ``core``
 block for the learner's step (``sparse_attn_moe``: the indexer's sizes and
 what its layers' remat keeps).
@@ -51,7 +53,14 @@ reads, for each query, the keys a learned indexer chooses (DeepSeek-V3.2's
 sparse attention, Keye-VL-2.0's sizes), with the held experts routed by a
 softmax (``sparse_attn_moe.py``). It is the first core with a loss of its
 own: the indexer's, sown per position beside the attention's counters.
-The three stacks share ``experts.py``: router, held experts, counters.
+``kda_mla_moe``: Kimi-Linear-form layers: Kimi Delta Attention (a gated
+delta rule, its e x e state a head carried in the row) beside the latent
+attention of ``mla_moe`` in its NoPE form by ``layer_types``, with
+``mla_moe``'s experts (``kda_mla_moe.py``). Its counters per KDA layer
+(decays, beta, the state's norm) are sown per position into ``core`` with
+no loss of its own, and the learner takes their means over its learning
+positions as it takes a loss's.
+The four stacks share ``experts.py``: router, held experts, counters.
 """
 
 from typing import Any, Dict
@@ -70,6 +79,9 @@ def make_core(config: NetworkConfig, dtype):
     if config.core.kind == "sparse_attn_moe":
         from r2d2_tpu.models.cores.sparse_attn_moe import SparseAttnMoeCore
         return SparseAttnMoeCore(config.core, dtype)
+    if config.core.kind == "kda_mla_moe":
+        from r2d2_tpu.models.cores.kda_mla_moe import KdaMlaMoeCore
+        return KdaMlaMoeCore(config.core, dtype)
     from r2d2_tpu.models.cores.mla_moe import MlaMoeCore
     return MlaMoeCore(config.core, dtype)
 

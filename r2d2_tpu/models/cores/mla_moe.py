@@ -115,22 +115,49 @@ class LatentAttention(nn.Module):
         keys = jnp.concatenate([mem, rows], axis=1)               # (B,M+T,.)
         key_pos = jnp.arange(-m, t)
         kvb = matmul("bsc,che->bshe", keys[..., :dc], w_kvb, dt)
-        k_rot = rope(keys[..., dc:], key_pos, c.rope_theta)       # (B,S,dr)
-        q_rot = rope(q[..., dn:], jnp.arange(t), c.rope_theta)    # (B,T,H,dr)
-        scores = (matmul("bthe,bshe->bhts", q[..., :dn], kvb[..., :dn], dt,
-                         _F32)
-                  + matmul("bthe,bse->bhts", q_rot, k_rot, dt, _F32))
-        scores = scores * (dn + dr) ** -0.5
-        # a window step sees the filled slots of the stored cache and the
-        # window up to itself
-        filled = jnp.any(mem != 0, axis=-1)                       # (B,M)
-        causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-        seen = jnp.concatenate(
-            [jnp.broadcast_to(filled[:, None, :], (h.shape[0], t, m)),
-             jnp.broadcast_to(causal[None], (h.shape[0], t, t))], axis=-1)
-        scores = jnp.where(seen[:, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        values = matmul("bhts,bshe->bthe", probs, kvb[..., dn:], dt)
+        if c.mla_use_nope:
+            k_rot, q_rot = keys[..., dc:], q[..., dn:]
+        else:
+            k_rot = rope(keys[..., dc:], key_pos, c.rope_theta)   # (B,S,dr)
+            q_rot = rope(q[..., dn:], jnp.arange(t),
+                         c.rope_theta)                    # (B,T,H,dr)
+
+        def attend(q_nope, q_rot, at=None):
+            """The values (B, Q, H, dv) of the queries at window steps
+            ``at`` (Q,), or of the whole window."""
+            scores = (matmul("bthe,bshe->bhts", q_nope, kvb[..., :dn], dt,
+                             _F32)
+                      + matmul("bthe,bse->bhts", q_rot, k_rot, dt, _F32))
+            scores = scores * (dn + dr) ** -0.5
+            # a window step sees the filled slots of the stored cache and
+            # the window up to itself
+            filled = jnp.any(mem != 0, axis=-1)                   # (B,M)
+            at = jnp.arange(t) if at is None else at
+            causal = at[:, None] >= jnp.arange(t)[None, :]
+            n_q = at.shape[0]
+            seen = jnp.concatenate(
+                [jnp.broadcast_to(filled[:, None, :], (h.shape[0], n_q, m)),
+                 jnp.broadcast_to(causal[None], (h.shape[0], n_q, t))],
+                axis=-1)
+            scores = jnp.where(seen[:, None], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+            return matmul("bhts,bshe->bthe", probs, kvb[..., dn:], dt)
+
+        block = c.q_chunk_size
+        if c.mla_use_nope and 0 < block < t and t % block == 0:
+            # in blocks of queries, each block's scores recomputed in the
+            # backward pass: no (B, H, T, M + T) array is held
+            def split(x):
+                return jnp.moveaxis(x.reshape((x.shape[0], t // block, block)
+                                              + x.shape[2:]), 1, 0)
+            values = jax.lax.map(
+                lambda xs: jax.checkpoint(attend)(*xs),
+                (split(q[..., :dn]), split(q_rot),
+                 jnp.arange(t).reshape(-1, block)))
+            values = jnp.moveaxis(values, 0, 1).reshape(
+                (h.shape[0], t) + values.shape[3:])
+        else:
+            values = attend(q[..., :dn], q_rot)
         return matmul("bthe,hed->btd", values, w_o, dt, _F32), rows
 
 

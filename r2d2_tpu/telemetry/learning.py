@@ -357,8 +357,8 @@ class CoreAggregator:
         flat = {k: jnp.reshape(v.astype(jnp.float32),
                                (-1, v.shape[-1])).sum(axis=0)
                 for k, v in own.items()}
-        loss = own["loss"]
-        self._steps += loss.size // loss.shape[-1]
+        first = next(iter(own.values()))
+        self._steps += first.size // first.shape[-1]
         self._sums = flat if self._sums is None else {
             k: self._sums[k] + flat[k] for k in flat}
 

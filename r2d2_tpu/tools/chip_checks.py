@@ -487,6 +487,276 @@ def run_chip_checks(only: str = "") -> int:
     for name, fn in keye_core().items():
         add(name, fn)
 
+    # --- Kimi Delta Attention's chunked scan (ops/kda.py) at the
+    # kimi-linear-core cell's tiles (heads of 128, chunks of 64) over a
+    # window that is not whole chunks, against the per-position recurrence
+    # on the same bf16 inputs: the forward kernel's output and last state,
+    # and the backward kernel's six gradients. Decays drawn as the layer's
+    # initialisation draws them, a quarter of the channels far faster (a
+    # chunk forgets them) and some at none
+    def kda_kernels(part):
+        def check():
+            rng = fresh_rng()
+            from r2d2_tpu.ops import kda
+            b, h, t, e, chunk = 1, 4, 1000, 128, 64
+            bf = jnp.bfloat16
+
+            def unit(*shape):
+                x = rng.standard_normal(shape)
+                return jnp.asarray(x / np.linalg.norm(x, axis=-1,
+                                                      keepdims=True), bf)
+
+            q, k = unit(b, h, t, e), unit(b, h, t, e)
+            v = jnp.asarray(rng.standard_normal((b, h, t, e)), bf)
+            rate = np.exp(rng.uniform(np.log(1e-3), np.log(0.1),
+                                      (b, h, t, e)))
+            rate[..., : e // 4] *= 400.0
+            rate[..., e // 4: e // 4 + 8] = 0.0
+            g = jnp.asarray(-rng.uniform(1.0, 16.0, (1, h, 1, 1)) * rate,
+                            jnp.float32)
+            beta = jnp.asarray(rng.uniform(0.0, 1.0, (b, h, t)), jnp.float32)
+            s0 = jnp.asarray(rng.standard_normal((b, h, e, e)) * 0.1,
+                             jnp.float32)
+
+            def close(got, want, what, atol=2e-3):
+                got, want = (np.asarray(x, np.float32) for x in (got, want))
+                assert np.isfinite(got).all(), what
+                scale = max(float(np.abs(want).max()), 1e-30)
+                np.testing.assert_allclose(got, want, rtol=0,
+                                           atol=atol * scale, err_msg=what)
+
+            o, s, states = kda.chunked_forward(q, k, v, g, beta, s0, chunk)
+            o_ref, s_ref = jax.jit(kda.recurrent)(q, k, v, g, beta, s0)
+            if part == "forward":
+                close(o, o_ref, "o")
+                close(s, s_ref, "state")
+                return
+            do = jnp.asarray(rng.standard_normal(o_ref.shape), jnp.float32)
+            ds = jnp.asarray(rng.standard_normal(s_ref.shape), jnp.float32)
+            want = jax.jit(lambda *x: jax.vjp(kda.recurrent, *x)[1](
+                (do, ds)))(q, k, v, g, beta, s0)
+            got = kda.chunked_backward(q, k, v, g, beta, states, do, ds,
+                                       chunk)
+            for x, y, what in zip(got, want,
+                                  ("dq", "dk", "dv", "dg", "dbeta", "ds0")):
+                close(x, y, what, 1e-2 if x.dtype == bf else 2e-3)
+        return check
+    for part in ("forward", "backward"):
+        add(f"kda_scan_{part}", kda_kernels(part))
+
+    # --- the kimi-linear-core cell's comparison, at its timed sizes, as
+    # its runner makes it (benchmarks/runners/learner_8k_state.py): the
+    # program's loss and Q against the plain reference (reference/
+    # check.py), and the state row the window leaves, by part, on two
+    # sequences of its full ring; and three controls that must fail it: the
+    # scan with each chunk started from S = 0 (the chunks' carry dropped),
+    # with the decays ignored (exp(g) = 1), and the core's products on
+    # 8-bit (e4m3) operands. Each prints its errors against their limits.
+    # Then the cell's step program, compiled for this chip as Learner
+    # builds it, read for what XLA did to fit it
+    def kimi_core():
+        made = {}
+
+        def prepare():
+            if made:
+                return made
+            import tempfile
+            from benchmarks import harness, traffic
+            from benchmarks.reference import check as reference_check
+            from benchmarks.reference import r2d2_kimi
+            from r2d2_tpu.models.network import NetworkApply
+            from r2d2_tpu.runtime.learner_loop import Learner
+            bench = harness.load_benchmark()
+            mix = harness.traffic_doc("learner-8k-state")
+            cfg = harness.build_config(harness.program_overrides(
+                harness.config_doc(bench, "kimi-linear-core"), mix),
+                tempfile.mkdtemp(), 0)
+            net = NetworkApply(6, cfg.network, cfg.env.frame_stack,
+                               cfg.env.frame_height, cfg.env.frame_width)
+            learner = Learner(cfg, net, 0, seed=0)
+            try:
+                traffic.fill_ring(learner, 6, mix["parameters"]["replay"], 0)
+                batch = reference_check.sample_sequences(learner, 2, 1)
+                params, target = jax.device_get(
+                    (learner.train_state.params,
+                     learner.train_state.target_params))
+            finally:
+                learner.stop_background()
+            fields = {f.name: getattr(batch, f.name)
+                      for f in dataclasses.fields(batch)
+                      if getattr(batch, f.name) is not None}
+            made.update(
+                cfg=cfg, net=net, batch=batch, params=params, target=target,
+                spec=dataclasses.replace(learner.spec, batch_size=2),
+                reference=jax.device_get(r2d2_kimi.from_config(cfg)(
+                    params, target, fields)))
+            return made
+
+        def compared(what):
+            from benchmarks.reference import check as reference_check
+            from benchmarks.runners import learner_8k_state as runner
+            from r2d2_tpu.learner.train_step import make_loss_fn
+            m = prepare()
+            net = m["net"]
+            loss, aux = jax.jit(make_loss_fn(net, m["spec"], m["cfg"].optim,
+                                             True))(m["params"], m["target"],
+                                                    m["batch"])
+            program = jax.device_get({"loss": loss, **{
+                k: aux[k] for k in ("priorities", "q_chosen", "abs_td")}})
+            out = reference_check.compare(
+                program, m["reference"], m["batch"].is_weights,
+                reference_check.TOLERANCE["bfloat16"])
+            row = runner.program_row(net, m["spec"], m["params"], m["batch"],
+                                     True)
+            out = runner.widen(out, runner.compare_state(
+                row, m["reference"], dataclasses.asdict(m["cfg"].network.core),
+                "bfloat16"))
+            found = out["state"]
+            errors = ", ".join(
+                f"{k} {v:.4g} (limit {part['limits'][k]:.4g})"
+                for part in (out, found) for k, v in part["errors"].items())
+            print(f"  kimi_core {what}: {errors}; the state's errors per "
+                  f"layer: {found['per_layer']}")
+            return out["ok"], errors
+
+        def with_scan(scan, what):
+            from r2d2_tpu.ops import kda
+            kept = kda.kda_scan
+            kda.kda_scan = scan
+            try:
+                return compared(what)
+            finally:
+                kda.kda_scan = kept
+
+        def sound():
+            ok, errors = compared("comparison")
+            assert ok, errors
+
+        def carry_off():
+            from r2d2_tpu.ops import kda
+            kept = kda.kda_scan
+
+            def scan(q, k, v, g, beta, s0, chunk):
+                b, h, t, e = q.shape
+                n = -(-t // chunk)
+
+                def split(x):
+                    x = jnp.pad(x, [(0, 0), (0, 0), (0, n * chunk - t)]
+                                + [(0, 0)] * (x.ndim - 3))
+                    x = x.reshape((b, h, n, chunk) + x.shape[3:])
+                    return jnp.moveaxis(x, 2, 1).reshape(
+                        (b * n, h, chunk) + x.shape[4:])
+
+                o, s = kept(split(q), split(k), split(v), split(g),
+                            split(beta), jnp.zeros((b * n,) + s0.shape[1:],
+                                                   s0.dtype), chunk)
+                o = jnp.moveaxis(o.reshape(b, n, h, chunk, -1), 1, 2)
+                return (o.reshape(b, h, n * chunk, -1)[:, :, :t],
+                        s.reshape((b, n) + s.shape[1:])[:, -1])
+
+            ok, _ = with_scan(scan, "chunks' carry dropped")
+            assert not ok, "the chunks' carry dropped passed the comparison"
+
+        def decay_off():
+            from r2d2_tpu.ops import kda
+            kept = kda.kda_scan
+
+            def scan(q, k, v, g, beta, s0, chunk):
+                return kept(q, k, v, jnp.zeros_like(g), beta, s0, chunk)
+
+            ok, _ = with_scan(scan, "decay ignored")
+            assert not ok, "the decay ignored passed the comparison"
+
+        def eight_bit():
+            from r2d2_tpu.models.cores import experts, kda_mla_moe, mla_moe
+            from r2d2_tpu.ops import kda
+            f8 = jnp.float8_e4m3fn
+
+            def coarse(x, dtype):
+                return x.astype(f8).astype(dtype)
+
+            kept = (experts.matmul, experts.grouped_matmul, kda.kda_scan)
+
+            def matmul(spec, x, w, dtype, out_dtype=None):
+                return kept[0](spec, coarse(x, dtype), coarse(w, dtype),
+                               dtype, out_dtype)
+
+            def grouped(rows, weights, sizes, dtype, out_dtype=None,
+                        transposed=False):
+                return kept[1](coarse(rows, dtype), coarse(weights, dtype),
+                               sizes, dtype, out_dtype, transposed)
+
+            def scan(q, k, v, *rest):
+                return kept[2](coarse(q, q.dtype), coarse(k, k.dtype),
+                               coarse(v, v.dtype), *rest)
+
+            experts.matmul = mla_moe.matmul = kda_mla_moe.matmul = matmul
+            experts.grouped_matmul = grouped
+            kda.kda_scan = scan
+            try:
+                ok, _ = compared("8-bit operands")
+            finally:
+                experts.matmul = mla_moe.matmul = kept[0]
+                kda_mla_moe.matmul = kept[0]
+                experts.grouped_matmul = kept[1]
+                kda.kda_scan = kept[2]
+            assert not ok, "8-bit operands passed the comparison"
+
+        def step_compile():
+            import re
+            from benchmarks import harness
+            from r2d2_tpu.learner.train_step import (create_train_state,
+                                                     make_multi_learner_step)
+            from r2d2_tpu.models.network import NetworkApply
+            from r2d2_tpu.replay.device_replay import replay_init
+            from r2d2_tpu.replay.structs import ReplaySpec
+            from r2d2_tpu.telemetry.learning import LearningDiag
+            from r2d2_tpu.telemetry.replaydiag import ReplayDiag
+            bench = harness.load_benchmark()
+            mix = harness.traffic_doc("learner-8k-state")
+            cfg = harness.build_config(harness.program_overrides(
+                harness.config_doc(bench, "kimi-linear-core"), mix), "", 0)
+            spec = ReplaySpec.from_config(cfg)
+            net = NetworkApply(6, cfg.network, cfg.env.frame_stack,
+                               cfg.env.frame_height, cfg.env.frame_width)
+            train = jax.eval_shape(lambda: create_train_state(
+                jax.random.PRNGKey(0), net, cfg.optim))
+            ring = jax.eval_shape(lambda: replay_init(spec))
+            step = make_multi_learner_step(
+                net, spec, cfg.optim, cfg.network.use_double,
+                cfg.runtime.resolved_steps_per_dispatch(),
+                diag=LearningDiag.from_config(cfg),
+                rdiag=ReplayDiag.from_config(cfg))
+            compiled = step.lower(train, ring).compile()
+            memory = compiled.memory_analysis()
+            text = compiled.as_text()
+            remat = re.findall(r"%(\S+\.remat\d*) = ", text)
+            laid = re.findall(r"f32\[8,\d+,\d+\]\{1,2,0", text)
+            t, m = cfg.sequence.seq_len, cfg.network.core.memory_len
+            heads = cfg.network.core.num_attention_heads
+            whole = [x for x in re.findall(r"\[([\d,]+)\]", text)
+                     if {str(t), str(t + m), str(heads)}
+                     <= set(x.split(","))]
+            gib = 2.0 ** 30
+            print(f"  kimi_core step: temp {memory.temp_size_in_bytes / gib:.3f}"
+                  f" GiB, arguments {memory.argument_size_in_bytes / gib:.3f}"
+                  f" GiB, the step's peak (arguments + temp) "
+                  f"{(memory.argument_size_in_bytes + memory.temp_size_in_bytes) / gib:.3f}"
+                  f" GiB; {len(remat)} remat instructions "
+                  f"{sorted(set(re.sub(r'[.]\d+', '', r) for r in remat))}; "
+                  f"{len(laid)} float32 expert arrays laid out {{1,2,0}}; "
+                  f"{len(whole)} arrays of queries x keys x heads")
+            assert not laid, "a float32 expert array laid out {1,2,0}"
+            assert not whole, "an array of queries x keys x heads"
+
+        return {"kimi_core_sound": sound,
+                "kimi_core_carry_off_fails": carry_off,
+                "kimi_core_decay_off_fails": decay_off,
+                "kimi_core_8bit_fails": eight_bit,
+                "kimi_core_step_compile": step_compile}
+    for name, fn in kimi_core().items():
+        add(name, fn)
+
     # --- the target held in the dtype its forward reads
     # (learner/target.py) at the widths of the benchmark's configurations
     # with experts: through one program (float32 in), the float32 tree and
